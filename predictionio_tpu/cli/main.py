@@ -477,7 +477,16 @@ def main(argv: Optional[list] = None) -> int:
                 # here on an ephemeral port
                 from predictionio_tpu.serving.supervisor import (
                     ChildSpec, Supervisor, child_argv_from_parent,
+                    require_chips,
                 )
+                from predictionio_tpu.utils.device import (
+                    visible_chip_count,
+                )
+                # every child holds a chip; this router holds none.
+                # More children than chips is refused before anything
+                # starts, and caps the autoscaler below
+                chips = visible_chip_count()
+                require_chips(args.supervised, chips)
                 server = FleetServer(
                     config, fleet_config_from_env(
                         registry.config, replicas=0,
@@ -495,7 +504,7 @@ def main(argv: Optional[list] = None) -> int:
 
                 sup = Supervisor(
                     [_child_spec(f"replica{i}")
-                     for i in range(args.supervised)])
+                     for i in range(args.supervised)], chips=chips)
                 sup.start()
                 scaling = ""
                 if args.autoscale == "on" or (
@@ -517,6 +526,10 @@ def main(argv: Optional[list] = None) -> int:
                         max_children=(args.autoscale_max
                                       if args.autoscale_max is not None
                                       else acfg.max_children))
+                    if chips is not None:
+                        acfg = dataclasses.replace(
+                            acfg, max_children=min(acfg.max_children,
+                                                   chips))
                     server.autoscaler = Autoscaler(
                         acfg, supervisor=sup, fleet=server,
                         spec_factory=_child_spec)

@@ -243,8 +243,11 @@ def train(registry, *, engine_json: str = "engine.json",
     Runner.scala:213-215,298-305)."""
     from predictionio_tpu.core import RuntimeContext, WorkflowParams
     from predictionio_tpu.core.workflow import CoreWorkflow, resolve_engine
-    from predictionio_tpu.obs import compile_count, install_compile_probe
+    from predictionio_tpu.obs import (
+        compile_cache_counts, compile_count, install_compile_probe,
+    )
     from predictionio_tpu.parallel import initialize_distributed
+    from predictionio_tpu.utils.device import claim_device
 
     # flags override env inside initialize_distributed; nothing is
     # written back to os.environ (a later single-host train in the same
@@ -252,6 +255,7 @@ def train(registry, *, engine_json: str = "engine.json",
     distributed = initialize_distributed(
         coordinator=coordinator, num_processes=num_processes,
         process_id=process_id)
+    device, cache_dir = claim_device()
 
     variant = load_variant(engine_json)
     factory = resolve_factory_name(variant, engine_factory, engine_json)
@@ -283,17 +287,25 @@ def train(registry, *, engine_json: str = "engine.json",
     # counted; the delta (not the process total) is reported
     install_compile_probe()
     compiles_before = compile_count()
+    cache_before = compile_cache_counts()
     with prof_ctx:
         row = CoreWorkflow.run_train(
             engine, engine_params, ctx,
             engine_factory=factory,
             engine_variant=variant.get("id", "default"),
             persist=persist)
+    cache_after = compile_cache_counts()
     return {"engineInstanceId": row.id, "status": row.status,
             "startTime": format_time(row.start_time),
             "endTime": format_time(row.end_time),
             "phaseTimings": dict(ctx.phase_timings),
             "jaxCompiles": int(compile_count() - compiles_before),
+            "compileCache": {
+                "dir": cache_dir,
+                **{k: cache_after[k] - cache_before[k]
+                   for k in cache_after}},
+            "device": device,
+            "mesh": {str(k): int(v) for k, v in ctx.mesh.shape.items()},
             "distributed": distributed, "persisted": persist}
 
 
@@ -308,10 +320,14 @@ def run_eval(registry, evaluation_path: str,
         MetricEvaluator, RuntimeContext, run_evaluation,
     )
 
+    from predictionio_tpu.utils.device import claim_device
+
     def resolve(dotted: str):
         module_name, _, attr = dotted.rpartition(".")
         obj = getattr(importlib.import_module(module_name), attr)
         return obj() if callable(obj) and not hasattr(obj, "engine") else obj
+
+    claim_device()
 
     evaluation = resolve(evaluation_path)
     engine_params_list = None
@@ -337,7 +353,9 @@ def batchpredict(registry, *, engine_json: str = "engine.json",
     from predictionio_tpu.core import RuntimeContext
     from predictionio_tpu.core.batchpredict import run_batch_predict
     from predictionio_tpu.core.workflow import resolve_engine
+    from predictionio_tpu.utils.device import claim_device
 
+    claim_device()
     variant = load_variant(engine_json)
     factory = resolve_factory_name(variant, engine_factory, engine_json)
     engine = resolve_engine(factory)
@@ -472,9 +490,29 @@ def template_new(directory: str, *, base: str = "recommendation") -> str:
 # status (commands/Management.scala:99-181)
 # ---------------------------------------------------------------------------
 
-def status(registry) -> Dict[str, Any]:
-    import jax
+def _probe_device(timeout_s: float = 60.0) -> Dict[str, Any]:
+    """`utils.device.device_info()` from a CHILD process: `status` itself
+    must not take the chip (one process per chip), and when a server
+    holds it the child fails or hangs — reported, never waited on past
+    `timeout_s`."""
+    import subprocess
+    import sys
+    code = ("import json; from predictionio_tpu.utils.device import "
+            "device_info; print(json.dumps(device_info()))")
+    try:
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True,
+                             timeout=timeout_s)
+    except subprocess.TimeoutExpired:
+        return {"platform": f"unavailable: no answer in {timeout_s:.0f}s "
+                            "(is another process holding the chip?)"}
+    if out.returncode != 0:
+        reason = (out.stderr.strip().splitlines() or ["no output"])[-1]
+        return {"platform": f"unavailable: {reason}"}
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
+
+def status(registry) -> Dict[str, Any]:
     import predictionio_tpu
 
     info: Dict[str, Any] = {
@@ -490,13 +528,9 @@ def status(registry) -> Dict[str, Any]:
         info["storage"] = "ok"
     except Exception as e:
         info["storage"] = f"error: {e}"
-    try:
-        devices = jax.devices()
-        info["devices"] = [str(d) for d in devices]
-        info["platform"] = devices[0].platform if devices else "none"
-    except Exception as e:  # pragma: no cover - env dependent
-        info["devices"] = []
-        info["platform"] = f"error: {e}"
+    info["device"] = _probe_device()
+    from predictionio_tpu import native
+    info["native"] = {"eventlog": native.load("eventlog") is not None}
     info["status"] = ("(sleeping)" if info["storage"] == "ok"
                       else "storage check failed")
     # the latest completed train with its per-phase timings (the

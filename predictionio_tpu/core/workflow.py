@@ -300,9 +300,11 @@ def warm_deploy(algos: List[Any], models: List[Any],
     state across the device mesh; legacy two-argument overrides keep
     working. Warmup cost/count land in the default metrics registry
     (`pio_serve_warmup_seconds`, `pio_serve_warmup_compiles_total`);
-    `PIO_SERVE_WARMUP=off` disables. A warmup failure is logged, never
-    fatal — the generic dispatch paths still serve correctly, just
-    slower on first touch."""
+    `PIO_SERVE_WARMUP=off` disables (and leaves every query on the
+    generic dispatch, host numpy below the crossover). A warm-up that
+    raises fails the deploy: a server whose plans did not compile would
+    answer 200 from a slower path and nobody would know. On `/reload`
+    the same error rolls back to the last good deployment."""
     import inspect
     import os
     import time as _time
@@ -318,21 +320,16 @@ def warm_deploy(algos: List[Any], models: List[Any],
     t0 = _time.perf_counter()
     compiled = 0
     for algo, model in zip(algos, models):
-        label = type(algo).__name__
         try:
-            try:
-                params = inspect.signature(algo.warm_serving).parameters
-                takes_mesh = ("mesh" in params or any(
-                    p.kind is inspect.Parameter.VAR_KEYWORD
-                    for p in params.values()))
-            except (TypeError, ValueError):
-                takes_mesh = False
-            n = (algo.warm_serving(model, buckets, mesh=mesh)
-                 if takes_mesh else algo.warm_serving(model, buckets))
-            compiled += int(n or 0)
-        except Exception as e:
-            _log.warning("serve_warmup_failed", algo=label,
-                         error=f"{type(e).__name__}: {e}")
+            params = inspect.signature(algo.warm_serving).parameters
+            takes_mesh = ("mesh" in params or any(
+                p.kind is inspect.Parameter.VAR_KEYWORD
+                for p in params.values()))
+        except (TypeError, ValueError):
+            takes_mesh = False
+        n = (algo.warm_serving(model, buckets, mesh=mesh)
+             if takes_mesh else algo.warm_serving(model, buckets))
+        compiled += int(n or 0)
     reg.gauge("pio_serve_warmup_seconds",
               "Wall time of the last deploy serve warmup").set(
         _time.perf_counter() - t0)

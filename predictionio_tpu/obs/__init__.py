@@ -18,7 +18,8 @@ from predictionio_tpu.obs.logs import (  # noqa: F401
     StructuredLogger, get_logger, new_request_id,
 )
 from predictionio_tpu.obs.jaxprobe import (  # noqa: F401
-    compile_count, compile_watch, install_compile_probe,
+    compile_cache_counts, compile_count, compile_watch,
+    install_compile_probe,
 )
 from predictionio_tpu.obs.report import (  # noqa: F401
     record_train_phases, train_report,

@@ -22,6 +22,11 @@ from typing import Optional
 from predictionio_tpu.obs.metrics import MetricsRegistry, get_registry
 
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# persistent compile cache (jax/_src/compiler.py, compilation_cache.py):
+# a hit loads the executable instead of compiling it; a miss is counted
+# when the freshly compiled entry is written
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 COMPILE_BUCKETS = (0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
                    30.0, 60.0, 120.0)
 
@@ -40,12 +45,21 @@ def _instruments(registry: MetricsRegistry):
     return counter, hist
 
 
+def _cache_counter(registry: MetricsRegistry):
+    return registry.counter(
+        "pio_jax_compile_cache_total",
+        "Persistent compile-cache lookups by result (hit: executable "
+        "loaded from the cache directory; miss: compiled and written)",
+        labels=("result",))
+
+
 def install_compile_probe(
         registry: Optional[MetricsRegistry] = None) -> None:
     """Register the jax.monitoring listener (once per process). Counts
     land in `registry` (default: the process-default registry)."""
     global _installed
     counter, hist = _instruments(registry or get_registry())
+    cache = _cache_counter(registry or get_registry())
     with _install_lock:
         if _installed:
             return
@@ -56,7 +70,14 @@ def install_compile_probe(
                 counter.inc()
                 hist.observe(duration)
 
+        def _on_event(event: str, **kwargs) -> None:
+            if event == CACHE_HIT_EVENT:
+                cache.labels(result="hit").inc()
+            elif event == CACHE_MISS_EVENT:
+                cache.labels(result="miss").inc()
+
         monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
         _installed = True
 
 
@@ -64,6 +85,13 @@ def compile_count(registry: Optional[MetricsRegistry] = None) -> int:
     """Current backend-compile count (0 before the probe ever fired)."""
     counter, _ = _instruments(registry or get_registry())
     return int(counter.value)
+
+
+def compile_cache_counts(
+        registry: Optional[MetricsRegistry] = None) -> dict:
+    """Persistent compile-cache {"hit": n, "miss": n} so far."""
+    cache = _cache_counter(registry or get_registry())
+    return {r: int(cache.labels(result=r).value) for r in ("hit", "miss")}
 
 
 class _CompileWatch:

@@ -42,6 +42,7 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 from predictionio_tpu.obs.logs import get_logger
 from predictionio_tpu.obs.metrics import MetricsRegistry, get_registry
+from predictionio_tpu.utils.device import backend_initialized, live_devices
 
 _log = get_logger("profiler")
 
@@ -335,25 +336,40 @@ def _reset_global_for_tests() -> None:
 
 # -- GC pause hook ------------------------------------------------------------
 _gc_lock = threading.Lock()
-_gc_registries: set = set()          # id() of registries already hooked
+# id(registry) -> pauses the hook has seen and nobody has folded yet,
+# as (generation, seconds)
+_gc_pending: Dict[int, "deque"] = {}
 _gc_start_ns = 0
 
 
-def install_gc_callbacks(metrics: Optional[MetricsRegistry] = None) -> bool:
-    """Install a `gc.callbacks` hook observing every collection's
-    wall time into `pio_gc_pause_seconds{generation}`. Idempotent per
-    registry (one hook feeds one registry; a test registry gets its
-    own). Returns True on install, False for already-installed."""
-    metrics = metrics if metrics is not None else get_registry()
-    hist = metrics.histogram(
+def _gc_histogram(metrics: MetricsRegistry):
+    return metrics.histogram(
         "pio_gc_pause_seconds",
         "Stop-the-world GC collection pauses by generation",
         buckets=(0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5),
         labels=("generation",))
+
+
+def install_gc_callbacks(metrics: Optional[MetricsRegistry] = None) -> bool:
+    """Install a `gc.callbacks` hook timing every collection for
+    `pio_gc_pause_seconds{generation}`. Idempotent per registry (one
+    hook feeds one registry; a test registry gets its own). Returns
+    True on install, False for already-installed.
+
+    The hook only appends to a bounded deque; `flush_gc_pauses` folds
+    the pauses into the histogram from the tsdb tick and `/metrics`. A
+    collection runs inside whichever thread happened to allocate — one
+    that may be holding this very family's (non-reentrant) lock, e.g.
+    halfway through rendering it — so a hook that observed directly
+    deadlocked that thread against itself and wedged every later
+    `/metrics` behind it."""
+    from collections import deque
+    metrics = metrics if metrics is not None else get_registry()
+    _gc_histogram(metrics)      # the family exists before the first pause
     with _gc_lock:
-        if id(metrics) in _gc_registries:
+        if id(metrics) in _gc_pending:
             return False
-        _gc_registries.add(id(metrics))
+        pending = _gc_pending[id(metrics)] = deque(maxlen=65536)
 
     def _on_gc(phase: str, info: Dict) -> None:
         # CPython runs collections (and hence callbacks) under a
@@ -363,31 +379,59 @@ def install_gc_callbacks(metrics: Optional[MetricsRegistry] = None) -> bool:
             _gc_start_ns = time.perf_counter_ns()
         elif phase == "stop" and _gc_start_ns:
             dt = (time.perf_counter_ns() - _gc_start_ns) / 1e9
-            hist.labels(generation=str(info.get("generation", "?"))
-                        ).observe(dt)
+            pending.append((str(info.get("generation", "?")), dt))
 
     gc.callbacks.append(_on_gc)
     return True
 
 
+def flush_gc_pauses(metrics: Optional[MetricsRegistry] = None) -> int:
+    """Fold the pauses the hook has queued for `metrics` into its
+    histogram; returns how many. Called from ordinary code (never from
+    the hook), where taking the family lock is safe."""
+    metrics = metrics if metrics is not None else get_registry()
+    pending = _gc_pending.get(id(metrics))
+    if not pending:
+        return 0
+    hist = _gc_histogram(metrics)
+    folded = 0
+    while True:
+        try:
+            generation, dt = pending.popleft()
+        except IndexError:
+            return folded
+        hist.labels(generation=generation).observe(dt)
+        folded += 1
+
+
 # -- host /proc sampler -------------------------------------------------------
 class HostSampler:
-    """RSS / CPU seconds / thread count from `/proc/self`, set on the
-    tsdb tick. CPU is a monotone counter advanced by delta."""
+    """RSS / CPU seconds / thread count from `/proc/self`, whether this
+    process holds a JAX backend, and the queued GC pauses — set on the
+    tsdb tick and on every `/metrics`. CPU is a monotone counter
+    advanced by delta."""
 
     def __init__(self, metrics: Optional[MetricsRegistry] = None):
-        m = metrics if metrics is not None else get_registry()
+        m = self._metrics = (metrics if metrics is not None
+                             else get_registry())
         self._rss = m.gauge("pio_host_rss_bytes",
                             "Resident set size of this process")
         self._threads = m.gauge("pio_host_threads",
                                 "Live threads in this process")
         self._cpu = m.counter("pio_host_cpu_seconds_total",
                               "Process CPU time (user+system)")
+        self._backend = m.gauge(
+            "pio_jax_backend_initialized",
+            "1 once this process has initialised a JAX backend (and so "
+            "holds whatever chip it found); stays 0 in processes that "
+            "do not compute")
         self._page = os.sysconf("SC_PAGE_SIZE")
         self._tick = float(os.sysconf("SC_CLK_TCK")) or 100.0
         self._last_cpu = 0.0
 
     def sample(self) -> None:
+        self._backend.set(1.0 if backend_initialized() else 0.0)
+        flush_gc_pauses(self._metrics)
         try:
             with open("/proc/self/statm", "rb") as fh:
                 self._rss.set(int(fh.read().split()[1]) * self._page)
@@ -409,23 +453,16 @@ class HostSampler:
 def sample_device_memory(metrics: Optional[MetricsRegistry] = None) -> int:
     """Per-device allocator stats into
     `pio_device_memory_bytes{device,kind}` (kind: in_use / peak).
-    Returns the number of devices sampled; 0 when jax is unavailable
-    or the backend exposes no memory_stats (CPU)."""
+    Returns the number of devices sampled; 0 when this process has not
+    initialised a backend (sampling never initialises one) or the
+    backend exposes no memory_stats (CPU)."""
     m = metrics if metrics is not None else get_registry()
-    try:
-        import jax
-        devices = jax.devices()
-    except Exception:
-        return 0
     gauge = m.gauge("pio_device_memory_bytes",
                     "Device allocator bytes by device and kind",
                     labels=("device", "kind"))
     sampled = 0
-    for d in devices:
-        try:
-            stats = d.memory_stats() or {}
-        except Exception:
-            stats = {}
+    for d in live_devices():
+        stats = d.memory_stats() or {}
         if not stats:
             continue
         dev = f"{d.platform}:{d.id}"

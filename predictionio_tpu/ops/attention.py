@@ -35,7 +35,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from predictionio_tpu.ops import compat
 
 _NEG = -1e30
 
@@ -161,6 +160,6 @@ def ring_attention(q, k, v, mesh, *, axis: str = "sp",
         else None
     spec = P(b, axis, None, None)
     mspec = P(b, axis)
-    return compat.shard_map(body, mesh=mesh,
+    return jax.shard_map(body, mesh=mesh,
                             in_specs=(spec, spec, spec, mspec),
                             out_specs=spec)(q, k, v, kv_mask)

@@ -1,9 +1,8 @@
-"""Mesh-sharded serving plans: partial top-k per shard + allgather merge.
+"""Mesh-sharded serving plans: partial top-k per shard + global merge.
 
-A catalog bigger than one chip's HBM cannot be pinned by `BucketedTopK`
-— and `MULTICHIP_r0*.json` shows every model's train step already runs
-on 8-device meshes while serving ignored the mesh entirely. This module
-closes that gap with the sharded-scoring shape "Scalable ML Training
+A catalog bigger than one chip's HBM cannot be pinned by `BucketedTopK`,
+and every model's train step already shards over the device mesh. This
+module gives serving the sharded-scoring shape "Scalable ML Training
 Infrastructure at Google" describes for ads scoring: partition the
 embedding (factor) table row-wise, score locally, merge partial top-k.
 
@@ -22,9 +21,10 @@ counterparts in `ops/topk.py`):
     ID SPACE (banned ids arrive untranslated; each shard subtracts its
     row base, routes out-of-shard ids to an out-of-bounds slot, and the
     scatter drops them), masks its padding
-    rows to NEG_INF, takes a LOCAL `lax.top_k`, then all-gathers the
-    `k_shard * n_shards` candidates and merges them with a final
-    top-k over globally-offset ids;
+    rows to NEG_INF and takes a LOCAL `lax.top_k`; the shards'
+    `k_shard` candidates come out of the shard_map stacked over the
+    mesh axis and `_jit_merged` merges the `k_shard * n_shards` of them
+    with a final top-k over globally-offset ids;
   - the merge is bit-identical to the single-device oracle, ties
     included: candidates concatenate in shard-major order (= global id
     order for equal scores, since `lax.top_k` is lowest-index-first
@@ -61,12 +61,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.ops import compat, topk
+from predictionio_tpu.ops import topk
 from predictionio_tpu.ops.topk import (
     DEFAULT_SERVE_BUCKETS, NEG_INF, BucketedSimilar, BucketedTopK,
     _next_pow2, _record_dispatch,
 )
-from predictionio_tpu.parallel.mesh import shard_put
+from predictionio_tpu.parallel.mesh import (  # noqa: F401 — re-export
+    parse_fleet_mesh, shard_put,
+)
 
 # the serve mesh's single axis: catalog rows are partitioned over it
 SHARD_AXIS = "items"
@@ -95,24 +97,6 @@ class ShardSlice:
     builds a `ShardSliceTopK` instead of a whole-catalog plan."""
     n_shards: int
     index: int
-
-
-def parse_fleet_mesh(spec: str):
-    """Parse a cross-host mesh spec: `items=N@fleet` (router side:
-    merge over N member-owned shards) or `items=N@fleet:i` (member
-    side: this process owns shard i). Returns (n_shards, index-or-None)
-    or None when `spec` is not a fleet mesh."""
-    import re
-    m = re.match(r"\s*items\s*=\s*(\d+)\s*@\s*fleet(?::(\d+))?\s*$",
-                 spec or "")
-    if m is None:
-        return None
-    n = int(m.group(1))
-    idx = int(m.group(2)) if m.group(2) is not None else None
-    if n < 1 or (idx is not None and not 0 <= idx < n):
-        raise ValueError(f"bad fleet mesh spec {spec!r}: need "
-                         "items=N@fleet[:i] with 0 <= i < N")
-    return n, idx
 
 
 def serve_mesh_from_conf(conf=None):
@@ -285,6 +269,32 @@ def _publish_shard_gauges(n_shards: int, per_shard: int,
         pass
 
 
+def _jit_merged(local_candidates, k: int, mesh):
+    """Jit `local_candidates` (a shard_map whose shards each return
+    their `[1, b, k_shard]` scores and GLOBAL ids, stacked over the
+    mesh axis) followed by the global merge. The merge runs outside
+    the shard_map on the stacked `[n_shards, b, k_shard]` arrays — the
+    partitioner inserts the all-gather — so the result is replicated by
+    construction and the varying-axis check stays on. Off-CPU the
+    per-call query block and ban/mask block are donated, as the
+    single-device plans do."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    def fn(vecs, factors, filt):
+        s_all, g_all = local_candidates(vecs, factors, filt)
+        b = s_all.shape[1]
+        # shard-major concatenation = global-id order for ties
+        s_cat = jnp.swapaxes(s_all, 0, 1).reshape(b, -1)
+        g_cat = jnp.swapaxes(g_all, 0, 1).reshape(b, -1)
+        sv, si = jax.lax.top_k(s_cat, k)
+        return sv, jnp.take_along_axis(g_cat, si, axis=1)
+
+    replicated = NamedSharding(mesh, P())
+    donate = () if jax.default_backend() == "cpu" else (0, 2)
+    return jax.jit(fn, out_shardings=(replicated, replicated),
+                   donate_argnums=donate)
+
+
 class _ShardedPlanBase:
     """Shared bucketing/pad/chunk mechanics of the two sharded plans."""
 
@@ -364,8 +374,7 @@ class ShardedBucketedTopK(_ShardedPlanBase):
         super().__init__(item_factors, k=k, buckets=buckets, mesh=mesh)
         self.banned_width = _next_pow2(max(1, banned_width))
         # whether the per-shard local-candidate stage runs as the
-        # single-launch fused kernel (ops/fused_topk.py); flips back to
-        # False if the kernel fails to lower at warm() time
+        # single-launch fused kernel (ops/fused_topk.py)
         self.fused = False
         self._fn = self._build()
 
@@ -382,7 +391,7 @@ class ShardedBucketedTopK(_ShardedPlanBase):
         if bucket is not None:
             local = fused_topk.shard_local_candidates(
                 per, self.rank, k=kk, bucket=bucket,
-                banned_width=self.banned_width)
+                banned_width=self.banned_width, axis=SHARD_AXIS)
             if local is None:
                 return None
             self.fused = True
@@ -415,29 +424,26 @@ class ShardedBucketedTopK(_ShardedPlanBase):
                 scores = jnp.where(gids[None, :] < n_items, scores,
                                    NEG_INF)
                 s, ix = jax.lax.top_k(scores, kk)
-            s_all = jax.lax.all_gather(s, SHARD_AXIS)
-            g_all = jax.lax.all_gather(ix + base, SHARD_AXIS)
-            # shard-major concatenation = global-id order for ties
-            s_cat = jnp.swapaxes(s_all, 0, 1).reshape(s.shape[0], -1)
-            g_cat = jnp.swapaxes(g_all, 0, 1).reshape(s.shape[0], -1)
-            sv, si = jax.lax.top_k(s_cat, k)
-            return sv, jnp.take_along_axis(g_cat, si, axis=1)
+            return s[None], (ix + base)[None]
 
-        smapped = compat.shard_map(
+        # Pallas' HLO interpreter (the CPU parity tests' stand-in for
+        # Mosaic) evaluates the kernel jaxpr with the varying-axis check
+        # on and trips over its own invariant grid indices; the compiled
+        # kernel is traced with the check off by pallas_call itself, so
+        # only the interpreted form has to opt out
+        check = local is None or not fused_topk.interpreted()
+        return _jit_merged(jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(P(), P(SHARD_AXIS, None), P()),
-            out_specs=(P(), P()))
-        if jax.default_backend() == "cpu":
-            return jax.jit(smapped)
-        # off-CPU: donate the per-call query + banned uploads, exactly
-        # as the single-device plan does
-        return jax.jit(smapped, donate_argnums=(0, 2))
+            out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
+            check_vma=check), k, self.mesh)
 
     def warm(self) -> int:
         """AOT-lower/compile every bucket executable against the
-        resident sharded factors (idempotent). Each bucket tries the
-        fused per-shard kernel first (PIO_SERVE_FUSED gate) and falls
-        back to the XLA body when fusion is off or fails to lower."""
+        resident sharded factors (idempotent). With fusion on
+        (PIO_SERVE_FUSED gate) each bucket's per-shard stage is the
+        fused kernel, and a kernel that does not compile fails the
+        warm-up; otherwise every bucket compiles the XLA body."""
         compiled = 0
         for b in self.buckets:
             if b in self._exe:
@@ -445,22 +451,16 @@ class ShardedBucketedTopK(_ShardedPlanBase):
             vec_spec = jax.ShapeDtypeStruct((b, self.rank), np.float32)
             ban_spec = jax.ShapeDtypeStruct((b, self.banned_width),
                                             np.int32)
-            exe = None
-            fn = self._build(bucket=b)
-            if fn is not None:
-                try:
-                    exe = fn.lower(vec_spec, self.factors,
-                                   ban_spec).compile()
-                except Exception:
-                    # kernel lowered at trace time but died in the
-                    # backend compiler: unfuse and fall through
-                    self.fused = False
-            if exe is None:
-                exe = self._fn.lower(vec_spec, self.factors,
-                                     ban_spec).compile()
-            self._exe[b] = exe
+            fn = self._build(bucket=b) or self._fn
+            self._exe[b] = fn.lower(vec_spec, self.factors,
+                                    ban_spec).compile()
             compiled += 1
         return compiled
+
+    def bucket_kernels(self) -> dict:
+        """Which per-shard kernel serves each warmed bucket."""
+        return {b: "fused" if self.fused else "xla"
+                for b in sorted(self._exe)}
 
     def fits(self, *, max_banned: int, k: int) -> bool:
         """Same gate as `BucketedTopK.fits`."""
@@ -526,20 +526,12 @@ class ShardedBucketedSimilar(_ShardedPlanBase):
             # mask columns with False), so no gid test is needed here
             scores = jnp.where(mask_local, scores, NEG_INF)
             s, ix = jax.lax.top_k(scores, kk)
-            s_all = jax.lax.all_gather(s, SHARD_AXIS)
-            g_all = jax.lax.all_gather(ix + base, SHARD_AXIS)
-            s_cat = jnp.swapaxes(s_all, 0, 1).reshape(s.shape[0], -1)
-            g_cat = jnp.swapaxes(g_all, 0, 1).reshape(s.shape[0], -1)
-            sv, si = jax.lax.top_k(s_cat, k)
-            return sv, jnp.take_along_axis(g_cat, si, axis=1)
+            return s[None], (ix + base)[None]
 
-        smapped = compat.shard_map(
+        return _jit_merged(jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(P(), P(SHARD_AXIS, None), P(None, SHARD_AXIS)),
-            out_specs=(P(), P()))
-        if jax.default_backend() == "cpu":
-            return jax.jit(smapped)
-        return jax.jit(smapped, donate_argnums=(0, 2))
+            out_specs=(P(SHARD_AXIS), P(SHARD_AXIS))), k, self.mesh)
 
     def warm(self) -> int:
         """AOT-lower/compile every bucket executable (idempotent)."""
@@ -642,6 +634,11 @@ class ShardSliceTopK:
 
     def warm(self) -> int:
         return self._inner.warm()
+
+    def bucket_kernels(self) -> dict:
+        kernels = getattr(self._inner, "bucket_kernels", None)
+        return (kernels() if kernels is not None
+                else {b: "xla" for b in self._inner.buckets})
 
     def fits(self, *, max_banned: int, k: int) -> bool:
         # k above the slice's own candidate count still FITS: the

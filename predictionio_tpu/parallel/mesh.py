@@ -56,6 +56,24 @@ class MeshSpec:
         return MeshSpec(axes)
 
 
+def parse_fleet_mesh(spec: str):
+    """Parse a cross-host mesh spec: `items=N@fleet` (router side:
+    merge over N member-owned shards) or `items=N@fleet:i` (member
+    side: this process owns shard i). Returns (n_shards, index-or-None)
+    or None when `spec` is not a fleet mesh."""
+    import re
+    m = re.match(r"\s*items\s*=\s*(\d+)\s*@\s*fleet(?::(\d+))?\s*$",
+                 spec or "")
+    if m is None:
+        return None
+    n = int(m.group(1))
+    idx = int(m.group(2)) if m.group(2) is not None else None
+    if n < 1 or (idx is not None and not 0 <= idx < n):
+        raise ValueError(f"bad fleet mesh spec {spec!r}: need "
+                         "items=N@fleet[:i] with 0 <= i < N")
+    return n, idx
+
+
 def make_mesh(spec: Optional[MeshSpec] = None, devices=None):
     """Build a `jax.sharding.Mesh` from a spec over the available devices.
 

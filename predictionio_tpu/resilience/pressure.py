@@ -92,18 +92,12 @@ def _rss_bytes() -> Optional[int]:
 
 def device_memory_frac() -> Optional[float]:
     """Worst bytes_in_use / bytes_limit across devices, or None when
-    the backend reports no limits (CPU)."""
-    try:
-        import jax
-        devices = jax.devices()
-    except Exception:
-        return None
+    this process has not initialised a backend (the guard never
+    initialises one) or the backend reports no limits (CPU)."""
+    from predictionio_tpu.utils.device import live_devices
     worst: Optional[float] = None
-    for d in devices:
-        try:
-            stats = d.memory_stats() or {}
-        except Exception:
-            continue
+    for d in live_devices():
+        stats = d.memory_stats() or {}
         limit = stats.get("bytes_limit")
         in_use = stats.get("bytes_in_use")
         if not limit or in_use is None:

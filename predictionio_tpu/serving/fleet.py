@@ -253,7 +253,7 @@ class FleetServer(HTTPServerBase):
         # router a MERGE point over N member-owned catalog shards
         # (in-process replicas are auto-assigned shard i%N; remote
         # members declare theirs via heartbeats). 0 = plain routing.
-        from predictionio_tpu.ops.topk_sharded import parse_fleet_mesh
+        from predictionio_tpu.parallel.mesh import parse_fleet_mesh
         parsed = parse_fleet_mesh(config.mesh)
         self._mesh_shards = (parsed[0]
                              if parsed is not None and parsed[1] is None

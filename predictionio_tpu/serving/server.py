@@ -865,9 +865,13 @@ class PredictionServer(HTTPServerBase):
         super().__init__(host=config.ip, port=config.port, metrics=metrics,
                          default_deadline_ms=config.default_deadline_ms,
                          max_inflight=config.max_inflight)
+        from predictionio_tpu.utils.device import claim_device
         from predictionio_tpu.utils.security import KeyAuthentication
 
         self.config = config
+        # this is the server that computes: take the device (and fail
+        # here, not at the first query, when there is none to take)
+        self.device, self._compile_cache = claim_device()
         self._serve_obs = _ServeInstruments(self.metrics)
         # a --mesh deploy flag rides in the server runtime_conf, where
         # prepare_deploy's serve-mesh derivation (merged with the
@@ -1012,9 +1016,15 @@ class PredictionServer(HTTPServerBase):
 
     # -- continuous observatory ---------------------------------------------
     def _obs_collectors(self):
-        """The serve plane's tsdb tick additionally samples the live
-        plans' device residency."""
-        return super()._obs_collectors() + [self._sample_plan_bytes]
+        """The serve plane owns device state, so its tsdb tick (alone
+        among the servers) samples device memory and the live plans'
+        device residency."""
+        return super()._obs_collectors() + [self._sample_device_memory,
+                                            self._sample_plan_bytes]
+
+    def _sample_device_memory(self) -> None:
+        from predictionio_tpu.obs.profiler import sample_device_memory
+        sample_device_memory(self.metrics)
 
     def _sample_plan_bytes(self) -> None:
         """Device residency of the live serving plans into
@@ -1278,7 +1288,7 @@ class PredictionServer(HTTPServerBase):
         shard i of n (`--mesh items=N@fleet:i`), else "" — advertised
         by the replica agent's heartbeats so the fleet router can map
         shard ownership without extra control traffic."""
-        from predictionio_tpu.ops.topk_sharded import parse_fleet_mesh
+        from predictionio_tpu.parallel.mesh import parse_fleet_mesh
         try:
             parsed = parse_fleet_mesh(self.config.mesh)
         except ValueError:
@@ -1865,6 +1875,9 @@ class PredictionServer(HTTPServerBase):
                 "requestCount": self.request_count,
                 "avgServingSec": self.avg_serving_sec,
                 "lastServingSec": self.last_serving_sec,
+                "device": self.device,
+                "compileCache": self._compile_cache,
+                "servePlans": _serve_plans(dep),
             })
 
         @r.get("/quality.json")
@@ -1952,6 +1965,28 @@ def install_signal_handlers(server, on_stopped=None) -> None:
 
     for sig in (signal.SIGTERM, signal.SIGINT):
         signal.signal(sig, _handle)
+
+
+def _serve_plans(dep: _Deployment) -> List[Dict[str, Any]]:
+    """What the deploy warm-up built, per algorithm: the plan class,
+    its shard count, and for every warmed batch bucket which kernel
+    serves it ("fused": the single-launch Pallas kernel, "xla": the AOT
+    XLA chain)."""
+    out = []
+    for holder in list(dep.algos) + list(dep.models):
+        plan = getattr(holder, "_serve_plan", None)
+        if plan is None:
+            continue
+        kernels = getattr(plan, "bucket_kernels", None)
+        out.append({
+            "algorithm": type(holder).__name__,
+            "plan": type(plan).__name__,
+            "shards": int(getattr(plan, "n_shards", 1)),  # lint: ok — host int
+            "buckets": ({str(b): k for b, k in kernels().items()}
+                        if kernels is not None
+                        else {str(b): "xla" for b in plan.buckets}),
+        })
+    return out
 
 
 def _gen_pr_id() -> str:

@@ -18,6 +18,13 @@ plane. The supervisor:
     graceful drain (`install_signal_handlers` routes SIGTERM through
     `PredictionServer.stop()`) before SIGKILL.
 
+One process per chip: a replica child initialises a JAX backend and so
+holds a chip until it exits, while the supervising router never touches
+one. With `chips` set (the CLI passes `utils.device.visible_chip_count()`)
+the supervisor refuses more children than chips — at start and on
+`grow` — and on a multi-chip host pins each slot to its own chip through
+`ChildSpec.env` (`utils.device.chip_env`), stable across respawns.
+
 Re-registration rides the PR-8 membership path: each child runs a
 `ReplicaAgent` that registers with the router(s) on start, so a
 respawned replica re-enters routing within one heartbeat with no
@@ -65,11 +72,22 @@ class ChildSpec:
     env: Dict[str, str] = field(default_factory=dict)
 
 
+def require_chips(n_children: int, chips: Optional[int]) -> None:
+    """Refuse more chip-holding children than the host has chips
+    (`chips` None: no accelerator host, nothing to ration)."""
+    if chips is not None and n_children > chips:
+        raise ValueError(
+            f"{n_children} supervised replicas need {n_children} chips, "
+            f"this host has {chips}: a chip belongs to one process (use "
+            "--replicas N to share one process)")
+
+
 class _Child:
     """Runtime state for one supervised slot."""
 
-    def __init__(self, spec: ChildSpec):
+    def __init__(self, spec: ChildSpec, chip: Optional[int] = None):
         self.spec = spec
+        self.chip = chip                # the chip this slot is pinned to
         self.proc: Optional[subprocess.Popen] = None
         self.death_times: List[float] = []
         self.next_spawn_at: Optional[float] = None
@@ -88,7 +106,7 @@ class _Child:
         return {"name": self.spec.name, "alive": self.alive,
                 "pid": self.proc.pid if self.proc is not None else None,
                 "respawns": self.respawns, "givenUp": self.given_up,
-                "retiring": self.retiring,
+                "retiring": self.retiring, "chip": self.chip,
                 "lastRc": self.last_rc}
 
 
@@ -96,6 +114,7 @@ class Supervisor:
     """Spawn, watch, respawn, and gracefully stop child replicas."""
 
     def __init__(self, specs: Sequence[ChildSpec], *,
+                 chips: Optional[int] = None,
                  grace_s: float = DEFAULT_GRACE_S,
                  poll_s: float = 0.2,
                  backoff_base_s: float = BACKOFF_BASE_S,
@@ -108,7 +127,13 @@ class Supervisor:
         self.backoff_max_s = backoff_max_s
         self.breaker_k = max(1, breaker_k)
         self.breaker_window_s = breaker_window_s
-        self._children = [_Child(s) for s in specs]
+        # chips this host can hand out (None: no accelerator host,
+        # nothing to ration); every child holds one for its lifetime
+        self.chips = chips
+        require_chips(len(specs), chips)
+        self._children: List[_Child] = []
+        for spec in specs:
+            self._children.append(_Child(spec, self._free_chip()))
         self._lock = threading.Lock()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -192,8 +217,13 @@ class Supervisor:
         treatment as the boot-time set."""
         if self.find(spec.name) is not None:
             raise ValueError(f"child {spec.name!r} already supervised")
-        child = _Child(spec)
         with self._lock:
+            if self.chips is not None and \
+                    len(self._children) >= self.chips:
+                raise ValueError(
+                    f"no free chip for {spec.name!r}: all {self.chips} "
+                    "are held by supervised children")
+            child = _Child(spec, self._free_chip())
             self._children.append(child)
         self._spawn_child(child)
         self._export_states()
@@ -232,8 +262,20 @@ class Supervisor:
         return True
 
     # -- spawning -----------------------------------------------------------
+    def _free_chip(self) -> Optional[int]:
+        """Lowest chip index no current slot is pinned to; None where
+        there is nothing to pin (no accelerator host, or a single chip
+        the one child takes by default)."""
+        if self.chips is None or self.chips < 2:
+            return None
+        held = {c.chip for c in self._children}
+        return next(i for i in range(self.chips) if i not in held)
+
     def _spawn_child(self, child: _Child) -> None:
         env = dict(os.environ)
+        if child.chip is not None:
+            from predictionio_tpu.utils.device import chip_env
+            env.update(chip_env(child.chip))
         env.update(child.spec.env)
         try:
             child.proc = subprocess.Popen(child.spec.argv, env=env)

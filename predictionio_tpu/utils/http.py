@@ -299,6 +299,7 @@ class HTTPServerBase:
 
     def _metrics_endpoint(self, req: Request) -> Response:
         self._sync_wire_metrics()
+        self._host_sampler.sample()
         return Response.text(
             self.metrics.render(),
             content_type="text/plain; version=0.0.4; charset=utf-8")
@@ -343,13 +344,11 @@ class HTTPServerBase:
 
     def _obs_collectors(self) -> List[Callable[[], None]]:
         """Collectors the tsdb scraper runs before each snapshot —
-        subclasses extend (fleet member scrape, device plan bytes)."""
-
-        def _device_memory() -> None:
-            prof_mod.sample_device_memory(self.metrics)
-
-        return [self._sync_wire_metrics, self._host_sampler.sample,
-                _device_memory]
+        subclasses extend (fleet member scrape, device memory and plan
+        bytes on the one server that owns device state). Nothing here
+        may touch jax: most servers never compute, and a process that
+        initialises a backend takes the chip from the ones that do."""
+        return [self._sync_wire_metrics, self._host_sampler.sample]
 
     def _sync_wire_metrics(self) -> None:
         """Scrape the selector wire's raw counters into pio_wire_*

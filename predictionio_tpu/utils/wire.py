@@ -1,10 +1,10 @@
 """Selector readiness-loop HTTP/1.1 front end — the 10k-qps wire path.
 
-Three bench rounds (BENCH_r03-r05) showed the device finishing a serve
-batch in ~2 ms while microbatched throughput plateaued near 500-900 qps:
-the ceiling was thread-per-connection handoffs and per-request header
-dict construction in the stdlib `ThreadingHTTPServer` stack, not the
-accelerator. This module replaces that stack for the serve plane:
+Under the stdlib `ThreadingHTTPServer` stack microbatched throughput
+plateaued on thread-per-connection handoffs and per-request header dict
+construction, not on the scorer (host-side gate: 0.53 ms bare scorer vs
+1.27 ms through the stack, BENCH_r06). This module replaces that stack
+for the serve plane:
 
   - a reactor thread multiplexes persistent keep-alive connections
     through a `selectors` readiness loop (accept + recv + incremental

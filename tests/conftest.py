@@ -23,10 +23,14 @@ if "xla_force_host_platform_device_count" not in flags:
 
 import jax  # noqa: E402
 
-# The env var alone is not enough where a site customization pre-selects a
-# platform; the config update is authoritative as long as no backend has
-# been initialized yet.
+# Authoritative even when something imported jax before the env
+# mutation above, as long as no backend has been initialized yet.
 jax.config.update("jax_platforms", "cpu")
+
+# The program places a persistent compile cache (utils.device); the
+# suite keeps compiling from nothing, as it always has, so that one
+# run's executables never decide what the next run's tests see.
+jax.config.update("jax_enable_compilation_cache", False)
 
 import pytest  # noqa: E402
 

@@ -121,7 +121,12 @@ class TestStatus:
     def test_status(self, mem_registry):
         info = ops.status(mem_registry)
         assert info["storage"] == "ok"
-        assert info["platform"] == "cpu"
+        # the device is probed from a child: status itself stays off it
+        assert info["device"]["platform"] == "cpu"
+        assert info["device"]["device_count"] >= 1
+        assert info["native"] == {"eventlog": True}
+        from predictionio_tpu.utils.device import backend_initialized
+        assert isinstance(backend_initialized(), bool)
 
 
 class TestTrainBatchPredict:

@@ -69,7 +69,8 @@ class TestGates:
             _int_factors(8, 2), n_items=8, rank=2, k=2, bucket=1,
             banned_width=4) is None
         assert fused_topk.shard_local_candidates(
-            8, 2, k=2, bucket=1, banned_width=4) is None
+            8, 2, k=2, bucket=1, banned_width=4,
+            axis=topk_sharded.SHARD_AXIS) is None
 
 
 class TestBucketParity:

@@ -66,10 +66,11 @@ def clean_gc_hooks():
     """Restore gc.callbacks + the per-registry install guard, so a
     test-installed hook can't observe later tests' collections."""
     before = list(gc.callbacks)
-    hooked = set(prof_mod._gc_registries)
+    hooked = set(prof_mod._gc_pending)
     yield
     gc.callbacks[:] = before
-    prof_mod._gc_registries.intersection_update(hooked)
+    for key in set(prof_mod._gc_pending) - hooked:
+        del prof_mod._gc_pending[key]
 
 
 # -- profiler ---------------------------------------------------------------
@@ -160,11 +161,38 @@ class TestGCPauseHook:
         assert install_gc_callbacks(reg) is True
         assert install_gc_callbacks(reg) is False   # idempotent
         gc.collect()
+        assert prof_mod.flush_gc_pauses(reg) >= 1
         fam = reg.snapshot()["pio_gc_pause_seconds"]
         assert fam["type"] == "histogram"
         assert sum(s["count"] for s in fam["series"]) >= 1
         gens = {s["labels"]["generation"] for s in fam["series"]}
         assert "2" in gens          # gc.collect() is a full collection
+
+    def test_collection_while_the_family_lock_is_held(self, clean_gc_hooks):
+        """A collection can start inside a thread that holds the gc
+        histogram's own lock (mid-render): the hook must not take it.
+        Seen on the chip machine: every `/metrics` of an idle event
+        server hung from ~40 s after start."""
+        reg = MetricsRegistry()
+        install_gc_callbacks(reg)
+        hook = gc.callbacks[-1]
+        fam = reg.histogram("pio_gc_pause_seconds", "", labels=(
+            "generation",), buckets=(0.0001, 0.0005, 0.001, 0.005, 0.01,
+                                     0.05, 0.1, 0.5))
+        done = threading.Event()
+
+        def collect_under_the_lock():
+            with fam._lock:
+                hook("start", {})
+                hook("stop", {"generation": 2})
+            done.set()
+
+        t = threading.Thread(target=collect_under_the_lock, daemon=True,
+                             name="pio-test-gc")
+        t.start()
+        assert done.wait(5), "gc hook blocked on the family lock"
+        assert prof_mod.flush_gc_pauses(reg) == 1
+        assert reg.render().count("pio_gc_pause_seconds_count") == 1
 
 
 # -- tsdb ring --------------------------------------------------------------

@@ -211,7 +211,12 @@ class TestShardedSimilar:
             s, ix = sharded(vecs, mask)
             os_, oix = single(vecs, mask)
             assert np.array_equal(ix, oix), f"id mismatch at batch {b}"
-            assert np.array_equal(s, os_)
+            # the normalised factors are irrational, so unlike the
+            # integer-valued top-k parity the two matmuls ([b, 203] vs
+            # [b, 26] per shard) may round the last bit differently:
+            # ids exact, scores within 2 ulp of float32
+            np.testing.assert_allclose(
+                s, os_, rtol=2 * np.finfo(np.float32).eps, atol=0)
             assert ix.max() < 203   # padding columns never leak
 
     def test_all_false_mask_row(self):

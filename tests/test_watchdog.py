@@ -398,6 +398,50 @@ class TestSupervisor:
             sup.stop()
 
 
+    def test_one_chip_per_child(self, tmp_path):
+        """A chip belongs to one process: more children than chips is
+        refused at start and on grow, and on a multi-chip host every
+        slot is pinned to its own chip (stable across a retire)."""
+        from predictionio_tpu.serving.supervisor import (
+            ChildSpec, Supervisor,
+        )
+        from predictionio_tpu.utils.device import chip_env
+
+        def spec(name):
+            out = tmp_path / f"{name}.env"
+            return ChildSpec(name, [
+                sys.executable, "-c",
+                "import os, time; open(%r, 'w').write(os.environ.get("
+                "'TPU_VISIBLE_CHIPS', '-')); time.sleep(60)" % str(out)])
+
+        with pytest.raises(ValueError, match="this host has 1"):
+            Supervisor([spec("a"), spec("b")], chips=1)
+        sup = Supervisor([spec("a"), spec("b")], chips=3,
+                         poll_s=0.05, grace_s=2.0)
+        sup.start()
+        try:
+            _wait(lambda: all((tmp_path / f"{n}.env").exists()
+                              and (tmp_path / f"{n}.env").read_text()
+                              for n in "ab"), msg="children started")
+            assert (tmp_path / "a.env").read_text() == "0"
+            assert (tmp_path / "b.env").read_text() == "1"
+            assert [c["chip"] for c in sup.children()] == [0, 1]
+            sup.grow(spec("c"))
+            assert sup.find("c").chip == 2
+            with pytest.raises(ValueError, match="no free chip"):
+                sup.grow(spec("d"))
+            assert sup.retire("a", grace_s=2.0)
+            sup.grow(spec("d"))              # the freed chip is reused
+            assert sup.find("d").chip == 0
+        finally:
+            sup.stop()
+        assert chip_env(2)["TPU_VISIBLE_CHIPS"] == "2"
+        # one chip (or no accelerator host): nothing to pin
+        solo = Supervisor([spec("s")], chips=1)
+        assert solo.find("s").chip is None
+        assert Supervisor([spec("n")]).find("n").chip is None
+
+
 # -- SIGTERM drain under load -------------------------------------------------
 
 class TestSignalDrain:
