@@ -26,6 +26,16 @@ entries that stitch under a single 128-bit trace id.
 Background work (refresher ticks/fold-ins, rolling reloads) records
 spans through `background()` into the same ring with `kind=
 "background"`.
+
+Batch cycles: the micro-batcher's drainer owns one `BatchTrace` per
+cycle (one window, one take, one device call). `stage(name)` is the one
+instrument of that path: inside a cycle it stamps the interval into the
+record (from which `pio_serve_stage_seconds{stage=...}` is observed at
+the cycle's end, recorder on or off), and on any thread it enters a
+`jax.profiler.TraceAnnotation("pio:batch.<name>")`, so the interval
+lies in the `/host:CPU` plane of whatever profile is being taken. With
+the recorder enabled every cycle lands in the ring as `kind="batch"`
+and its members point at it by `batch_id`.
 """
 
 from __future__ import annotations
@@ -36,6 +46,7 @@ import hmac
 import json
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -57,7 +68,7 @@ S_HANDLER = 2        # worker picked it up, handler entered
 S_AUTH = 3           # authenticated + admitted (tenancy)
 S_ENQ = 4            # enqueued on its micro-batch lane
 S_DRAIN = 5          # drained out of the lane into a batch
-S_EXEC = 6           # model executed (device exec + d2h complete)
+S_EXEC = 6           # the member's batch came back from predict_batch
 S_SPLICE = 7         # response payload spliced/encoded
 S_DONE = 8           # handler returned the response object
 S_SENT = 9           # response bytes written to the socket
@@ -70,7 +81,7 @@ _SEG_NAMES = {
     S_AUTH: "auth_admission",
     S_ENQ: "batch_submit",
     S_DRAIN: "lane_wait",
-    S_EXEC: "device_exec",
+    S_EXEC: "batch",
     S_SPLICE: "response_splice",
     S_DONE: "respond",
     S_SENT: "wire_write",
@@ -78,10 +89,32 @@ _SEG_NAMES = {
 
 _log = get_logger("trace")
 
-# Latency buckets for pio_serve_seconds (end-to-end, wire to wire);
-# public: the server creates the same family for the tracing-off path.
+# Latency buckets for pio_serve_seconds and the two wire waits beside
+# it; public: the server creates the same families in its own registry.
 SERVE_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                  0.25, 0.5, 1.0, 2.5, 5.0)
+# One meaning, recorder on or off: the handler's own interval. What the
+# request waited before it (pio_wire_worker_wait_seconds) and after it
+# (pio_wire_reply_seconds) has its own family; a kept trace's
+# duration_ms is the whole, wire read to wire write.
+SERVE_SECONDS_HELP = ("Serve latency inside the /queries.json handler "
+                      "(handler entry to handler return; lane wait "
+                      "included, worker wait and reply not)")
+
+# The stages of one batch cycle, in the order the drainer passes them.
+# They tile the cycle: a stage runs from the close of the stage before
+# it to its own close, so the glue between two blocks is charged to the
+# later one; `predict` is the parent of lookup..unpack.
+STAGES = ("window", "take", "supplement", "predict", "lookup", "pack",
+          "launch", "fetch", "unpack", "serve", "encode", "wake")
+_STAGE_IX = {name: i for i, name in enumerate(STAGES)}
+_IX_FETCH = _STAGE_IX["fetch"]
+STAGE_SECONDS_HELP = (
+    "Serve-chain stage wall time. Per request: extract, feedback. Per "
+    "batch cycle on the drainer's thread: window, take, supplement, "
+    "lookup, pack, launch, fetch, unpack, serve, encode, wake tile the "
+    "cycle. Sums of others: predict = lookup..unpack, cycle = all "
+    "eleven, host = cycle - fetch")
 
 
 class PendingTrace:
@@ -91,7 +124,7 @@ class PendingTrace:
     __slots__ = ("st", "trace_id", "span_id", "parent_id", "sampled",
                  "kind", "app", "route", "status", "dispatch", "error",
                  "batch_id", "batch_size", "rid", "extra", "reactor",
-                 "query")
+                 "query", "serve_s")
 
     def __init__(self):
         self.st = [0.0] * N_STAMPS
@@ -112,6 +145,44 @@ class PendingTrace:
         self.reactor = -1        # accept-shard index (set by the wire)
         self.query = None        # (user, num) tuple or query dict —
         #                          replayable by the reload canary
+        self.serve_s = 0.0       # the handler's own interval, as the
+        #                          handler observed it (pio_serve_seconds)
+
+
+class BatchTrace:
+    """One batch cycle of the micro-batcher's drainer, made like
+    `PendingTrace`: slots and scalars, nothing built until the cycle
+    ends. `start[i]` is the first start of stage i, `dur[i]` the sum of
+    its intervals (a batch past the biggest bucket packs, launches and
+    fetches more than once). `hist` maps a stage's name (and `cycle`,
+    `host`) to its child of pio_serve_stage_seconds, so code below the
+    server never looks up a registry. A `solo` record is one
+    `predict_batch` outside the drainer (no batcher, canary, batch
+    predict): it observes the stages it passed and is neither a cycle
+    nor kept."""
+
+    __slots__ = ("start", "dur", "t_begin", "t_last", "last", "closed",
+                 "batch_id", "rows", "bucket", "path", "hist", "solo")
+
+    def __init__(self, hist=None, t0: float = 0.0, solo: bool = False):
+        self.start = [0.0] * len(STAGES)
+        self.dur = [0.0] * len(STAGES)
+        self.t_begin = self.t_last = t0 if t0 > 0.0 else time.perf_counter()
+        self.last = -1           # the stage that closed last
+        self.closed = 0          # how many closed so far
+        self.batch_id = 0
+        self.rows = 0
+        self.bucket = 0          # rows the plan padded the call to
+        self.path = ""           # host|device|sharded|fused, as the
+        #                          call that dispatched set it
+        self.hist = hist
+        self.solo = solo
+
+    def cycle_s(self) -> float:
+        return self.t_last - self.t_begin
+
+    def host_s(self) -> float:
+        return self.t_last - self.t_begin - self.dur[_IX_FETCH]
 
 
 # -- X-PIO-Trace codec (signed-header compatible with X-PIO-App) -------------
@@ -190,8 +261,7 @@ class TraceRecorder:
             "pio_trace_kept_total", "Traces kept in the ring, by reason",
             labels=("why",))
         self._serve_hist = self._metrics.histogram(
-            "pio_serve_seconds",
-            "End-to-end serve latency (wire read to wire write)",
+            "pio_serve_seconds", SERVE_SECONDS_HELP,
             labels=("app",), buckets=SERVE_BUCKETS)
         # app -> histogram child: labels() rebuilds key tuples and takes
         # the family lock per call; finish() runs once per request, so
@@ -213,14 +283,6 @@ class TraceRecorder:
         if random.random() < self.sample:
             p.sampled = True
         return p
-
-    def on_sent(self, raw) -> None:
-        """Wire write completed: stamp S_SENT and finish the trace."""
-        p = raw.trace
-        if p is None:
-            return
-        p.st[S_SENT] = time.perf_counter()
-        self.finish(p)
 
     # -- finish / keep -------------------------------------------------------
     def finish(self, p: PendingTrace) -> None:
@@ -253,12 +315,20 @@ class TraceRecorder:
             if self.slow_ms > 0.0 and dur * 1000.0 >= self.slow_ms:
                 self._slow_log(p, dur)
         if p.kind == "serve":
+            # the same interval the handler observes with the recorder
+            # off; a handler that died before it could say falls back
+            # to its two stamps
+            serve_s = p.serve_s
+            if serve_s <= 0.0 and st[S_HANDLER] > 0.0:
+                serve_s = max(
+                    (st[S_DONE] if st[S_DONE] > 0.0 else tend)
+                    - st[S_HANDLER], 0.0)
             child = self._hist_by_app.get(p.app)
             if child is None:
                 child = self._serve_hist.labels(app=p.app)
                 if len(self._hist_by_app) < 1024:
                     self._hist_by_app[p.app] = child
-            child.observe(dur, exemplar=p.trace_id if why else None)
+            child.observe(serve_s, exemplar=p.trace_id if why else None)
 
     def _tail_slow_locked(self, dur: float) -> bool:
         """Frugal-streaming quantile step toward p90; True once the
@@ -367,13 +437,46 @@ class TraceRecorder:
         with self._lock:
             self._ring.append(entry)
 
+    # -- batch cycles --------------------------------------------------------
+    def record_batch(self, bt: BatchTrace) -> None:
+        """One finished cycle into the ring (`kind="batch"`): rows,
+        bucket, path and a span per stage it passed, on the cycle's own
+        time scale. Kept members carry the same `batch_id`."""
+        t0 = bt.t_begin
+        spans = [{"name": STAGES[i],
+                  "start_ms": round((bt.start[i] - t0) * 1000.0, 3),
+                  "dur_ms": round(bt.dur[i] * 1000.0, 3)}
+                 for i in range(len(STAGES)) if bt.start[i] > 0.0]
+        entry = {
+            "trace_id": _new_trace_id(),
+            "span_id": _new_span_id(),
+            "parent_id": "",
+            "kind": "batch",
+            "name": "batch",
+            "app": "",
+            "status": 0,
+            "dispatch": bt.path,
+            "duration_ms": round(bt.cycle_s() * 1000.0, 3),
+            "host_ms": round(bt.host_s() * 1000.0, 3),
+            "keep": "batch",
+            "ts": time.time(),
+            "batch_id": bt.batch_id,
+            "rows": bt.rows,
+            "bucket": bt.bucket,
+            "spans": spans,
+        }
+        with self._lock:
+            self._ring.append(entry)
+
     # -- export --------------------------------------------------------------
     def snapshot(self, app: Optional[str] = None,
                  min_ms: Optional[float] = None,
                  trace_id: Optional[str] = None,
-                 limit: int = 0) -> List[Dict[str, Any]]:
+                 limit: int = 0,
+                 batch_id: Optional[int] = None) -> List[Dict[str, Any]]:
         """Ring contents newest-first, filtered by app / min duration /
-        trace id — the body of `/traces.json`."""
+        trace id / batch id (a batch and its kept members) — the body
+        of `/traces.json`."""
         with self._lock:
             entries = list(self._ring)
         entries.reverse()
@@ -384,6 +487,8 @@ class TraceRecorder:
             if min_ms is not None and e.get("duration_ms", 0.0) < min_ms:
                 continue
             if trace_id is not None and e.get("trace_id") != trace_id:
+                continue
+            if batch_id is not None and e.get("batch_id") != batch_id:
                 continue
             out.append(e)
             if limit and len(out) >= limit:
@@ -460,10 +565,18 @@ def new_stamps(t0: float) -> Optional[PendingTrace]:
 
 
 def on_sent(raw) -> None:
-    """Wire hook: response bytes on the socket — finish the trace."""
+    """Wire hook: response bytes on the socket. Observes the reply's
+    interval (handler return to here) into whatever the handler left on
+    `raw.reply_obs`, recorder on or off, and finishes the trace."""
+    now = time.perf_counter()
+    obs = raw.reply_obs
+    if obs is not None:
+        obs.observe(max(now - raw.t_done, 0.0))
+    p = raw.trace
     rec = _REC
-    if rec is not None:
-        rec.on_sent(raw)
+    if p is not None and rec is not None:
+        p.st[S_SENT] = now
+        rec.finish(p)
 
 
 def stamp(raw, slot: int) -> None:
@@ -528,12 +641,15 @@ def child_header(p: PendingTrace) -> str:
 def annotate(raw, status: int = 0, app: Optional[str] = None,
              route: Optional[str] = None, dispatch: Optional[str] = None,
              error: Optional[str] = None,
-             kind: Optional[str] = None, query=None) -> None:
+             kind: Optional[str] = None, query=None,
+             serve_s: float = 0.0) -> None:
     """Attach scalar attributes to a RawRequest's pending trace —
     keyword scalars only, nothing allocated on the hot path."""
     p = raw.trace
     if p is None:
         return
+    if serve_s:
+        p.serve_s = serve_s
     if status:
         p.status = status
     if app is not None:
@@ -584,6 +700,146 @@ def add_span(p: Optional[PendingTrace], name: str, t0: float,
     p.extra.append((name, t0, t1))
 
 
+# -- batch cycles: the record of the thread's cycle, and the stage helper -----
+_TLS = threading.local()
+_ANNOTATION = None
+
+
+def _annotation():
+    """`jax.profiler.TraceAnnotation`, once this process has loaded jax
+    itself. `obs` never imports it: an event server or a router has no
+    use for it and must not load it, and a process that computes has it
+    in `sys.modules` long before its first stage."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        try:
+            _ANNOTATION = jax.profiler.TraceAnnotation
+        except AttributeError:       # not loaded (yet): ask again later
+            return None
+    return _ANNOTATION
+
+
+class _Stage:
+    """One interval of `stage()`: a context manager, and the handle of
+    the `stage_open` / `stage_close` pair."""
+
+    __slots__ = ("name", "ix", "bt", "t0", "mark", "ann")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.ix = _STAGE_IX.get(name, -1)
+        self.bt = None
+        self.ann = None
+
+    def __enter__(self):
+        bt = getattr(_TLS, "batch", None)
+        if bt is not None and self.ix >= 0:
+            self.bt = bt
+            self.t0 = bt.t_last
+            self.mark = bt.closed
+            if bt.start[self.ix] == 0.0:
+                bt.start[self.ix] = bt.t_last
+        cls = _annotation()
+        if cls is not None:
+            self.ann = cls("pio:batch." + self.name)
+            self.ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        bt = self.bt
+        if bt is not None:
+            now = time.perf_counter()
+            if bt.closed != self.mark:
+                # stages closed inside this one: it is their parent, and
+                # what it did after the last of them is that one's
+                bt.dur[bt.last] += now - bt.t_last
+            bt.dur[self.ix] += now - self.t0
+            bt.t_last = now
+            bt.last = self.ix
+            bt.closed += 1
+        return False
+
+
+def stage(name: str) -> _Stage:
+    """`with trace.stage("pack"):` — one interval of the serve chain.
+    On the thread of a batch cycle it is stamped into the cycle's
+    record and so observed into pio_serve_stage_seconds{stage=name};
+    on any thread it is a `pio:batch.<name>` span of the profile being
+    taken, if one is. Outside a cycle (eval, batch predict, a
+    template's own call into ops/) it is the span only."""
+    return _Stage(name)
+
+
+def stage_open(name: str) -> _Stage:
+    """`stage()` where a `with` does not fit; hand the result to
+    `stage_close`."""
+    return _Stage(name).__enter__()
+
+
+def stage_close(handle: _Stage) -> None:
+    handle.__exit__(None, None, None)
+
+
+def batch_begin(hist=None, t0: float = 0.0,
+                solo: bool = False) -> BatchTrace:
+    """Open this thread's cycle record. `t0` is where the cycle before
+    it ended (what `batch_end` returned), so that cycles tile the
+    drainer's life as stages tile a cycle."""
+    bt = BatchTrace(hist, t0, solo)
+    _TLS.batch = bt
+    return bt
+
+
+def current_batch() -> Optional[BatchTrace]:
+    return getattr(_TLS, "batch", None)
+
+
+def batch_drop() -> None:
+    """Close the thread's record unobserved (a window nothing came in:
+    the drainer retires)."""
+    _TLS.batch = None
+
+
+def batch_end(bt: BatchTrace) -> float:
+    """The cycle is over: observe every stage it passed, `cycle` and
+    `host` into the record's histogram children, and with the recorder
+    on put it in the ring. Returns the instant the cycle ended."""
+    _TLS.batch = None
+    hist = bt.hist
+    if hist is not None:
+        for i in range(len(STAGES)):
+            if bt.start[i] > 0.0:
+                hist[STAGES[i]].observe(bt.dur[i])
+        if not bt.solo:
+            hist["cycle"].observe(bt.cycle_s())
+            hist["host"].observe(bt.host_s())
+    if not bt.solo:
+        rec = _REC
+        if rec is not None and rec.enabled:
+            rec.record_batch(bt)
+    return bt.t_last
+
+
+def note_dispatch(path: str, bucket: int = 0) -> None:
+    """The plan call says which path it took and how many rows it
+    padded to; lands on the thread's cycle record, if it has one."""
+    bt = getattr(_TLS, "batch", None)
+    if bt is not None:
+        bt.path = path
+        if bucket:
+            bt.bucket = bucket
+
+
+def stage_children(family) -> Dict[str, Any]:
+    """Every cycle stage's child of a pio_serve_stage_seconds family,
+    resolved once (the drainer then observes without a labels() call)."""
+    return {name: family.labels(stage=name)
+            for name in STAGES + ("cycle", "host")}
+
+
 # -- contextvar plumbing for the generic (non-fast) route --------------------
 _current: "contextvars.ContextVar[Optional[PendingTrace]]" = \
     contextvars.ContextVar("pio_trace", default=None)
@@ -629,6 +885,7 @@ def traces_json_body(query_get) -> bytes:
     min_ms = query_get("min_ms") or query_get("min_duration_ms")
     tid = query_get("trace_id")
     limit = query_get("limit")
+    bid = query_get("batch_id")
     try:
         min_ms_f = float(min_ms) if min_ms else None
     except ValueError:
@@ -637,7 +894,12 @@ def traces_json_body(query_get) -> bytes:
         limit_i = int(limit) if limit else 0
     except ValueError:
         limit_i = 0
+    try:
+        bid_i = int(bid) if bid else None
+    except ValueError:
+        bid_i = None
     entries = rec.snapshot(app=app or None, min_ms=min_ms_f,
-                           trace_id=tid or None, limit=limit_i)
+                           trace_id=tid or None, limit=limit_i,
+                           batch_id=bid_i)
     return json.dumps({"traces": entries, "count": len(entries),
                        "enabled": rec.enabled}).encode()

@@ -51,6 +51,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from predictionio_tpu.obs import trace
+
 NEG_INF = -1e30
 
 # [b, n_items] score cells below which the host path wins. Environment-
@@ -212,23 +214,15 @@ class DispatchPolicy:
 
 DISPATCH_POLICY = DispatchPolicy()
 
-# most recent dispatch path taken by any topk call — read by the
-# micro-batch drainer to tag member traces (host|device|sharded|fused).
-# A plain module global, not thread-local: multi-algorithm fan-out runs
-# predict in pool threads while the drainer reads from its own, and the
-# benign last-writer-wins race matches DISPATCH_COUNTS' semantics.
-_LAST_PATH = ""
-
-
-def last_dispatch() -> str:
-    """The dispatch path of the most recent topk call ("" before any)."""
-    return _LAST_PATH
-
 
 def _record_dispatch(path: str, cells: int,
-                     seconds: Optional[float] = None) -> None:
-    global _LAST_PATH
-    _LAST_PATH = path
+                     seconds: Optional[float] = None,
+                     bucket: int = 0) -> None:
+    """Count one dispatch by the path that served it, feed the policy's
+    EWMAs, and tell the batch cycle this thread is in (if it is in one:
+    obs/trace.BatchTrace) which path it took and the rows it padded to
+    — the drainer tags the cycle's member traces from there."""
+    trace.note_dispatch(path, bucket)
     DISPATCH_COUNTS[path] += 1
     try:
         _dispatch_total().labels(path=path).inc()
@@ -429,16 +423,19 @@ def topk_scores_filtered(user_vecs, item_factors, banned_lists, *, k: int):
         return out if traced else jax.device_get(out)
     # host inputs: pad batch to a power of two to bound jit variants
     t0 = time.perf_counter()
-    bp = _next_pow2(b)
-    vecs = np.zeros((bp, user_vecs.shape[1]), np.float32)
-    vecs[:b] = user_vecs
-    banned_pad = np.full((bp, max(wp, 1)), n_items, np.int32)
-    banned_pad[:b] = banned_np
-    out = _topk_scores_banned_device(
-        jnp.asarray(vecs), device_resident(item_factors),
-        jnp.asarray(banned_pad), k=k, has_bans=wp > 0)
-    scores, ixs = jax.device_get(out)
-    _record_dispatch("device", cells, time.perf_counter() - t0)
+    with trace.stage("pack"):
+        bp = _next_pow2(b)
+        vecs = np.zeros((bp, user_vecs.shape[1]), np.float32)
+        vecs[:b] = user_vecs
+        banned_pad = np.full((bp, max(wp, 1)), n_items, np.int32)
+        banned_pad[:b] = banned_np
+    with trace.stage("launch"):
+        out = _topk_scores_banned_device(
+            jnp.asarray(vecs), device_resident(item_factors),
+            jnp.asarray(banned_pad), k=k, has_bans=wp > 0)
+    with trace.stage("fetch"):
+        scores, ixs = jax.device_get(out)
+    _record_dispatch("device", cells, time.perf_counter() - t0, bp)
     return scores[:b], ixs[:b]
 
 
@@ -685,17 +682,21 @@ class BucketedTopK:
                 f"BucketedTopK bucket {bucket} not warmed; call warm() "
                 "at deploy time")
         t0 = time.perf_counter()
-        vecs = np.zeros((bucket, self.rank), np.float32)
-        vecs[:b] = user_vecs
-        banned = np.full((bucket, self.banned_width), self.n_items,
-                         np.int32)
-        for row, bl in enumerate(banned_lists):
-            if len(bl):
-                banned[row, :len(bl)] = np.asarray(bl, np.int32)  # lint: ok
-        scores, ixs = jax.device_get(exe(vecs, self.factors, banned))
+        with trace.stage("pack"):
+            vecs = np.zeros((bucket, self.rank), np.float32)
+            vecs[:b] = user_vecs
+            banned = np.full((bucket, self.banned_width), self.n_items,
+                             np.int32)
+            for row, bl in enumerate(banned_lists):
+                if len(bl):
+                    banned[row, :len(bl)] = np.asarray(bl, np.int32)  # lint: ok
+        with trace.stage("launch"):
+            out = exe(vecs, self.factors, banned)
+        with trace.stage("fetch"):
+            scores, ixs = jax.device_get(out)
         _record_dispatch(
             "fused" if bucket in self._fused_sizes else "device",
-            bucket * self.n_items, time.perf_counter() - t0)
+            bucket * self.n_items, time.perf_counter() - t0, bucket)
         return scores[:b], ixs[:b]
 
 

@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from predictionio_tpu.obs import trace
 from predictionio_tpu.ops import topk
 from predictionio_tpu.ops.topk import (
     DEFAULT_SERVE_BUCKETS, NEG_INF, BucketedSimilar, BucketedTopK,
@@ -483,16 +484,20 @@ class ShardedBucketedTopK(_ShardedPlanBase):
         bucket = self._bucket_for(b)
         exe = self._require_exe(bucket)
         t0 = time.perf_counter()
-        vecs = np.zeros((bucket, self.rank), np.float32)
-        vecs[:b] = user_vecs
-        banned = np.full((bucket, self.banned_width), self.n_items,
-                         np.int32)
-        for row, bl in enumerate(banned_lists):
-            if len(bl):
-                banned[row, :len(bl)] = np.asarray(bl, np.int32)  # lint: ok
-        scores, ixs = jax.device_get(exe(vecs, self.factors, banned))
+        with trace.stage("pack"):
+            vecs = np.zeros((bucket, self.rank), np.float32)
+            vecs[:b] = user_vecs
+            banned = np.full((bucket, self.banned_width), self.n_items,
+                             np.int32)
+            for row, bl in enumerate(banned_lists):
+                if len(bl):
+                    banned[row, :len(bl)] = np.asarray(bl, np.int32)  # lint: ok
+        with trace.stage("launch"):
+            out = exe(vecs, self.factors, banned)
+        with trace.stage("fetch"):
+            scores, ixs = jax.device_get(out)
         _record_dispatch("sharded", bucket * self.n_items,
-                         time.perf_counter() - t0)
+                         time.perf_counter() - t0, bucket)
         return scores[:b], ixs[:b]
 
 

@@ -102,9 +102,11 @@ class _ServeInstruments:
     def __init__(self, metrics: Optional[MetricsRegistry] = None):
         metrics = metrics if metrics is not None else get_registry()
         self.stage = metrics.histogram(
-            "pio_serve_stage_seconds",
-            "Serve-chain stage wall time (extract/supplement/predict/"
-            "serve/feedback)", labels=("stage",))
+            "pio_serve_stage_seconds", trace.STAGE_SECONDS_HELP,
+            labels=("stage",))
+        # a batch cycle's stages, resolved once: the cycle's record
+        # (obs/trace.BatchTrace) carries them down to ops/
+        self.cycle_stages = trace.stage_children(self.stage)
         self.algo = metrics.histogram(
             "pio_serve_algo_predict_seconds",
             "Per-algorithm batch_predict wall time", labels=("algo",))
@@ -119,6 +121,19 @@ class _ServeInstruments:
             "pio_queue_delay_seconds",
             "Micro-batch enqueue->drain latency (feeds the adaptive "
             "shed decision)")
+        # a /queries.json request's life is four adjoining intervals:
+        # worker wait, the handler (pio_serve_seconds), of which lane
+        # wait (pio_queue_delay_seconds), and reply. Each has an exact
+        # sum / count, recorder on or off.
+        self.worker_wait = metrics.histogram(
+            "pio_wire_worker_wait_seconds",
+            "First read of a /queries.json request's bytes to handler "
+            "entry: the time it waited for a wire worker",
+            buckets=trace.SERVE_BUCKETS)
+        self.reply = metrics.histogram(
+            "pio_wire_reply_seconds",
+            "/queries.json handler return to the response's last byte "
+            "written to the socket", buckets=trace.SERVE_BUCKETS)
         # `app` on the feedback families follows the shed-metric
         # convention: the authenticated tenant, "" with tenancy off
         self.feedback = metrics.counter(
@@ -401,7 +416,9 @@ class _Deployment:
 
     def predict_batch(self, queries: Sequence[Any]) -> List[Any]:
         """supplement -> per-algo batch_predict -> serve, for a batch;
-        each stage lands in pio_serve_stage_seconds.
+        each stage lands in pio_serve_stage_seconds through the batch
+        cycle's record — the drainer's, or for a call from anywhere
+        else (no batcher, canary, batch predict) a solo one of its own.
 
         Per-algorithm error isolation: one failing algorithm is dropped
         from the ensemble for this batch (counted in
@@ -413,6 +430,16 @@ class _Deployment:
         device dispatch releases the GIL, so independent algorithms'
         predict work overlaps; ordering and the isolation contract are
         unchanged (results land positionally)."""
+        obs = self.obs
+        solo = (trace.batch_begin(obs.cycle_stages, solo=True)
+                if trace.current_batch() is None else None)
+        try:
+            return self._predict_batch(queries)
+        finally:
+            if solo is not None:
+                trace.batch_end(solo)
+
+    def _predict_batch(self, queries: Sequence[Any]) -> List[Any]:
         obs = self.obs
 
         def run_one(i, a, m):
@@ -429,10 +456,13 @@ class _Deployment:
                     degraded=len(self.algos) > 1)
                 return None, e
 
-        with obs.stage.labels(stage="supplement").time():
+        with trace.stage("supplement"):
             supplemented = [self.serving.supplement(q) for q in queries]
         indexed = list(enumerate(supplemented))
-        with obs.stage.labels(stage="predict").time():
+        # one algorithm predicts on this thread and its stages (lookup,
+        # pack, launch, fetch, unpack) are predict's children; several
+        # run on the algo pool's threads, which have no cycle record
+        with trace.stage("predict"):
             if len(self.algos) == 1:
                 outcomes = [run_one(0, self.algos[0], self.models[0])]
             else:
@@ -445,7 +475,7 @@ class _Deployment:
         alive = [pa for pa in per_algo if pa is not None]
         if not alive:
             raise errors[0]
-        with obs.stage.labels(stage="serve").time():
+        with trace.stage("serve"):
             return [self.serving.serve(q, [pa[i] for pa in alive])
                     for i, q in enumerate(queries)]
 
@@ -721,22 +751,34 @@ class _MicroBatcher:
                                       budget_s=self.submit_timeout_s)
         wd_beat.attach()
         self._drain_beat = wd_beat
+        # one record a cycle (obs/trace.BatchTrace); each opens where
+        # the one before it closed, so cycles tile the drainer's life
+        # as stages tile a cycle
+        stages = self.obs.cycle_stages
+        t_end = 0.0
         try:
             while True:
                 wd_beat.tick()
+                bt = trace.batch_begin(stages, t_end)
+                h = trace.stage_open("window")
                 with self._lock:
                     # wait out the window — but a full batch forming
                     # mid-window notifies the condition and ships NOW
                     self._full.wait_for(
                         lambda: len(self._queue) >= self.batch_max,
                         timeout=self.window_s)
+                    trace.stage_close(h)
+                    h = trace.stage_open("take")
                     batch = self._queue.take(self.batch_max)
                     self.obs.queue_depth.set(float(len(self._queue)))
                     if not batch:
                         # nothing arrived during the window: retire. The
                         # flag is cleared under the same lock any submit
                         # checks, so the next arrival starts a fresh
-                        # drainer; close() waiters re-check now.
+                        # drainer; close() waiters re-check now. The
+                        # empty window is no cycle and is not observed.
+                        trace.stage_close(h)
+                        trace.batch_drop()
                         self._draining = False
                         self._full.notify_all()
                         return
@@ -748,9 +790,13 @@ class _MicroBatcher:
                             delay - self._delay_ewma)
                         self._queue.observe_delay(tenant, delay)
                         trace.mark(pend, trace.S_DRAIN)
+                    trace.stage_close(h)
+                bt.batch_id = next(self._batch_seq)
+                bt.rows = len(batch)
                 t0 = time.perf_counter()
-                self._process(batch)
+                self._process(batch, bt)
                 dt = time.perf_counter() - t0
+                t_end = trace.batch_end(bt)
                 with self._lock:
                     # blend into the AGED estimate: recovering from a
                     # stall starts from the decayed value instead of
@@ -799,60 +845,63 @@ class _MicroBatcher:
         with self._lock:
             self._closed = False
 
-    def _process(self, pending: List[tuple]) -> None:
-        if not pending:
-            return
-        n = len(pending)
-        self.obs.batch_size.observe(float(n))  # lint: ok (host int)
-        pow2 = 1
-        while pow2 < n:
-            pow2 <<= 1
-        with self._lock:
-            self._size_counts[pow2] = self._size_counts.get(pow2, 0) + 1
-        from predictionio_tpu.ops.topk import last_dispatch
-        # group by deployment (reload may swap mid-flight)
-        by_dep: Dict[int, List] = {}
-        for item in pending:
-            by_dep.setdefault(id(item[0]), []).append(item)
+    def _process(self, pending: List[tuple], bt) -> None:
+        """One taken batch through predict, encode and wake; `bt` is the
+        cycle's record (obs/trace.BatchTrace)."""
+        with trace.stage("take"):
+            n = len(pending)
+            self.obs.batch_size.observe(float(n))  # lint: ok (host int)
+            pow2 = 1
+            while pow2 < n:
+                pow2 <<= 1
+            with self._lock:
+                self._size_counts[pow2] = \
+                    self._size_counts.get(pow2, 0) + 1
+            # group by deployment (reload may swap mid-flight)
+            by_dep: Dict[int, List] = {}
+            for item in pending:
+                by_dep.setdefault(id(item[0]), []).append(item)
         for items in by_dep.values():
             dep = items[0][0]
             queries = [item[1] for item in items]
             try:
                 results = dep.predict_batch(queries)
-                disp = last_dispatch()
-                bid = next(self._batch_seq)
-                for item in items:
-                    p = item[6]
-                    if p is not None:
-                        trace.mark(p, trace.S_EXEC)
-                        p.batch_id = bid
-                        p.batch_size = len(items)
-                        if disp:
-                            p.dispatch = disp
-                wires: Optional[List[Optional[bytes]]] = None
-                if self.encoder is not None:
-                    try:
-                        wires = self.encoder(dep, results)
-                    except Exception:
-                        wires = None     # encoder bugs degrade, not fail
-                for i, ((_, _, done, slot, _, _, p), r) in enumerate(
-                        zip(items, results)):
-                    slot["result"] = r
-                    if wires is not None and wires[i] is not None:
-                        slot["wire"] = wires[i]
-                    trace.mark(p, trace.S_SPLICE)
-                    done.set()
+                with trace.stage("encode"):
+                    for item in items:
+                        p = item[6]
+                        if p is not None:
+                            trace.mark(p, trace.S_EXEC)
+                            p.batch_id = bt.batch_id
+                            p.batch_size = len(items)
+                            if bt.path:
+                                p.dispatch = bt.path
+                    wires: Optional[List[Optional[bytes]]] = None
+                    if self.encoder is not None:
+                        try:
+                            wires = self.encoder(dep, results)
+                        except Exception:
+                            wires = None  # encoder bugs degrade, not fail
+                with trace.stage("wake"):
+                    for i, ((_, _, done, slot, _, _, p), r) in enumerate(
+                            zip(items, results)):
+                        slot["result"] = r
+                        if wires is not None and wires[i] is not None:
+                            slot["wire"] = wires[i]
+                        trace.mark(p, trace.S_SPLICE)
+                        done.set()
             except Exception as e:
-                for _, _, done, slot, _, _, p in items:
-                    slot["error"] = e
-                    trace.annotate_pending(p, error=type(e).__name__)
-                    done.set()
+                with trace.stage("wake"):
+                    for _, _, done, slot, _, _, p in items:
+                        slot["error"] = e
+                        trace.annotate_pending(p, error=type(e).__name__)
+                        done.set()
         hook = self.drain_hook
         if hook is not None:
-            try:
-                hook()
-            except Exception:
-                pass           # a wire nudge must never kill the drainer
+            with trace.stage("wake"):
+                try:
+                    hook()
+                except Exception:
+                    pass       # a wire nudge must never kill the drainer
 
 
 class PredictionServer(HTTPServerBase):
@@ -892,16 +941,18 @@ class PredictionServer(HTTPServerBase):
         self._slo = SLOTracker(
             metrics=self.metrics,
             loader=dao_overrides_loader(self.ctx.registry))
-        # end-to-end serve latency. With tracing ON the flight recorder
-        # observes this family itself (wire read -> wire write, with
-        # trace-id exemplars); these prebound children are the direct
-        # observation path when tracing is off, so the histogram exists
-        # either way.
+        # serve latency inside the handler, one interval on both paths
+        # and recorder on or off. With the recorder ON it observes the
+        # family itself when the reply is written (the same interval,
+        # handed over as `serve_s`, with trace-id exemplars); these
+        # prebound children are the direct observation path when it is
+        # off, so the histogram exists either way.
         self._serve_seconds = self.metrics.histogram(
-            "pio_serve_seconds",
-            "End-to-end serve latency (wire read to wire write)",
+            "pio_serve_seconds", trace.SERVE_SECONDS_HELP,
             labels=("app",), buckets=trace.SERVE_BUCKETS)
         self._ss0 = self._serve_seconds.labels(app="")
+        self._worker_wait = self._serve_obs.worker_wait.labels()
+        self._reply = self._serve_obs.reply.labels()
         self._engine_arg = engine
         self._dep: Optional[_Deployment] = None
         self._dep_lock = threading.Lock()
@@ -1483,10 +1534,13 @@ class PredictionServer(HTTPServerBase):
                 (dt - self.avg_serving_sec) / self.request_count)
         if p is None:
             # tracing off (or legacy wire): observe serve latency here;
-            # with tracing on the recorder observes at wire write
+            # with tracing on the recorder observes this same interval
+            # at wire write
             app = tenant.label if tenant is not None else ""
             (self._ss0 if not app
              else self._serve_seconds.labels(app=app)).observe(dt)
+        else:
+            p.serve_s = dt
         out = to_jsonable(prediction)
         if isinstance(out, dict):
             out.update(response_extra)
@@ -1534,6 +1588,8 @@ class PredictionServer(HTTPServerBase):
                     time.perf_counter(), raw=raw)
             user, num = decoded
         t0 = time.perf_counter()
+        if raw.t_read > 0.0:
+            self._worker_wait.observe(t0 - raw.t_read)
         rid = raw.header("X-Request-ID") or ""
         keep = raw.keep_alive
         if raw.trace is not None:
@@ -1625,13 +1681,16 @@ class PredictionServer(HTTPServerBase):
             self._quality.observe_result(app, slot["result"], user,
                                          dep.user_maps)
         trace.annotate(raw, status=200, app=app, route="/queries.json",
-                       query=(user, num))
+                       query=(user, num), serve_s=dt)
         trace.stamp(raw, trace.S_DONE)
         if raw.trace is None:
             # tracing off: direct serve-latency observation (the
-            # recorder observes at wire write when tracing is on)
+            # recorder observes the same dt at wire write when it is on)
             (self._ss0 if not app
              else self._serve_seconds.labels(app=app)).observe(dt)
+        # the reply's interval starts where the handler's ended
+        raw.t_done = t0 + dt
+        raw.reply_obs = self._reply
         return build_response(200, "application/json", wire, rid,
                               keep_alive=keep)
 
@@ -1655,8 +1714,11 @@ class PredictionServer(HTTPServerBase):
         self._slo.record(app, dt, ok=status < 500)
         if raw is not None:
             trace.annotate(raw, status=status, app=app,
-                           route="/queries.json", error=message)
+                           route="/queries.json", error=message,
+                           serve_s=dt)
             trace.stamp(raw, trace.S_DONE)
+            raw.t_done = t0 + dt
+            raw.reply_obs = self._reply
         if raw is None or raw.trace is None:
             (self._ss0 if not app
              else self._serve_seconds.labels(app=app)).observe(dt)
@@ -1773,6 +1835,22 @@ class PredictionServer(HTTPServerBase):
 
         @r.post("/queries.json")
         def queries(req: Request) -> Response:
+            raw = req.raw
+            if raw is not None:
+                # the request's waits on the generic route: the worker
+                # wait ends here and the reply starts where the route
+                # returns or raises; pio_serve_seconds is _serve_one's
+                if raw.t_read > 0.0:
+                    self._worker_wait.observe(
+                        time.perf_counter() - raw.t_read)
+                raw.reply_obs = self._reply
+            try:
+                return _queries(req)
+            finally:
+                if raw is not None:
+                    raw.t_done = time.perf_counter()
+
+        def _queries(req: Request) -> Response:
             # with tenancy on, this is the same contract the event
             # server enforces on ingest: authenticate the app key, then
             # charge the app's rate/concurrency quota (429 + Retry-After

@@ -53,7 +53,8 @@ Checks, per source file:
     fallbacks (e.g. the encoder-declined single serialization). The
     same functions must not build f-strings per request, and may call
     the flight recorder only through its stamp-slot API (stamp/mark/
-    begin_raw/annotate/...) — materialization belongs in on_sent
+    begin_raw/annotate/stage/...) — materialization belongs in on_sent
+    and batch_end
   - tenancy layers (tenancy/, serving/) must not grow tenant-keyed
     containers unboundedly — ``x[...] = ...`` / ``.setdefault(`` on a
     name containing ``tenant``/``lane`` is per-REMOTE-PRINCIPAL state:
@@ -139,10 +140,14 @@ _HOT_ROUTE_FUNCS = ("frame_request", "build_response", "header",
 # the flight-recorder calls allowed on the hot route: stamp-slot writes
 # and deferred annotation only — anything else (materialization, ring
 # access, id generation) allocates or locks per request and belongs in
-# on_sent/finish, which run after the response bytes are queued
+# on_sent/finish, which run after the response bytes are queued. The
+# stage helper (stage / stage_open / stage_close) stamps an interval
+# into the batch cycle's record and enters a profiler span; what it
+# stamped is observed and kept in batch_end, on the drainer's thread.
 _HOT_TRACE_API = ("stamp", "mark", "begin_raw", "annotate",
                   "annotate_pending", "add_span", "on_sent", "new_stamps",
-                  "current", "child_header", "ensure_ids")
+                  "current", "child_header", "ensure_ids",
+                  "stage", "stage_open", "stage_close", "note_dispatch")
 
 # container-name fragments the tenant-growth rule keys on
 _TENANT_NAME_FRAGMENTS = ("tenant", "lane")

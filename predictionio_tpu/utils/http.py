@@ -82,6 +82,9 @@ class Request:
     request_id: str = ""       # assigned by the middleware, never empty there
     route: str = ""            # matched route pattern (metrics label)
     deadline: Optional[Deadline] = None   # from X-PIO-Deadline-Ms / default
+    # the selector wire's frame, if that wire carried the request
+    raw: Optional[RawRequest] = field(default=None, repr=False,
+                                      compare=False)
 
     def json(self) -> Any:
         if not self.body:
@@ -501,7 +504,7 @@ class HTTPServerBase:
             method=raw.method, path=raw.path,
             query={k: v[0] for k, v in raw_q.items()},
             headers=dict(raw.header_items()), body=raw.body,
-            client=raw.client, request_id=rid)
+            client=raw.client, request_id=rid, raw=raw)
         p = raw.trace
         tok = None
         if p is not None:

@@ -58,9 +58,15 @@ openings keep it that way without blinding the flight recorder:
     allocates preallocated stamp slots onto `RawRequest.trace` as a
     request is framed (the wire stamps `.reactor` onto whatever comes
     back so traces attribute accept-shard skew), the other fires after
-    the response bytes hit the socket. Both are None by default and
-    the hot path checks one global before paying anything — tracing
-    off costs two loads.
+    the response bytes hit the socket, for a request that has a trace
+    or whose handler left something on `RawRequest.reply_obs`. Both
+    are None by default and the hot path checks one global before
+    paying anything — tracing off costs two loads.
+  - every framed request carries `t_read`, the `perf_counter` of the
+    first read of its bytes (one clock read per readable event,
+    hooks or no hooks), so a handler can tell how long the request sat
+    in the worker queue; `t_done` and `reply_obs` are the handler's to
+    fill for the sent hook. The wire reads none of the three.
   - `SelectorWire.stats` counts raw wire activity (accepts, framed
     requests, bytes, pipeline high-water, gathered flushes, busy
     workers) as plain ints; the obs layer scrapes `stats_snapshot()`
@@ -185,7 +191,8 @@ class RawRequest:
     legacy path materializes a dict via `header_items()`."""
 
     __slots__ = ("method", "target", "path", "query_string", "head",
-                 "body", "keep_alive", "client", "trace", "_lhead")
+                 "body", "keep_alive", "client", "trace", "_lhead",
+                 "t_read", "t_done", "reply_obs")
 
     def __init__(self, method: str, target: str, head: bytes,
                  client: str = ""):
@@ -200,6 +207,9 @@ class RawRequest:
         self.client = client
         self.trace = None         # PendingTrace stamp slots (obs/trace.py)
         self._lhead: Optional[bytes] = None
+        self.t_read = 0.0         # first read of this request's bytes
+        self.t_done = 0.0         # handler return, by the handler
+        self.reply_obs = None     # .observe(seconds) for the sent hook
 
     def header(self, name: str) -> Optional[str]:
         """Case-insensitive single-header scan over the raw block — no
@@ -634,7 +644,7 @@ class SelectorWire:
 
     def _on_readable(self, conn: _Conn) -> None:
         eof = False
-        if not conn.buf and _STAMP_NEW is not None:
+        if not conn.buf:
             # first bytes of the next request on this connection
             conn.t_read = time.perf_counter()
         n_in = 0
@@ -683,6 +693,7 @@ class SelectorWire:
             if raw is None:
                 break
             del conn.buf[:consumed]
+            raw.t_read = conn.t_read
             sn = _STAMP_NEW
             if sn is not None:
                 raw.trace = sn(conn.t_read)
@@ -884,7 +895,8 @@ class SelectorWire:
         with self.stats.lock:
             self.stats.responses += 1
         cb = _ON_SENT
-        if cb is not None and raw is not None and raw.trace is not None:
+        if cb is not None and raw is not None and (
+                raw.trace is not None or raw.reply_obs is not None):
             try:
                 cb(raw)
             except Exception:

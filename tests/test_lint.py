@@ -418,6 +418,39 @@ def test_hot_route_gate_allows_stamp_api_and_escapes(tmp_path):
     assert not lint.run(tmp_path)
 
 
+def test_hot_route_gate_allows_the_stage_helper_only(tmp_path):
+    # the stage helper stamps into the cycle's record: allowed on the
+    # hot route; opening, ending or keeping a record is not
+    ok = tmp_path / "predictionio_tpu" / "utils" / "wire.py"
+    ok.parent.mkdir(parents=True)
+    ok.write_text(
+        '"""doc"""\n'
+        "from predictionio_tpu.obs import trace\n"
+        "def _fast_queries(raw):\n"
+        "    with trace.stage('pack'):\n"
+        "        pass\n"
+        "    h = trace.stage_open('launch')\n"
+        "    trace.stage_close(h)\n"
+        "    trace.note_dispatch('fused', 64)\n"
+    )
+    assert not lint.run(tmp_path)
+    bad = tmp_path / "predictionio_tpu" / "serving" / "server.py"
+    bad.parent.mkdir(parents=True)
+    bad.write_text(
+        '"""doc"""\n'
+        "from predictionio_tpu.obs import trace\n"
+        "def _fast_queries(raw, hist):\n"
+        "    bt = trace.batch_begin(hist)\n"
+        "    trace.batch_end(bt)\n"
+        "    trace.get_recorder().record_batch(bt)\n"
+    )
+    kinds = "\n".join(lint.run(tmp_path))
+    assert "trace.batch_begin() in hot-route '_fast_queries'" in kinds
+    assert "trace.batch_end() in hot-route '_fast_queries'" in kinds
+    assert "trace.get_recorder() in hot-route '_fast_queries'" in kinds
+    assert "stage_open" in kinds          # the message lists what is allowed
+
+
 def test_hot_route_trace_gate_scoped_to_wire_files(tmp_path):
     # trace materialization outside the wire files is the normal API
     ok = tmp_path / "predictionio_tpu" / "tools" / "page.py"

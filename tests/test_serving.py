@@ -322,6 +322,44 @@ class TestMicroBatch:
             srv.shutdown()
 
 
+class TestStagesWithoutABatcher:
+    @pytest.mark.parametrize("window_ms", [0, 5],
+                             ids=["unbatched", "batched"])
+    def test_predict_batch_stages_observed_either_way(self, trained,
+                                                      window_ms):
+        """With no batcher every query is its own `predict_batch` on a
+        wire worker: it observes the stages it passes through a record
+        of its own, and is no cycle — `window`, `take`, `encode`,
+        `wake`, `cycle` and `host` belong to the drainer alone."""
+        from predictionio_tpu.obs import MetricsRegistry
+        registry, engine, _, _ = trained
+        srv = PredictionServer(
+            ServerConfig(ip="127.0.0.1", port=0, batch_window_ms=window_ms),
+            registry=registry, engine=engine, metrics=MetricsRegistry())
+        srv.start()
+        try:
+            for u in range(3):
+                status, _ = call(srv.port, "POST", "/queries.json",
+                                 {"user": f"u{u}", "num": 2})
+                assert status == 200
+            deadline = time.perf_counter() + 5.0
+            while srv._batcher is not None and srv._batcher._draining \
+                    and time.perf_counter() < deadline:
+                time.sleep(0.005)
+            counts = {key[0]: child.count
+                      for key, child in srv._serve_obs.stage._items()}
+            for name in ("supplement", "predict", "lookup", "unpack",
+                         "serve"):
+                assert counts[name] == 3, (name, counts)
+            drainer_only = ("window", "take", "encode", "wake", "cycle",
+                            "host")
+            for name in drainer_only:
+                assert counts[name] == (3 if window_ms else 0), (name, counts)
+            assert srv._serve_obs.worker_wait.labels().count == 3
+        finally:
+            srv.shutdown()
+
+
 class TestServerKeyAuth:
     """/reload and /stop are key-protected when a server key is
     configured (CreateServer.scala:624-637 authenticate guard)."""

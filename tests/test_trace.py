@@ -32,7 +32,7 @@ from predictionio_tpu.core import CoreWorkflow, EngineParams, RuntimeContext
 from predictionio_tpu.data.event import DataMap, Event
 from predictionio_tpu.data.storage import App, SLOObjective
 from predictionio_tpu.models import recommendation as rec
-from predictionio_tpu.obs import get_registry
+from predictionio_tpu.obs import MetricsRegistry, get_registry
 from predictionio_tpu.obs import trace
 from predictionio_tpu.obs.slo import SLOTracker, dao_overrides_loader
 from predictionio_tpu.serving import (
@@ -353,7 +353,9 @@ class TestServerTraces:
             assert e["batch_id"] >= 1 and e["batch_size"] >= 1
             assert e["dispatch"] in ("host", "device", "sharded", "fused")
             names = {s["name"] for s in e["spans"]}
-            assert "device_exec" in names
+            # the member's segment between drain and splice is named for
+            # what it spans: its batch, not the device
+            assert "batch" in names and "device_exec" not in names
         finally:
             srv.shutdown()
 
@@ -369,8 +371,7 @@ class TestServerTraces:
             assert body["count"] == len(body["traces"]) > 0
             # p99 exemplar on the serve histogram resolves to a kept trace
             hist = get_registry().histogram(
-                "pio_serve_seconds",
-                "End-to-end serve latency (wire read to wire write)",
+                "pio_serve_seconds", trace.SERVE_SECONDS_HELP,
                 labels=("app",), buckets=trace.SERVE_BUCKETS)
             # the series is process-global: earlier suites may have
             # parked the cumulative p99 — and stale exemplars — in
@@ -439,6 +440,358 @@ class TestServerTraces:
             assert "(default)" in body["slo"]
         finally:
             srv.shutdown()
+
+
+# -- batch cycles: one record a cycle, stages that tile it --------------------
+
+_LEAVES = tuple(n for n in trace.STAGES if n != "predict")
+_PREDICT_CHILDREN = ("lookup", "pack", "launch", "fetch", "unpack")
+
+
+def _metrics_server(trained, **cfg):
+    """A server over its own registry, so that a test reads only what
+    its own requests observed."""
+    registry, engine = trained
+    srv = PredictionServer(ServerConfig(ip="127.0.0.1", port=0, **cfg),
+                           registry=registry, engine=engine,
+                           metrics=MetricsRegistry())
+    srv.start()
+    return srv
+
+
+def _hammer(port, callers=6, each=6, banned_every=3):
+    """Concurrent callers, every `banned_every`-th query with a
+    blackList (the generic route; the others take the wire fast path)."""
+    def one(n):
+        for j in range(each):
+            body = {"user": f"u{(n + j) % 20}", "num": 3}
+            if (n + j) % banned_every == 0:
+                body["blackList"] = ["i1", "i2"]
+            status, _ = call(port, "POST", "/queries.json", body)
+            assert status == 200
+    threads = [threading.Thread(target=one, args=(n,))
+               for n in range(callers)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    return callers * each
+
+
+def _drained(srv, timeout=5.0):
+    """Wait until the drainer has retired: a cycle's stages are
+    observed after its waiters are woken."""
+    deadline = time.perf_counter() + timeout
+    while time.perf_counter() < deadline:
+        if not srv._batcher._draining:
+            return
+        time.sleep(0.005)
+    raise AssertionError("the drainer did not retire")
+
+
+def _stage_totals(srv):
+    return {key[0]: (child.count, child.sum)
+            for key, child in srv._serve_obs.stage._items()}
+
+
+class TestBatchCycle:
+    def test_leaf_stages_tile_the_cycle(self, trained):
+        srv = _metrics_server(trained, batch_window_ms=5, batch_max=8)
+        try:
+            _hammer(srv.port)
+            _drained(srv)
+            tot = _stage_totals(srv)
+            cycles, cycle_s = tot["cycle"]
+            assert cycles >= 1 and cycle_s > 0.0
+            leaves = sum(tot[n][1] for n in _LEAVES)
+            assert abs(leaves - cycle_s) <= 0.02 * cycle_s
+            children = sum(tot[n][1] for n in _PREDICT_CHILDREN)
+            assert abs(children - tot["predict"][1]) \
+                <= 0.02 * tot["predict"][1]
+            assert tot["host"][1] == pytest.approx(
+                cycle_s - tot["fetch"][1], rel=1e-9)
+            # every stage of every cycle, and nothing for the empty
+            # windows in which the drainer retired
+            batches = srv._serve_obs.batch_size.labels().count
+            for name in trace.STAGES + ("cycle", "host"):
+                assert tot[name][0] == batches == cycles, name
+        finally:
+            srv.shutdown()
+
+    def test_stages_and_wire_waits_observed_with_recorder_off(self, trained):
+        trace.configure(sample=0.0)
+        srv = _metrics_server(trained, batch_window_ms=5, batch_max=8)
+        try:
+            sent = _hammer(srv.port)
+            _drained(srv)
+            status, text = call(srv.port, "GET", "/metrics")
+            assert status == 200
+            for name in trace.STAGES + ("cycle", "host"):
+                line = next(ln for ln in text.splitlines() if ln.startswith(
+                    f'pio_serve_stage_seconds_count{{stage="{name}"}}'))
+                assert float(line.split()[-1]) >= 1, name
+            obs = srv._serve_obs
+            # both routes, every request: fast path and generic
+            assert obs.worker_wait.labels().count == sent
+            assert obs.queue_delay.labels().count == sent
+            assert srv._ss0.count == sent
+            deadline = time.perf_counter() + 5.0
+            while obs.reply.labels().count < sent \
+                    and time.perf_counter() < deadline:
+                time.sleep(0.005)
+            assert obs.reply.labels().count == sent
+            assert "pio_wire_worker_wait_seconds_count" in text
+            assert "pio_wire_reply_seconds_count" in text
+            assert trace.get_recorder().ring_len() == 0
+        finally:
+            srv.shutdown()
+
+    @pytest.mark.parametrize("banned", [False, True],
+                             ids=["fast_path", "generic_route"])
+    @pytest.mark.parametrize("sample", [0.0, 1.0],
+                             ids=["recorder_off", "recorder_on"])
+    def test_serve_seconds_is_the_handlers_interval(self, trained, sample,
+                                                    banned):
+        """One meaning on both routes, recorder on or off: handler
+        entry to handler return, inside the client's wall time and
+        inside the kept trace's wire-to-wire duration."""
+        registry, engine = trained
+        m = MetricsRegistry()
+        trace.configure(sample=sample, ring=64, metrics=m)
+        srv = PredictionServer(
+            ServerConfig(ip="127.0.0.1", port=0, batch_window_ms=2),
+            registry=registry, engine=engine, metrics=m)
+        srv.start()
+        try:
+            body = {"user": "u1", "num": 3}
+            if banned:
+                body["blackList"] = ["i1"]
+            t0 = time.perf_counter()
+            status, _ = call(srv.port, "POST", "/queries.json", body)
+            wall = time.perf_counter() - t0
+            assert status == 200
+            child = srv._ss0
+            deadline = time.perf_counter() + 5.0
+            while child.count < 1 and time.perf_counter() < deadline:
+                time.sleep(0.005)
+            assert child.count == 1
+            assert 0.0 < child.sum < wall
+            # the window is inside the handler: lane wait is part of it
+            assert child.sum >= 0.002 * 0.5
+            lane = srv._serve_obs.queue_delay.labels()
+            assert lane.count == 1 and lane.sum <= child.sum
+            if sample:
+                entry = _serve_entries(trace.get_recorder().snapshot())[0]
+                assert child.sum * 1000.0 <= entry["duration_ms"]
+                handler_ms = sum(
+                    s["dur_ms"] for s in entry["spans"]
+                    if s["name"] not in ("wire_frame", "worker_queue",
+                                         "wire_write"))
+                assert child.sum * 1000.0 <= handler_ms + 0.01
+        finally:
+            srv.shutdown()
+
+    def test_recorder_observes_the_handed_interval(self):
+        m = MetricsRegistry()
+        rec_ = trace.TraceRecorder(sample=1.0, ring=8, metrics=m)
+        p = trace.PendingTrace()
+        t0 = time.perf_counter() - 1.0
+        p.st[trace.S_WIRE_READ] = t0
+        p.st[trace.S_HANDLER] = t0 + 0.4
+        p.st[trace.S_DONE] = t0 + 0.7
+        p.st[trace.S_SENT] = t0 + 1.0
+        p.kind, p.sampled, p.serve_s = "serve", True, 0.25
+        rec_.finish(p)
+        child = rec_._serve_hist.labels(app="")
+        assert (child.count, child.sum) == (1, 0.25)
+        assert rec_.snapshot()[0]["duration_ms"] == pytest.approx(1000.0)
+        # a handler that died before it could say: its two stamps
+        p.serve_s = 0.0
+        rec_.finish(p)
+        assert child.sum == pytest.approx(0.25 + 0.3)
+
+    def test_batch_ring_entry_and_members_by_batch_id(self, trained):
+        trace.configure(sample=1.0, ring=256)
+        srv = _start_server(trained, batch_window_ms=5, batch_max=8)
+        try:
+            _hammer(srv.port, callers=4, each=3)
+            _drained(srv)
+            deadline = time.perf_counter() + 5.0
+            member = None
+            while member is None and time.perf_counter() < deadline:
+                member = next(
+                    (e for e in _serve_entries(
+                        trace.get_recorder().snapshot())
+                     if e.get("batch_size")), None)
+                time.sleep(0.005)
+            assert member is not None
+            found = trace.get_recorder().snapshot(
+                batch_id=member["batch_id"])
+            batch = [e for e in found if e["kind"] == "batch"]
+            assert len(batch) == 1 and member in found
+            b = batch[0]
+            assert b["rows"] >= member["batch_size"] >= 1
+            assert b["bucket"] >= b["rows"]
+            assert b["dispatch"] == member["dispatch"] != ""
+            spans = {s["name"]: s for s in b["spans"]}
+            assert set(spans) == set(trace.STAGES)
+            assert spans["window"]["start_ms"] == 0.0
+            assert sum(spans[n]["dur_ms"] for n in _LEAVES) \
+                == pytest.approx(b["duration_ms"], abs=0.02)
+            assert b["host_ms"] == pytest.approx(
+                b["duration_ms"] - spans["fetch"]["dur_ms"], abs=0.01)
+            status, body = call(
+                srv.port, "GET",
+                f"/traces.json?batch_id={member['batch_id']}")
+            assert status == 200
+            kinds = sorted({t["kind"] for t in body["traces"]})
+            assert kinds == ["batch", "serve"]
+        finally:
+            srv.shutdown()
+
+    def test_profile_holds_the_cycle_on_the_drainers_thread(self, trained,
+                                                            tmp_path):
+        """A short CPU capture of batched queries: the stages are
+        `pio:batch.*` events of `/host:CPU`, all of one cycle on one
+        line (the drainer's thread), predict's children inside it."""
+        import glob
+
+        import jax
+        srv = _metrics_server(trained, batch_window_ms=5, batch_max=8)
+        try:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+            try:
+                _hammer(srv.port, callers=3, each=2)
+                _drained(srv)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            srv.shutdown()
+        path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                             / "*.xplane.pb"))[0]
+        profile = jax.profiler.ProfileData.from_file(path)
+        host = next(p for p in profile.planes if p.name == "/host:CPU")
+        lines = []
+        for line in host.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events if e.name.startswith("pio:batch.")]
+            if evs:
+                lines.append(evs)
+        assert lines, "no pio:batch.* event in /host:CPU"
+        seen = set()
+        for evs in lines:
+            seen |= {name for name, _, _ in evs}
+            predicts = [(a, b) for name, a, b in evs
+                        if name == "pio:batch.predict"]
+            for name, a, b in evs:
+                if name.split(".", 1)[1] in _PREDICT_CHILDREN:
+                    assert any(pa <= a and b <= pb for pa, pb in predicts), \
+                        f"{name} outside every predict of its thread"
+            # a drainer's line holds whole cycles: as many takes as wakes
+            n_take = sum(name == "pio:batch.take" for name, _, _ in evs)
+            n_pred = len(predicts)
+            assert n_take >= n_pred >= 1
+        assert seen == {"pio:batch." + n for n in trace.STAGES}
+
+    def test_stage_outside_a_cycle_observes_no_histogram(self, trained):
+        m = MetricsRegistry()
+        fam = m.histogram("pio_serve_stage_seconds",
+                          trace.STAGE_SECONDS_HELP, labels=("stage",))
+        children = trace.stage_children(fam)
+        assert trace.current_batch() is None
+        with trace.stage("pack"):
+            pass
+        h = trace.stage_open("launch")
+        trace.stage_close(h)
+        with trace.stage("not_a_stage_of_the_cycle"):
+            pass
+        # a template's own call into the model, as eval and batch
+        # predict make it: the stages inside are spans only
+        before = {key: c.count for key, c in get_registry().histogram(
+            "pio_serve_stage_seconds", trace.STAGE_SECONDS_HELP,
+            labels=("stage",))._items()}
+        srv = _metrics_server(trained)
+        try:
+            algo, model = srv._dep.algos[0], srv._dep.models[0]
+        finally:
+            srv.shutdown()
+        out = algo.batch_predict(model, [(0, rec.Query(user="u1", num=3))])
+        assert len(out) == 1
+        after = {key: c.count for key, c in get_registry().histogram(
+            "pio_serve_stage_seconds", trace.STAGE_SECONDS_HELP,
+            labels=("stage",))._items()}
+        assert after == before
+        assert all(c.count == 0 for c in children.values())
+        # and inside one they are observed, through the record alone
+        bt = trace.batch_begin(children)
+        with trace.stage("pack"):
+            pass
+        trace.batch_end(bt)
+        assert children["pack"].count == 1
+        assert children["cycle"].count == children["host"].count == 1
+        assert children["launch"].count == 0
+        assert trace.current_batch() is None
+
+    def test_dispatch_path_rides_on_the_cycles_record(self):
+        """What `ops/topk.last_dispatch()` was for: the call that
+        dispatched says so on the record of the thread's cycle, and
+        says nothing where there is none."""
+        from predictionio_tpu.ops import topk
+        assert not hasattr(topk, "last_dispatch")
+        rng = np.random.RandomState(3)
+        items = rng.randn(50, 4).astype(np.float32)
+        vecs = rng.randn(2, 4).astype(np.float32)
+        mask = np.ones((2, 50), bool)
+        topk.topk_scores(vecs, items, mask, k=3)       # no cycle: fine
+        bt = trace.batch_begin()
+        topk.topk_scores(vecs, items, mask, k=3)
+        assert bt.path == "host" and bt.bucket == 0
+        trace.batch_drop()
+        plan = topk.BucketedTopK(items, k=3, buckets=(4,), banned_width=4)
+        plan.warm()
+        bt = trace.batch_begin()
+        plan(vecs, [[1], []])
+        assert bt.path in ("device", "fused") and bt.bucket == 4
+        for name in ("pack", "launch", "fetch"):
+            assert bt.start[trace.STAGES.index(name)] > 0.0
+        assert trace.batch_end(bt) == bt.t_last
+        # another thread has no record of this one's cycle
+        bt = trace.batch_begin()
+        seen = []
+        t = threading.Thread(
+            target=lambda: seen.append(trace.current_batch()))
+        t.start()
+        t.join()
+        trace.batch_drop()
+        assert seen == [None]
+
+
+def test_obs_imports_without_jax():
+    """Event servers and routers import obs (and the stage helper with
+    it) and must never load jax; the helper finds jax only where the
+    process has loaded it itself."""
+    import subprocess
+    import sys
+    from pathlib import Path
+    code = (
+        "import sys\n"
+        "from predictionio_tpu.obs import trace\n"
+        "with trace.stage('pack'):\n"
+        "    pass\n"
+        "bt = trace.batch_begin()\n"
+        "with trace.stage('take'):\n"
+        "    pass\n"
+        "trace.batch_end(bt)\n"
+        "assert 'jax' not in sys.modules, 'obs loaded jax'\n"
+        "print('ok')\n")
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, env=dict(
+            __import__("os").environ,
+            PYTHONPATH=str(Path(__file__).resolve().parent.parent)))
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
 
 
 # -- fleet stitching ----------------------------------------------------------
@@ -534,8 +887,7 @@ class TestFleetStitching:
     def test_router_hop_not_double_counted_in_serve_hist(self, trained):
         trace.configure(sample=1.0, ring=256)
         hist = get_registry().histogram(
-            "pio_serve_seconds",
-            "End-to-end serve latency (wire read to wire write)",
+            "pio_serve_seconds", trace.SERVE_SECONDS_HELP,
             labels=("app",), buckets=trace.SERVE_BUCKETS)
         before = hist.labels(app="").count
         fleet = _start_fleet(trained, replicas=2)

@@ -541,6 +541,53 @@ class TestGatheredEgress:
             set_trace_hooks(None, None)
         assert stamps and stamps[0].reactor == 7
 
+    @pytest.mark.parametrize("hooks", [False, True],
+                             ids=["no_hooks", "sent_hook"])
+    def test_first_read_stamp_and_reply_hook_without_a_trace(self, hooks):
+        """Every framed request carries the first read of its bytes,
+        hooks or none; the sent hook fires for a request whose handler
+        left a `reply_obs`, though it has no trace, and for no other."""
+        seen, sent = [], []
+
+        def handler(raw):
+            seen.append((raw.t_read, time.perf_counter(), raw.trace))
+            if raw.path == "/echo":
+                raw.t_done = time.perf_counter()
+                raw.reply_obs = object()
+            return _echo(raw)
+
+        set_trace_hooks(None, sent.append if hooks else None)
+        try:
+            srv = SelectorWire(("127.0.0.1", 0), handler, workers=1)
+            t = threading.Thread(target=srv.serve_forever, daemon=True)
+            t.start()
+            try:
+                t0 = time.perf_counter()
+                with _connect(srv) as s, s.makefile("rb") as f:
+                    for path in ("/echo", "/other", "/echo"):
+                        s.sendall(_req(path=path, body=b"x"))
+                        status, _, _ = _read_response(f)
+                        assert status == 200
+                # the hook runs after the bytes left: wait for the last
+                deadline = time.perf_counter() + 5.0
+                while hooks and len(sent) < 2 \
+                        and time.perf_counter() < deadline:
+                    time.sleep(0.005)
+            finally:
+                _stop_wire(srv, t)
+        finally:
+            set_trace_hooks(None, None)
+        assert len(seen) == 3
+        reads = [t_read for t_read, _, _ in seen]
+        assert reads == sorted(reads) and len(set(reads)) == 3
+        for t_read, t_in, tr in seen:
+            assert t0 < t_read <= t_in and tr is None
+        if hooks:
+            assert [r.path for r in sent] == ["/echo", "/echo"]
+            assert all(r.t_done >= r.t_read for r in sent)
+        else:
+            assert sent == []
+
 
 # -- sharded reactors ---------------------------------------------------------
 
