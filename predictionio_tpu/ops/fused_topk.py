@@ -8,31 +8,61 @@ batch bucket:
 
   - the item catalog streams through VMEM in `PIO_FUSED_TILE_ITEMS`-row
     tiles (grid over item tiles; the full score matrix never exists in
-    HBM);
-  - each tile's scores are computed on the MXU
+    HBM), and a grid step walks its tile in gate sub-blocks of
+    `_SUB_ITEMS` rows;
+  - each sub-block's scores are computed on the MXU
     (`preferred_element_type=f32`, `Precision.HIGHEST` — identical math
-    to the XLA chain), banned GLOBAL ids are masked by comparison
-    against the tile's id range (the `n_items` filler never matches a
-    real id), catalog-padding rows are masked to NEG_INF;
-  - a running (score, id) scoreboard carried in the output blocks
-    merges each tile via k selection steps with an explicit
-    (max score, lowest id) key — exactly `lax.top_k`'s documented
-    lowest-index-first tie-break, so the fused outputs are
-    BIT-IDENTICAL to the `_topk_scores_banned` oracle whenever the
-    per-cell dot products are (always true for the integer-valued
-    factors the parity tests use; real factors agree to the last ulp
-    of the two matmuls). Removed scoreboard entries are parked at
-    -inf, strictly below the NEG_INF ban value, so a banned item can
-    be emitted (matching the oracle) but never emitted twice.
+    to the XLA chain) and catalog-padding rows are masked to NEG_INF;
+  - THE GATE: a running (score, id) scoreboard is carried in the output
+    blocks, sorted best first, so its column k-1 is each row's k-th
+    best score so far. A sub-block can change a row's top-k only if one
+    of its scores is STRICTLY greater than that: sub-blocks come in id
+    order and the scoreboard holds only lower ids, so an equal score
+    loses `lax.top_k`'s lowest-index-first tie-break to every one of
+    the k entries already there. The kernel therefore reduces
+    `scores > scoreboard[:, k-1]` to one scalar and runs the ban mask
+    and the merge only under it; a sub-block that cannot change the
+    answer costs one product, one compare and one reduction. The
+    threshold starts at the removed-entry sentinel -inf (below), so a
+    row merges every sub-block until it holds k entries, banned items
+    at NEG_INF included (the oracle emits those too when fewer than k
+    items are allowed). The compare reads the scores BEFORE bans: a
+    banned item above the threshold only opens a merge that was not
+    needed;
+  - inside a merge, banned GLOBAL ids are masked by comparison against
+    the sub-block's ids (the `n_items` filler never matches a real id)
+    — only where some row bans an id inside the sub-block's range, which
+    is the same result — and then k selection steps over scoreboard +
+    sub-block pick by an explicit (max score, lowest id) key — exactly
+    `lax.top_k`'s documented lowest-index-first tie-break, so the fused
+    outputs are BIT-IDENTICAL to the `_topk_scores_banned` oracle
+    whenever the per-cell dot products are (always true for the
+    integer-valued factors the parity tests use; real factors agree to
+    the last ulp of the two matmuls). Removed scoreboard entries are
+    parked at -inf, strictly below the NEG_INF ban value, so a banned
+    item can be emitted (matching the oracle) but never emitted twice;
+  - a third output, one int32 in SMEM, counts the sub-blocks whose
+    merge ran. The plans fetch it with the results and observe
+    `merged / gate_blocks()` into `pio_topk_merge_share`, once a call.
+
+What the gate buys depends on the catalog's order, which the kernel
+observes and no option states: with scores independent of the id,
+sub-block t of a row opens with probability about k / t, and a batch
+of b rows merges about b·k·(1 + ln(n_blocks / (b·k))) sub-blocks (14%
+at 45 rows of 12M items). WORST CASE: a catalog whose scores rise with
+the id opens every sub-block; the kernel then does all the work it did
+before the gate, plus the gate's compare and reduction and the ban
+range's in every sub-block.
 
 Everything the kernel touches is laid out for Mosaic's (8 sublane, 128
 lane) vector tiles: the batch is padded to a multiple of 8 rows, the
 scoreboard is `_round_up(k, 128)` lanes wide (slots past k stay parked
-at the sentinels), the item tile is a multiple of 128, so the
-scoreboard/tile concatenation is lane-aligned, and the ban block
-arrives as [W, b, 1] so each banned id is read as a [b, 1] column by a
-leading-dim index. The jitted wrapper pads and re-lays the inputs and
-slices `[bucket, k]` back out.
+at the sentinels), the sub-block is a multiple of 128, so the
+scoreboard/sub-block concatenation is lane-aligned, and the ban block
+arrives twice: as [b, W] rows for the range test and as [W, b, 1] so
+each banned id is read as a [b, 1] column by a leading-dim index. The
+jitted wrapper pads and re-lays the inputs and slices `[bucket, k]`
+back out.
 
 `PIO_SERVE_FUSED` selects the kernel:
 
@@ -65,10 +95,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from predictionio_tpu.ops.topk import NEG_INF
 
-# items per VMEM tile (rounded up to whole 128-lane groups, and to k so
-# every merge sees >= k real candidates and the scoreboard fillers can
-# never leak into results)
-DEFAULT_TILE_ITEMS = 512
+# items per DMA tile in VMEM, and items per gate sub-block inside it:
+# what one product, one compare and, where it holds a candidate, one
+# merge cover (both rounded up to whole 128-lane groups, the sub-block
+# to at least k so every merge sees >= k real candidates). Set from
+# chip measurements at 12,047,500 x 64 (PERF.md section 6, PR 26): tile
+# 512 -> 4096 is 4% of a call and 8192 no more; sub-block 512 / 1024 /
+# 2048 read 37.0 / 33.7 / 33.8 ms at 45 rows
+DEFAULT_TILE_ITEMS = 4096
+_SUB_ITEMS = 1024
 
 _LANES = 128
 _SUBLANES = 8
@@ -110,43 +145,50 @@ def interpreted() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _tile_items(k: int) -> int:
+def _tile_items(n_rows: int, k: int) -> tuple:
+    """(DMA tile, gate sub-block) for a catalog of `n_rows`, both in
+    items: the sub-block is whole 128-lane groups and holds at least k
+    items, the tile is a whole number of sub-blocks, and a catalog
+    smaller than the tile is one grid step of its own sub-blocks."""
     tile = int(os.environ.get("PIO_FUSED_TILE_ITEMS", "0") or 0)
     if tile <= 0:
         tile = DEFAULT_TILE_ITEMS
-    return _round_up(max(tile, k), _LANES)
+    tile = _round_up(max(tile, k), _LANES)
+    sub = min(tile, _round_up(max(_SUB_ITEMS, k), _LANES))
+    return min(_round_up(tile, sub), _round_up(n_rows, sub)), sub
 
 
-def _merge_body(n_valid, t, vecs_ref, fac_ref, ban_ref,
-                out_s_ref, out_i_ref, *, k: int, tile: int,
-                n_banned: int) -> None:
-    """One grid step: score this item tile, mask bans/padding, merge
-    into the running scoreboard carried by the output blocks."""
-    b, board = out_s_ref.shape
+def _any(mask) -> jax.Array:
+    """Scalar: whether any cell of a 2-D boolean block is set."""
+    return jnp.max(jnp.where(mask, np.int32(1), np.int32(0))) > 0
 
-    @pl.when(t == 0)
-    def _init():
-        out_s_ref[...] = jnp.full((b, board), _REMOVED, jnp.float32)
-        out_i_ref[...] = jnp.full((b, board), _FILLER_ID, jnp.int32)
 
-    # [b, tile] tile scores — same contraction/precision as the chain
-    scores = jax.lax.dot_general(
-        vecs_ref[...], fac_ref[...], (((1,), (1,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)
-    gidx = t * tile + jax.lax.broadcasted_iota(jnp.int32, (b, tile), 1)
-    # rows past n_valid are catalog padding or the out-of-bounds part
-    # of the last tile (whatever the DMA left there): select, never add
-    scores = jnp.where(gidx < n_valid, scores, np.float32(NEG_INF))
+def _merge(base, scores, banrow_ref, ban_ref, out_s_ref, out_i_ref, *,
+           k: int, n_banned: int) -> None:
+    """Merge the [b, sub] scores of the sub-block that starts at item
+    `base` into the scoreboard: bans first, then the k-step
+    selection."""
+    b, sub = scores.shape
+    board = out_s_ref.shape[1]
+    gidx = base + jax.lax.broadcasted_iota(jnp.int32, (b, sub), 1)
 
     def ban_body(w, sc):
         # ban_ref is [W, b, 1]: a leading-dim index yields the w-th
         # banned id of every row as a [b, 1] column
         return jnp.where(ban_ref[w] == gidx, np.float32(NEG_INF), sc)
 
-    scores = jax.lax.fori_loop(0, n_banned, ban_body, scores)
+    def ban_all(sc):
+        return jax.lax.fori_loop(0, n_banned, ban_body, sc)
 
-    # k-step selection over scoreboard + tile with the explicit
+    # the W compare-selects over the whole block are two thirds of a
+    # merge, and a ban list names a few ids of millions: run them only
+    # where some row bans an id inside this sub-block's range (an id
+    # outside it equals no cell of `gidx`, so skipping changes nothing)
+    ids = banrow_ref[...]
+    near = (ids >= base) & (ids < base + sub)
+    scores = jax.lax.cond(_any(near), ban_all, lambda sc: sc, scores)
+
+    # k-step selection over scoreboard + sub-block with the explicit
     # (max score, lowest id) key of lax.top_k
     comb_s = jnp.concatenate([out_s_ref[...], scores], axis=1)
     comb_i = jnp.concatenate([out_i_ref[...], gidx], axis=1)
@@ -171,58 +213,107 @@ def _merge_body(n_valid, t, vecs_ref, fac_ref, ban_ref,
     out_i_ref[...] = outi
 
 
-def _kernel_static(vecs_ref, fac_ref, ban_ref, out_s_ref, out_i_ref, *,
-                   n_valid: int, k: int, tile: int,
-                   n_banned: int) -> None:
+def _merge_body(n_valid, t, vecs_ref, fac_ref, banrow_ref, ban_ref,
+                out_s_ref, out_i_ref, cnt_ref, *, k: int, tile: int,
+                sub: int, n_banned: int) -> None:
+    """One grid step: score this item tile sub-block by sub-block, and
+    merge into the running scoreboard carried by the output blocks the
+    sub-blocks that hold a score above some row's k-th best."""
+    b, board = out_s_ref.shape
+
+    @pl.when(t == 0)
+    def _init():
+        out_s_ref[...] = jnp.full((b, board), _REMOVED, jnp.float32)
+        out_i_ref[...] = jnp.full((b, board), _FILLER_ID, jnp.int32)
+        cnt_ref[0, 0] = np.int32(0)
+
+    vecs = vecs_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (b, sub), 1)
+
+    def sub_block(s, carry):
+        base = t * tile + s * sub
+        fac = fac_ref[pl.ds(pl.multiple_of(s * sub, sub), sub), :]
+        # [b, sub] scores — same contraction/precision as the chain
+        scores = jax.lax.dot_general(
+            vecs, fac, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        # rows past n_valid are catalog padding or the out-of-bounds
+        # part of the last tile (whatever the DMA left there): select,
+        # never add
+        scores = jnp.where(lane < n_valid - base, scores,
+                           np.float32(NEG_INF))
+
+        # the gate: strictly above the row's k-th best, or nothing in
+        # this sub-block can enter that row's top-k
+        @pl.when(_any(scores > out_s_ref[:, k - 1:k]))
+        def _hit():
+            cnt_ref[0, 0] += np.int32(1)
+            _merge(base, scores, banrow_ref, ban_ref, out_s_ref,
+                   out_i_ref, k=k, n_banned=n_banned)
+
+        return carry
+
+    jax.lax.fori_loop(0, tile // sub, sub_block, 0)
+
+
+def _kernel_static(*refs, n_valid: int, **static) -> None:
     """Single-device form: the valid-row bound is the static catalog
     size baked into the trace."""
-    _merge_body(n_valid, pl.program_id(0), vecs_ref, fac_ref, ban_ref,
-                out_s_ref, out_i_ref, k=k, tile=tile, n_banned=n_banned)
+    _merge_body(n_valid, pl.program_id(0), *refs, **static)
 
 
-def _kernel_dynamic(nv_ref, vecs_ref, fac_ref, ban_ref, out_s_ref,
-                    out_i_ref, *, k: int, tile: int,
-                    n_banned: int) -> None:
+def _kernel_dynamic(nv_ref, *refs, **static) -> None:
     """Sharded form: each shard's valid-row bound depends on its mesh
     position, so it arrives as a scalar operand (SMEM on TPU)."""
-    _merge_body(nv_ref[0], pl.program_id(0), vecs_ref, fac_ref, ban_ref,
-                out_s_ref, out_i_ref, k=k, tile=tile, n_banned=n_banned)
+    _merge_body(nv_ref[0], pl.program_id(0), *refs, **static)
+
+
+def gate_blocks(n_rows: int, k: int) -> int:
+    """How many sub-blocks the gate judges in one call over `n_rows`
+    catalog rows: the base of the merge counter's share."""
+    tile, sub = _tile_items(n_rows, k)
+    return -(-n_rows // tile) * (tile // sub)
 
 
 def _pallas_topk(n_rows: int, rank: int, *, k: int, bucket: int,
                  banned_width: int, n_valid: Optional[int],
                  vma=frozenset()):
     """The fused callable for one bucket: `(vecs [bucket, rank], factors
-    [n_rows, rank], banned [bucket, W]) -> (scores, ids) [bucket, k]`.
-    With `n_valid` set the bound is static (single-device); with
-    `n_valid=None` the callable takes a leading [1] int32 bound operand
-    (per-shard form, SMEM on TPU). `vma` names the mesh axes the
-    outputs vary over when the call sits inside a shard_map."""
+    [n_rows, rank], banned [bucket, W]) -> (scores [bucket, k], ids
+    [bucket, k], merged [] i32)`, `merged` the number of sub-blocks
+    whose merge ran. With `n_valid` set the bound is static
+    (single-device); with `n_valid=None` the callable takes a leading
+    [1] int32 bound operand (per-shard form, SMEM on TPU). `vma` names
+    the mesh axes the outputs vary over when the call sits inside a
+    shard_map."""
     interpret = interpreted()
-    tile = _tile_items(k)
+    tile, sub = _tile_items(n_rows, k)
     nt = -(-n_rows // tile)
     rows = _round_up(bucket, _SUBLANES)
     board = _round_up(k, _LANES)
+    smem = pl.BlockSpec(memory_space=None if interpret else pltpu.SMEM)
     specs = [pl.BlockSpec((rows, rank), lambda i: (0, 0)),
              pl.BlockSpec((tile, rank), lambda i: (i, 0)),
+             pl.BlockSpec((rows, banned_width), lambda i: (0, 0)),
              pl.BlockSpec((banned_width, rows, 1), lambda i: (0, 0, 0))]
+    static = dict(k=k, tile=tile, sub=sub, n_banned=banned_width)
     if n_valid is None:
-        kern = functools.partial(_kernel_dynamic, k=k, tile=tile,
-                                 n_banned=banned_width)
-        specs = [pl.BlockSpec(memory_space=None if interpret
-                              else pltpu.SMEM)] + specs
+        kern = functools.partial(_kernel_dynamic, **static)
+        specs = [smem] + specs
     else:
-        kern = functools.partial(_kernel_static, n_valid=n_valid, k=k,
-                                 tile=tile, n_banned=banned_width)
+        kern = functools.partial(_kernel_static, n_valid=n_valid, **static)
     call = pl.pallas_call(
         kern,
         grid=(nt,),
         in_specs=specs,
         out_specs=(pl.BlockSpec((rows, board), lambda i: (0, 0)),
-                   pl.BlockSpec((rows, board), lambda i: (0, 0))),
+                   pl.BlockSpec((rows, board), lambda i: (0, 0)),
+                   smem),
         out_shape=(
             jax.ShapeDtypeStruct((rows, board), jnp.float32, vma=vma),
-            jax.ShapeDtypeStruct((rows, board), jnp.int32, vma=vma)),
+            jax.ShapeDtypeStruct((rows, board), jnp.int32, vma=vma),
+            jax.ShapeDtypeStruct((1, 1), jnp.int32, vma=vma)),
         # the scoreboard is carried from tile to tile
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -234,9 +325,10 @@ def _pallas_topk(n_rows: int, rank: int, *, k: int, bucket: int,
         # padded rows: zero vectors, bans that match no id. The ban
         # block goes in as [W, rows, 1] so the kernel reads one banned
         # id per row with a leading-dim index (no lane slicing)
-        ban_cols = jnp.pad(banned, pad, constant_values=-1).T[..., None]
-        out_s, out_i = call(*bound, jnp.pad(vecs, pad), factors, ban_cols)
-        return out_s[:bucket, :k], out_i[:bucket, :k]
+        ban_rows = jnp.pad(banned, pad, constant_values=-1)
+        out_s, out_i, merged = call(*bound, jnp.pad(vecs, pad), factors,
+                                    ban_rows, ban_rows.T[..., None])
+        return out_s[:bucket, :k], out_i[:bucket, :k], merged[0, 0]
 
     return fn
 

@@ -106,6 +106,30 @@ def _dispatch_total():
     return _DISPATCH_TOTAL
 
 
+_MERGE_SHARE = None
+
+
+def _observe_merge_share(merged, blocks: int) -> None:
+    """One fused call's `merged / blocks` into `pio_topk_merge_share`
+    (process-default registry, lazy like `_dispatch_total`): the share
+    of the kernel's gate sub-blocks whose merge ran
+    (ops/fused_topk.py)."""
+    global _MERGE_SHARE
+    try:
+        if _MERGE_SHARE is None:
+            from predictionio_tpu.obs import get_registry
+            _MERGE_SHARE = get_registry().histogram(
+                "pio_topk_merge_share",
+                "Share of the fused top-k kernel's gate sub-blocks that "
+                "held a score above a row's k-th best and were merged, "
+                "per call",
+                buckets=(0.01, 0.02, 0.03, 0.05, 0.075, 0.1, 0.15, 0.2,
+                         0.3, 0.5, 0.75, 1.0))
+        _MERGE_SHARE.observe(merged / blocks)
+    except Exception:
+        pass  # metrics must never fail a serve call
+
+
 class DispatchPolicy:
     """Amortized host/device dispatch from observed per-path latency.
 
@@ -582,6 +606,8 @@ class BucketedTopK:
         # which bucket sizes went fused, so dispatch attribution can
         # tag "fused" vs "device" per call
         self._fused_sizes: set = set()
+        # sub-blocks the fused kernel's gate judges in one call
+        self._gate_blocks = 0
         register_resident_plan(self)
 
     def resident_per_device_bytes(self) -> float:
@@ -613,6 +639,8 @@ class BucketedTopK:
             if exe is not None:
                 self.fused_buckets += 1
                 self._fused_sizes.add(b)
+                self._gate_blocks = fused_topk.gate_blocks(self.n_items,
+                                                           self.k)
             else:
                 vec_spec = jax.ShapeDtypeStruct((b, self.rank),
                                                 np.float32)
@@ -693,7 +721,10 @@ class BucketedTopK:
         with trace.stage("launch"):
             out = exe(vecs, self.factors, banned)
         with trace.stage("fetch"):
-            scores, ixs = jax.device_get(out)
+            # a fused bucket also returns how many sub-blocks it merged
+            scores, ixs, *merged = jax.device_get(out)
+        if merged:
+            _observe_merge_share(merged[0], self._gate_blocks)
         _record_dispatch(
             "fused" if bucket in self._fused_sizes else "device",
             bucket * self.n_items, time.perf_counter() - t0, bucket)

@@ -65,7 +65,7 @@ from predictionio_tpu.obs import trace
 from predictionio_tpu.ops import topk
 from predictionio_tpu.ops.topk import (
     DEFAULT_SERVE_BUCKETS, NEG_INF, BucketedSimilar, BucketedTopK,
-    _next_pow2, _record_dispatch,
+    _next_pow2, _observe_merge_share, _record_dispatch,
 )
 from predictionio_tpu.parallel.mesh import (  # noqa: F401 — re-export
     parse_fleet_mesh, shard_put,
@@ -282,17 +282,18 @@ def _jit_merged(local_candidates, k: int, mesh):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def fn(vecs, factors, filt):
-        s_all, g_all = local_candidates(vecs, factors, filt)
+        s_all, g_all, *counts = local_candidates(vecs, factors, filt)
         b = s_all.shape[1]
         # shard-major concatenation = global-id order for ties
         s_cat = jnp.swapaxes(s_all, 0, 1).reshape(b, -1)
         g_cat = jnp.swapaxes(g_all, 0, 1).reshape(b, -1)
         sv, si = jax.lax.top_k(s_cat, k)
-        return sv, jnp.take_along_axis(g_cat, si, axis=1)
+        # a fused local stage also stacks each shard's merge count
+        return (sv, jnp.take_along_axis(g_cat, si, axis=1),
+                *(c.sum() for c in counts))
 
-    replicated = NamedSharding(mesh, P())
     donate = () if jax.default_backend() == "cpu" else (0, 2)
-    return jax.jit(fn, out_shardings=(replicated, replicated),
+    return jax.jit(fn, out_shardings=NamedSharding(mesh, P()),
                    donate_argnums=donate)
 
 
@@ -377,6 +378,9 @@ class ShardedBucketedTopK(_ShardedPlanBase):
         # whether the per-shard local-candidate stage runs as the
         # single-launch fused kernel (ops/fused_topk.py)
         self.fused = False
+        # sub-blocks the fused kernels' gates judge in one call, all
+        # shards together
+        self._gate_blocks = 0
         self._fn = self._build()
 
     def _build(self, bucket: Optional[int] = None):
@@ -396,6 +400,8 @@ class ShardedBucketedTopK(_ShardedPlanBase):
             if local is None:
                 return None
             self.fused = True
+            self._gate_blocks = (fused_topk.gate_blocks(per, kk)
+                                 * self.n_shards)
 
         def body(vecs, factors_local, banned):
             # vecs [b, rank] + banned [b, W] replicated; factors_local
@@ -415,7 +421,8 @@ class ShardedBucketedTopK(_ShardedPlanBase):
                 # and rides in as a scalar operand
                 nv = jnp.clip(n_items - base, 0,
                               per).astype(jnp.int32).reshape((1,))
-                s, ix = local(nv, vecs, factors_local, loc)
+                s, ix, merged = local(nv, vecs, factors_local, loc)
+                return s[None], (ix + base)[None], merged[None]
             else:
                 scores = jnp.matmul(vecs, factors_local.T,
                                     precision=jax.lax.Precision.HIGHEST)
@@ -436,7 +443,7 @@ class ShardedBucketedTopK(_ShardedPlanBase):
         return _jit_merged(jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(P(), P(SHARD_AXIS, None), P()),
-            out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
+            out_specs=(P(SHARD_AXIS),) * (2 if local is None else 3),
             check_vma=check), k, self.mesh)
 
     def warm(self) -> int:
@@ -495,7 +502,10 @@ class ShardedBucketedTopK(_ShardedPlanBase):
         with trace.stage("launch"):
             out = exe(vecs, self.factors, banned)
         with trace.stage("fetch"):
-            scores, ixs = jax.device_get(out)
+            # fused shards also return their summed merge count
+            scores, ixs, *merged = jax.device_get(out)
+        if merged:
+            _observe_merge_share(merged[0], self._gate_blocks)
         _record_dispatch("sharded", bucket * self.n_items,
                          time.perf_counter() - t0, bucket)
         return scores[:b], ixs[:b]

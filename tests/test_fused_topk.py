@@ -227,3 +227,241 @@ class TestShardedParity:
             after, _ = fused(vecs, [[], []])
         assert w.count == 0
         assert not np.array_equal(before, after)
+
+
+# --- the threshold gate (PR 26): a sub-block is merged only where one of
+# its scores lies strictly above a row's k-th best so far
+
+_GATE_SUB = fused_topk._SUB_ITEMS
+_GATE_TILE = 2 * _GATE_SUB      # two sub-blocks a grid step
+# two grid steps, the second's last sub-block wholly past the end; and
+# a catalog of two sub-blocks that a ban list can cover
+_GATE_N = _GATE_TILE + _GATE_SUB // 2 + 40
+_GATE_N_SMALL = _GATE_SUB + 176
+
+
+def _axis_catalog(n, rank, scores):
+    """Factors whose score against the first unit vector is `scores`
+    (integers, so every product is exact)."""
+    factors = np.zeros((n, rank), np.float32)
+    factors[:, 0] = scores
+    factors[:, 1:] = _int_factors(n, rank - 1, seed=n)
+    return factors
+
+
+def _unit(rank, rows=1, scale=1.0):
+    vecs = np.zeros((rows, rank), np.float32)
+    vecs[:, 0] = scale
+    return vecs
+
+
+def _gate_case(name, n):
+    """(factors [n, 8], vecs, bans, k, banned_width) for one of the
+    gate's edge cases."""
+    rank, k, width = 8, 6, 16
+    ids = np.arange(n)
+    if name == "descending":        # only the first sub-blocks merge
+        return (_axis_catalog(n, rank, n - ids), _unit(rank, 2),
+                [[], [3]], k, width)
+    if name == "ascending":         # every sub-block merges
+        return (_axis_catalog(n, rank, ids), _unit(rank, 2),
+                [[], [n - 1]], k, width)
+    if name == "tie_in_later_tile":
+        # a later tile holds the k-th best's score exactly: the lower
+        # id keeps its place
+        sc = np.where(ids < k, 5, -ids % 4 - 1)
+        sc[n - 7] = 5
+        sc[_GATE_TILE + 3] = 5
+        return _axis_catalog(n, rank, sc), _unit(rank), [[]], k, width
+    if name == "banned_best":
+        # the best item sits in a late sub-block and is banned: that
+        # sub-block merges for nothing, the answer is the plain one
+        sc = n - ids
+        sc[n - 40] = 10 * n
+        return (_axis_catalog(n, rank, sc), _unit(rank, 2),
+                [[n - 40], []], k, width)
+    if name == "all_and_nearly_all_banned":
+        width = 2 * _GATE_SUB
+        return (_int_factors(n, rank), _queries(2, rank),
+                [list(range(n)), list(range(3, n))], k, width)
+    if name == "zero_rows_beside_live":
+        vecs = _queries(5, rank, seed=3)
+        vecs[1] = 0.0
+        vecs[4] = 0.0
+        return (_int_factors(n, rank), vecs,
+                [[], [2], [n - 1, 0], [], []], k, width)
+    if name == "random":
+        return (_int_factors(n, rank), _queries(7, rank, seed=5),
+                _ban_cases(n, width)[:4] + [[], [], [1]],
+                k, width)
+    raise AssertionError(name)
+
+
+_GATE_CASES = ["descending", "ascending", "tie_in_later_tile",
+               "banned_best", "all_and_nearly_all_banned",
+               "zero_rows_beside_live", "random"]
+
+
+def _both(cls, factors, monkeypatch, *, k, bucket, width, **kw):
+    monkeypatch.setenv("PIO_FUSED_TILE_ITEMS", str(_GATE_TILE))
+    plans = []
+    for mode in ("off", "on"):
+        monkeypatch.setenv("PIO_SERVE_FUSED", mode)
+        plan = cls(factors, k=k, buckets=(bucket,), banned_width=width,
+                   **kw)
+        plan.warm()
+        plans.append(plan)
+    return plans
+
+
+class TestGatedMerge:
+    @pytest.mark.parametrize("case", _GATE_CASES)
+    def test_single_device_bit_identical(self, case, monkeypatch):
+        n = _GATE_N_SMALL if case.startswith("all_") else _GATE_N
+        factors, vecs, bans, k, width = _gate_case(case, n)
+        chain, fused = _both(BucketedTopK, factors, monkeypatch, k=k,
+                             bucket=8, width=width)
+        assert fused.fused_buckets == 1
+        cs, ci = chain(vecs, bans)
+        fs, fi = fused(vecs, bans)
+        np.testing.assert_array_equal(ci, fi)
+        np.testing.assert_array_equal(cs, fs)
+
+    @pytest.mark.parametrize("case", _GATE_CASES)
+    def test_sharded_bit_identical(self, case, monkeypatch):
+        # 8 shards, the last one short by five rows
+        n = 8 * (_GATE_N_SMALL if case.startswith("all_")
+                 else _GATE_N) - 5
+        factors, vecs, bans, k, width = _gate_case(case, n)
+        if case.startswith("all_"):
+            # a ban list wider than the plan holds cannot be served:
+            # ban a whole shard's rows and all but three of the next's
+            per = -(-n // 8)
+            bans = [list(range(per)), list(range(per + 3, 2 * per))]
+        chain, fused = _both(ShardedBucketedTopK, factors, monkeypatch,
+                             k=k, bucket=8, width=width, mesh=_mesh())
+        assert fused.fused and not chain.fused
+        cs, ci = chain(vecs, bans)
+        fs, fi = fused(vecs, bans)
+        np.testing.assert_array_equal(ci, fi)
+        np.testing.assert_array_equal(cs, fs)
+
+    @pytest.mark.parametrize("poison", [np.nan, np.inf])
+    def test_poisoned_tail_past_n_valid_is_never_read(self, poison,
+                                                      monkeypatch):
+        """`n_items` is not a multiple of the tile and the operand's
+        rows past it hold NaN / +inf: they neither open the gate nor
+        reach the scoreboard."""
+        import jax
+        monkeypatch.setenv("PIO_SERVE_FUSED", "on")
+        monkeypatch.setenv("PIO_FUSED_TILE_ITEMS", str(_GATE_TILE))
+        n, pad_n, rank, k = _GATE_N, 2 * _GATE_TILE, 8, 6
+        factors = _int_factors(n, rank)
+        padded = np.full((pad_n, rank), poison, np.float32)
+        padded[:n] = factors
+        vecs = _queries(3, rank, seed=11)
+        bans = np.full((8, 16), n, np.int32)
+        bans[0, :2] = [n - 1, 4]
+        fn = jax.jit(fused_topk._pallas_topk(
+            pad_n, rank, k=k, bucket=8, banned_width=16, n_valid=n))
+        qs = np.zeros((8, rank), np.float32)
+        qs[:3] = vecs
+        fs, fi, merged = jax.device_get(fn(qs, padded, bans))
+        monkeypatch.setenv("PIO_SERVE_FUSED", "off")
+        chain = BucketedTopK(factors, k=k, buckets=(8,), banned_width=16)
+        chain.warm()
+        cs, ci = chain(vecs, [[n - 1, 4], [], []])
+        np.testing.assert_array_equal(ci, fi[:3])
+        np.testing.assert_array_equal(cs, fs[:3])
+        # the sub-block that lies wholly past n_valid never merged
+        assert 1 <= int(merged) <= 3
+
+    def test_worst_case_every_block_merges(self, monkeypatch):
+        factors, vecs, bans, k, width = _gate_case("ascending",
+                                                   2 * _GATE_TILE)
+        _, fused = _both(BucketedTopK, factors, monkeypatch, k=k,
+                         bucket=8, width=width)
+        before = _merge_observations()
+        fused(vecs, bans)
+        count, total = _merge_observations()
+        assert count == before[0] + 1
+        assert total - before[1] == pytest.approx(1.0)
+
+
+def _merge_observations():
+    """(count, sum) of `pio_topk_merge_share` so far."""
+    fam = topk._MERGE_SHARE
+    if fam is None:
+        return 0, 0.0
+    child = fam._default()
+    return child.count, child.sum
+
+
+def _count_candidate_blocks(factors, vecs, bans, k, sub):
+    """The gate's count by its definition, in NumPy: walk the catalog
+    in `sub`-item blocks keeping every row's exact banned top-k so far,
+    and count the blocks in which some row's raw score lies strictly
+    above that row's k-th best."""
+    n = factors.shape[0]
+    scores = vecs @ factors.T
+    banned = scores.copy()
+    for r, bl in enumerate(bans):
+        if len(bl):
+            banned[r, np.asarray(bl, int)] = topk.NEG_INF
+    kept = np.full((vecs.shape[0], k), -np.inf, np.float32)
+    merged = 0
+    for lo in range(0, n, sub):
+        blk = scores[:, lo:lo + sub]
+        if not (blk > kept[:, -1:]).any():
+            continue
+        merged += 1
+        both = np.concatenate([kept, banned[:, lo:lo + sub]], axis=1)
+        kept = -np.sort(-both, axis=1, kind="stable")[:, :k]
+    return merged
+
+
+class TestMergeCounter:
+    def test_counts_blocks_holding_a_candidate_once_per_call(
+            self, monkeypatch):
+        n, rank, k, width = 20 * _GATE_TILE - 480, 8, 6, 16
+        factors = _int_factors(n, rank, seed=17)
+        vecs = _queries(5, rank, seed=19)
+        bans = [[], [7, n // 5], [], list(range(0, 16)), [n - 1]]
+        chain, fused = _both(BucketedTopK, factors, monkeypatch, k=k,
+                             bucket=8, width=width)
+        blocks = fused_topk.gate_blocks(n, k)
+        assert blocks == 40         # twenty grid steps of two sub-blocks
+        calls = [(vecs, bans), (vecs[:2], bans[:2]), (vecs[3:], bans[3:])]
+        for qs, bl in calls:
+            before = _merge_observations()
+            fused(qs, bl)
+            count, total = _merge_observations()
+            assert count == before[0] + 1
+            want = _count_candidate_blocks(factors, qs, bl, k, _GATE_SUB)
+            assert 1 <= want < blocks
+            assert total - before[1] == pytest.approx(want / blocks)
+        # a bucket served by the XLA chain observes nothing
+        before = _merge_observations()
+        chain(vecs, bans)
+        assert _merge_observations() == before
+
+    def test_sharded_plan_sums_its_shards(self, monkeypatch):
+        n, rank, k, width = 8 * _GATE_N - 5, 8, 6, 16
+        factors = _int_factors(n, rank, seed=23)
+        vecs = _queries(3, rank, seed=29)
+        bans = [[], [5, _GATE_N + 700], [n - 1]]
+        _, fused = _both(ShardedBucketedTopK, factors, monkeypatch, k=k,
+                         bucket=8, width=width, mesh=_mesh())
+        per = fused.per_shard
+        want = 0
+        for sh in range(8):
+            lo, hi = sh * per, min(n, (sh + 1) * per)
+            local = [[g - lo for g in bl if lo <= g < hi] for bl in bans]
+            want += _count_candidate_blocks(factors[lo:hi], vecs, local,
+                                            k, _GATE_SUB)
+        blocks = 8 * fused_topk.gate_blocks(per, fused.k_shard)
+        before = _merge_observations()
+        fused(vecs, bans)
+        count, total = _merge_observations()
+        assert count == before[0] + 1
+        assert total - before[1] == pytest.approx(want / blocks)
