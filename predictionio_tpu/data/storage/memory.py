@@ -39,6 +39,11 @@ class MemStorageClient:
         self.slo_objectives: Dict[int, SLOObjective] = {}
         # (app_id, channel_id) -> event_id -> Event
         self.events: Dict[Tuple[int, Optional[int]], Dict[str, Event]] = {}
+        # (app_id, channel_id) -> (entity_type, entity_id) -> event_id
+        # -> Event: the same events by entity (MemEvents keeps it)
+        self.events_by_entity: Dict[
+            Tuple[int, Optional[int]],
+            Dict[Tuple[str, str], Dict[str, Event]]] = {}
         self._app_seq = itertools.count(1)
         self._channel_seq = itertools.count(1)
 
@@ -306,11 +311,21 @@ class MemLeases(base.Leases):
 
 
 class MemEvents(base.EventStore):
+    """Events of one (app, channel) in insertion order, and beside them
+    an index by entity, kept on insert, delete and remove: a
+    `find` that names `entity_type` and `entity_id` (the serve-time
+    history read) looks at that entity's events only, not at every
+    event of the app. Same results, same order."""
+
     def __init__(self, client: MemStorageClient):
         self.c = client
 
     def _table(self, app_id: int, channel_id: Optional[int]) -> Dict[str, Event]:
         return self.c.events.setdefault((app_id, channel_id), {})
+
+    def _index(self, app_id: int, channel_id: Optional[int]
+               ) -> Dict[Tuple[str, str], Dict[str, Event]]:
+        return self.c.events_by_entity.setdefault((app_id, channel_id), {})
 
     def init(self, app_id: int, channel_id: Optional[int] = None) -> bool:
         with self.c.lock:
@@ -320,6 +335,7 @@ class MemEvents(base.EventStore):
     def remove(self, app_id: int, channel_id: Optional[int] = None) -> bool:
         with self.c.lock:
             self.c.events.pop((app_id, channel_id), None)
+            self.c.events_by_entity.pop((app_id, channel_id), None)
         return True
 
     def close(self) -> None:
@@ -334,6 +350,8 @@ class MemEvents(base.EventStore):
                 raise base.StorageWriteError(
                     f"Duplicate event id {e.event_id}")
             table[e.event_id] = e
+            self._index(app_id, channel_id).setdefault(
+                (e.entity_type, e.entity_id), {})[e.event_id] = e
             return e.event_id
 
     def get(self, event_id: str, app_id: int,
@@ -343,7 +361,15 @@ class MemEvents(base.EventStore):
     def delete(self, event_id: str, app_id: int,
                channel_id: Optional[int] = None) -> bool:
         with self.c.lock:
-            return self._table(app_id, channel_id).pop(event_id, None) is not None
+            e = self._table(app_id, channel_id).pop(event_id, None)
+            if e is None:
+                return False
+            index = self._index(app_id, channel_id)
+            of_entity = index.get((e.entity_type, e.entity_id), {})
+            of_entity.pop(event_id, None)
+            if not of_entity:
+                index.pop((e.entity_type, e.entity_id), None)
+            return True
 
     def find(self, app_id: int, channel_id: Optional[int] = None, *,
              start_time: Optional[datetime] = None,
@@ -357,7 +383,11 @@ class MemEvents(base.EventStore):
              limit: Optional[int] = None,
              reversed: bool = False) -> Iterator[Event]:
         with self.c.lock:
-            events = list(self._table(app_id, channel_id).values())
+            if entity_type is not None and entity_id is not None:
+                events = list(self._index(app_id, channel_id).get(
+                    (entity_type, entity_id), {}).values())
+            else:
+                events = list(self._table(app_id, channel_id).values())
         events = [e for e in events if match_event(
             e, start_time=start_time, until_time=until_time,
             entity_type=entity_type, entity_id=entity_id,

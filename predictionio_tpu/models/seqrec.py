@@ -13,6 +13,15 @@ from the store at query time — the e-commerce template's
 serve-time-read pattern (ECommAlgorithm.scala:331-430) — so a user's
 newest activity influences their very next recommendation without
 retraining.
+
+The causal transformer is a BACKBONE (`ops/backbone.py`): `backbone:
+"sasrec"` is the template's own small block built from `dim` /
+`n_heads` / `n_layers`; anything else is a configuration file, a public
+architecture's stack in its own config keys, the item catalog as its
+vocabulary. Whatever the backbone, `batch_predict` packs the batch's
+histories into a few token buckets compiled at deploy
+(`ops/seqrec.PackedEncoder`) and scores the last positions through the
+catalog top-k serve plan (`ops/topk.py`), as ALS scores its users.
 """
 
 from __future__ import annotations
@@ -29,10 +38,14 @@ from predictionio_tpu.core import (
 from predictionio_tpu.data import store
 from predictionio_tpu.ingest import BiMap, RatingColumns
 from predictionio_tpu.models.recommendation import (
-    PredictedResult, Query,
+    ItemScore, PredictedResult, Query,
 )
+from predictionio_tpu.obs import trace
 from predictionio_tpu.ops.seqrec import (
-    SeqRecModel, build_sequences, seqrec_encode, seqrec_train,
+    PackedEncoder, SeqRecModel, build_sequences, seqrec_train,
+)
+from predictionio_tpu.ops.topk import (
+    NEG_INF, topk_scores, topk_scores_filtered,
 )
 
 
@@ -77,6 +90,9 @@ class SeqRecParams(Params):
     lr: float = 3e-3
     temperature: float = 0.07
     seed: Optional[int] = None
+    # the layer stack: `sasrec` (built from dim / n_heads / n_layers
+    # above) or a configuration file (ops/backbone.config_from_json)
+    backbone: str = "sasrec"
 
 
 class SeqRecAlgorithm(Algorithm):
@@ -101,7 +117,8 @@ class SeqRecAlgorithm(Algorithm):
             dim=p.dim, n_heads=p.n_heads, n_layers=p.n_layers,
             batch_size=bsz, epochs=p.epochs, lr=p.lr,
             temperature=p.temperature,
-            seed=p.seed if p.seed is not None else 0, mesh=ctx.mesh)
+            seed=p.seed if p.seed is not None else 0, mesh=ctx.mesh,
+            backbone=p.backbone)
         return SeqRecServingModel(net, pd.users, pd.items)
 
     def fold_in(self, model: SeqRecServingModel, delta,
@@ -143,7 +160,7 @@ class SeqRecAlgorithm(Algorithm):
             n_layers=p.n_layers, batch_size=bsz, epochs=1, lr=p.lr,
             temperature=p.temperature,
             seed=p.seed if p.seed is not None else 0, mesh=fctx.mesh,
-            init_params=model.net.params)
+            init_params=model.net.params, backbone=p.backbone)
         return SeqRecServingModel(net, model.users, model.items)
 
     # -- serving -------------------------------------------------------------
@@ -159,52 +176,113 @@ class SeqRecAlgorithm(Algorithm):
     def with_serving_context(self, ctx: RuntimeContext) -> None:
         self._serving_ctx = ctx
 
-    def _history(self, model: SeqRecServingModel, user: str) -> List[int]:
-        """The user's most recent item ids (store read, newest last).
-        Reads a LARGER window than seq_len before filtering: the model's
-        item map is frozen at training, so a burst of recent events on
-        post-training items must evict into older mappable history, not
-        empty it (history is this model's only input)."""
+    def _history(self, model: SeqRecServingModel, user: str,
+                 keep: int) -> List[int]:
+        """The user's `keep` most recent item ids (store read, newest last).
+        Reads a LARGER window than the model takes before filtering: the
+        model's item map is frozen at training, so a burst of recent
+        events on post-training items must evict into older mappable
+        history, not empty it (history is this model's only input)."""
         p = self.params
         try:
             events = list(store.find_by_entity(
                 self._ctx().registry, p.app_name, channel_name=p.channel,
                 entity_type="user", entity_id=user,
                 event_names=list(p.event_names),
-                limit=4 * model.net.seq_len, latest_first=True))
+                limit=4 * keep, latest_first=True))
         except store.AppNotFoundError:
             return []
         hist = [ix for e in reversed(events)
                 if e.target_entity_id is not None
                 and (ix := model.items.get(e.target_entity_id)) is not None]
-        return hist[-model.net.seq_len:]
+        return hist[-keep:]
 
     def predict(self, model: SeqRecServingModel,
                 query: Query) -> PredictedResult:
         return self.batch_predict(model, [(0, query)])[0][1]
 
+    def _plans(self, model: SeqRecServingModel, buckets=(1,), mesh=None):
+        """The serve plans of this model: the packed encoder over the
+        stack and the catalog top-k plan over the head's rows (the one
+        ALS serves its item factors with). Built once a model, the
+        encoder's token buckets compiled with it; a deploy builds them in
+        `warm_serving` (which also compiles the plan's batch buckets), a
+        bare `predict` on first use."""
+        plans = getattr(self, "_serve_plans", None)
+        if plans is None or plans[0] is not model:
+            from predictionio_tpu.ops.topk_sharded import serve_plan
+            encoder = PackedEncoder(model.net, rows=max(buckets))
+            encoder.warm()
+            plans = (model, encoder,
+                     serve_plan(model.net.item_emb, k=Query(user="").num,
+                                buckets=buckets, banned_width=64,
+                                mesh=mesh))
+            self._serve_plans = plans
+        return plans[1], plans[2]
+
+    def warm_serving(self, model: SeqRecServingModel, buckets,
+                     mesh=None) -> int:
+        """Deploy warm-up: pin the stack's weights and the head's rows
+        on the device and compile, ahead of time, one executable a
+        token bucket and one a batch bucket of the top-k plan. After it
+        no query compiles."""
+        self._serve_plans = None
+        encoder, plan = self._plans(model, tuple(buckets), mesh)
+        return len(encoder.buckets) + plan.warm()
+
     def batch_predict(self, model: SeqRecServingModel,
                       queries: Sequence[Tuple[int, Query]]
                       ) -> List[Tuple[int, PredictedResult]]:
+        """Histories from the store, packed through the stack, the last
+        positions scored by the catalog top-k plan. Stages of the batch
+        cycle (obs/trace.stage): `history` (store read to item
+        indexes), the encoder's `seq_pack` / `seq_launch` /
+        `seq_fetch`, `lookup` (ban lists to indexes), the plan's own
+        pack, launch and fetch, and `unpack`."""
         out: List[Tuple[int, PredictedResult]] = []
         live = []
-        S = model.net.seq_len
-        n_items = model.net.n_items
-        for i, q in queries:
-            hist = self._history(model, q.user)
-            if not hist:
-                out.append((i, PredictedResult()))
-            else:
-                live.append((i, q, hist))
+        with trace.stage("history"):
+            keep = model.net.config.max_history
+            for i, q in queries:
+                hist = self._history(model, q.user, keep)
+                if not hist:
+                    out.append((i, PredictedResult()))
+                else:
+                    live.append((i, q, hist))
         if not live:
             return out
-        seqs = np.full((len(live), S), n_items, np.int32)
-        for row, (_, _, hist) in enumerate(live):
-            seqs[row, S - len(hist):] = hist
-        vecs = seqrec_encode(model.net, seqs)
-        from predictionio_tpu.models.common import score_and_rank
-        out.extend(score_and_rank(vecs, model.net.item_emb,
-                                  model.items, live))
+        encoder, plan = self._plans(model)
+        vecs = encoder([h for _, _, h in live])
+        n_items = model.net.n_items
+        with trace.stage("lookup"):
+            k = max(min(q.num, n_items) for _, q, _ in live)
+            banned = mask = None
+            if all(q.whiteList is None for _, q, _ in live):
+                banned = [
+                    [ix for ix in (model.items.get(b)
+                                   for b in (q.blackList or ()))
+                     if ix is not None]
+                    for _, q, _ in live]
+            else:
+                from predictionio_tpu.models.common import resolve_item_mask
+                mask = np.concatenate(
+                    [resolve_item_mask(model.items, white_list=q.whiteList,
+                                       black_list=q.blackList or ())
+                     for _, q, _ in live], axis=0)
+        if banned is None:
+            scores, ixs = topk_scores(vecs, model.net.item_emb, mask, k=k)
+        elif plan.fits(max_banned=max(map(len, banned), default=0), k=k):
+            scores, ixs = plan(vecs, banned)
+        else:   # more bans or a larger k than the plan was built for
+            scores, ixs = topk_scores_filtered(vecs, model.net.item_emb,
+                                               banned, k=k)
+        with trace.stage("unpack"):
+            scores, ixs = np.asarray(scores), np.asarray(ixs)
+            for row, (i, q, _) in enumerate(live):
+                found = [ItemScore(model.items.inverse(int(ix)), float(s))
+                         for s, ix in zip(scores[row], ixs[row])
+                         if s > NEG_INF / 2][:q.num]
+                out.append((i, PredictedResult(tuple(found))))
         return out
 
 

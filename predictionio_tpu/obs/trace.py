@@ -105,16 +105,23 @@ SERVE_SECONDS_HELP = ("Serve latency inside the /queries.json handler "
 # They tile the cycle: a stage runs from the close of the stage before
 # it to its own close, so the glue between two blocks is charged to the
 # later one; `predict` is the parent of lookup..unpack.
-STAGES = ("window", "take", "supplement", "predict", "lookup", "pack",
-          "launch", "fetch", "unpack", "serve", "encode", "wake")
+# A sequence model's predict reads histories and runs its stack before
+# the plan's own pack / launch / fetch (SEQ_STAGES); a cycle of any other
+# template never enters them and observes nothing under their names.
+SEQ_STAGES = ("history", "seq_pack", "seq_launch", "seq_fetch")
+STAGES = ("window", "take", "supplement", "predict") + SEQ_STAGES + (
+    "lookup", "pack", "launch", "fetch", "unpack", "serve", "encode",
+    "wake")
 _STAGE_IX = {name: i for i, name in enumerate(STAGES)}
 _IX_FETCH = _STAGE_IX["fetch"]
+_IX_SEQ_FETCH = _STAGE_IX["seq_fetch"]
 STAGE_SECONDS_HELP = (
     "Serve-chain stage wall time. Per request: extract, feedback. Per "
     "batch cycle on the drainer's thread: window, take, supplement, "
+    "(a sequence model's history, seq_pack, seq_launch, seq_fetch,) "
     "lookup, pack, launch, fetch, unpack, serve, encode, wake tile the "
-    "cycle. Sums of others: predict = lookup..unpack, cycle = all "
-    "eleven, host = cycle - fetch")
+    "cycle. Sums of others: predict = history..unpack, cycle = all of "
+    "them, host = cycle - fetch - seq_fetch")
 
 
 class PendingTrace:
@@ -182,7 +189,8 @@ class BatchTrace:
         return self.t_last - self.t_begin
 
     def host_s(self) -> float:
-        return self.t_last - self.t_begin - self.dur[_IX_FETCH]
+        return (self.t_last - self.t_begin - self.dur[_IX_FETCH]
+                - self.dur[_IX_SEQ_FETCH])
 
 
 # -- X-PIO-Trace codec (signed-header compatible with X-PIO-App) -------------

@@ -104,6 +104,8 @@ from predictionio_tpu.ops.topk import NEG_INF
 # 2048 read 37.0 / 33.7 / 33.8 ms at 45 rows
 DEFAULT_TILE_ITEMS = 4096
 _SUB_ITEMS = 1024
+# most bytes of one catalog tile in VMEM (float32 rows; two in flight)
+_TILE_BYTES = 4 * 1024 * 1024
 
 _LANES = 128
 _SUBLANES = 8
@@ -145,14 +147,18 @@ def interpreted() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _tile_items(n_rows: int, k: int) -> tuple:
+def _tile_items(n_rows: int, k: int, rank: int) -> tuple:
     """(DMA tile, gate sub-block) for a catalog of `n_rows`, both in
     items: the sub-block is whole 128-lane groups and holds at least k
     items, the tile is a whole number of sub-blocks, and a catalog
-    smaller than the tile is one grid step of its own sub-blocks."""
+    smaller than the tile is one grid step of its own sub-blocks. A
+    tile is double-buffered in VMEM, so one of wide rows (a sequence
+    model's head: rank 4,096) is cut to `_TILE_BYTES`; at rank 64 the
+    cut is far above the default tile and changes nothing."""
     tile = int(os.environ.get("PIO_FUSED_TILE_ITEMS", "0") or 0)
     if tile <= 0:
         tile = DEFAULT_TILE_ITEMS
+    tile = min(tile, max(_LANES, _TILE_BYTES // (4 * rank)))
     tile = _round_up(max(tile, k), _LANES)
     sub = min(tile, _round_up(max(_SUB_ITEMS, k), _LANES))
     return min(_round_up(tile, sub), _round_up(n_rows, sub)), sub
@@ -269,10 +275,10 @@ def _kernel_dynamic(nv_ref, *refs, **static) -> None:
     _merge_body(nv_ref[0], pl.program_id(0), *refs, **static)
 
 
-def gate_blocks(n_rows: int, k: int) -> int:
+def gate_blocks(n_rows: int, k: int, rank: int) -> int:
     """How many sub-blocks the gate judges in one call over `n_rows`
     catalog rows: the base of the merge counter's share."""
-    tile, sub = _tile_items(n_rows, k)
+    tile, sub = _tile_items(n_rows, k, rank)
     return -(-n_rows // tile) * (tile // sub)
 
 
@@ -288,7 +294,7 @@ def _pallas_topk(n_rows: int, rank: int, *, k: int, bucket: int,
     the mesh axes the outputs vary over when the call sits inside a
     shard_map."""
     interpret = interpreted()
-    tile, sub = _tile_items(n_rows, k)
+    tile, sub = _tile_items(n_rows, k, rank)
     nt = -(-n_rows // tile)
     rows = _round_up(bucket, _SUBLANES)
     board = _round_up(k, _LANES)

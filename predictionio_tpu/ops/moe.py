@@ -1,0 +1,246 @@
+"""Sparse experts: a router at its published width, and an expert layer
+that is told which experts it holds.
+
+`route` scores every token against ALL experts of the model (sigmoid
+scores, selection by `s + c`, weights from `s`). `moe_apply` computes
+the part of the layer's result that the experts held HERE give:
+`sum over selected & held of w_e * Expert_e(u)`. That is what expert
+parallelism asks of one chip; the exchange that would bring the other
+chips' tokens in and carry the parts out is no part of this module
+(one chip exchanges nothing), and a token none of whose experts is
+held here gets zero.
+
+No capacity and no dropped token: the (token, expert) pairs that are
+selected and held are sorted by expert, each expert's group is padded
+up to whole row blocks, and one grouped product a projection runs over
+the blocks (`grouped_matmul`, a Pallas kernel: a block's expert comes
+from a prefetched table and picks the weight tile; blocks past the
+last used one load and compute nothing). The buffer holds as many
+pairs as the call has tokens (twice what uniform routing sends to a
+sixteenth of the experts); a call that routes more to this chip fills
+further buffers, so the bound is on memory, never on the answer.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+
+class Routing(NamedTuple):
+    experts: jax.Array     # [T, k] int32, the selected experts' ids
+    weights: jax.Array     # [T, k] float32, normalised over the k
+
+
+class MoeStats(NamedTuple):
+    expert_tokens: jax.Array   # [held] int32, pairs each held expert got
+    unrouted: jax.Array        # [] int32, live tokens with no expert here
+
+
+def expert_share(index: int, count: int, n_experts: int) -> Tuple[int, int]:
+    """(first expert id, experts held) of share `index` of `count` equal
+    shares of `n_experts`: share 0 of 16 over 256 holds experts 0-15."""
+    if n_experts % count or not 0 <= index < count:
+        raise ValueError(f"share {index} of {count} over {n_experts}")
+    held = n_experts // count
+    return index * held, held
+
+
+def route(u, w_router, bias, *, top_k: int, norm_topk_prob: bool = True,
+          scale: float = 1.0) -> Routing:
+    """u [T, D] float32, w_router [D, E], bias [E] (the correction term
+    of the aux-loss-free balancing, used for selection only). The
+    scores are float32: a selection that flips on rounding sends a
+    token to another expert. Against bfloat16 weights that is two
+    products on the matrix unit, u's leading and next 8 bits of
+    mantissa each against the weights, which bfloat16 holds exactly
+    (2^-17 of u left out); XLA lowers the one `highest` float32
+    product of this shape to multiplies and adds off the matrix unit,
+    3 ms a layer at 8,192 tokens (my chip run, PR 27)."""
+    if w_router.dtype == jnp.bfloat16:
+        hi = u.astype(jnp.bfloat16)
+        lo = (u - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        logits = (jnp.matmul(hi, w_router,
+                             preferred_element_type=jnp.float32)
+                  + jnp.matmul(lo, w_router,
+                               preferred_element_type=jnp.float32))
+    else:
+        logits = jnp.matmul(u, w_router.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, experts = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, experts, axis=1)
+    if norm_topk_prob:
+        w = w / w.sum(axis=1, keepdims=True)
+    return Routing(experts.astype(jnp.int32), w * scale)
+
+
+def _gmm_kernel(expert_ref, used_ref, x_ref, w_ref, o_ref):
+    del expert_ref
+
+    @pl.when(pl.program_id(1) < used_ref[0])
+    def _():
+        o_ref[...] = jax.lax.dot_general(
+            x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def grouped_matmul(x, w, block_expert, n_used, block_rows: int,
+                   block_cols: int = 512, out_dtype=None):
+    """x [R, K] in row blocks of `block_rows`, block b all of expert
+    `block_expert[b]`; w [E, K, N]. Returns [R, N]: rows of the first
+    `n_used` blocks are x @ w[expert], rows of the others are not
+    written."""
+    R, K = x.shape
+    N = w.shape[2]
+    tn = min(block_cols, N)
+    n_blocks = R // block_rows
+
+    def row_block(b, used_ref):
+        return jnp.maximum(jnp.minimum(b, used_ref[0] - 1), 0)
+
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(N // tn, n_blocks),
+        in_specs=[
+            pl.BlockSpec((block_rows, K),
+                         lambda n, b, e, u: (row_block(b, u), 0)),
+            pl.BlockSpec((None, K, tn),
+                         lambda n, b, e, u: (e[row_block(b, u)], 0, n)),
+        ],
+        # a skipped step keeps the last used block's index, so that
+        # block is written once, whole, when the sweep ends
+        out_specs=pl.BlockSpec((block_rows, tn),
+                               lambda n, b, e, u: (row_block(b, u), n)))
+    return pl.pallas_call(
+        _gmm_kernel, grid_spec=grid,
+        out_shape=jax.ShapeDtypeStruct((R, N), out_dtype or x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=96 * 1024 * 1024),
+        interpret=jax.default_backend() != "tpu",
+        name="moe_grouped_matmul",
+    )(block_expert, jnp.reshape(n_used, (1,)).astype(jnp.int32), x, w)
+
+
+def _gmm_fwd(x, w, block_expert, n_used, block_rows, block_cols,
+             out_dtype):
+    return (grouped_matmul(x, w, block_expert, n_used, block_rows,
+                           block_cols, out_dtype),
+            (x, w, block_expert, n_used))
+
+
+def _gmm_bwd(block_rows, block_cols, out_dtype, res, dy):
+    """For the template's small training runs: dx is the same grouped
+    product against the transposed weights; dw is formed block by
+    block in plain einsums (every block's [K, N] at once, which no
+    training at width could hold)."""
+    x, w, block_expert, n_used = res
+    used = jnp.arange(x.shape[0] // block_rows) < n_used
+    dy = jnp.where(jnp.repeat(used, block_rows)[:, None], dy, 0)
+    dx = grouped_matmul(dy.astype(x.dtype), w.swapaxes(1, 2),
+                        block_expert, n_used, block_rows,
+                        min(block_cols, x.shape[1]), x.dtype)
+    xb = jnp.where(jnp.repeat(used, block_rows)[:, None], x, 0).reshape(
+        -1, block_rows, x.shape[1])
+    per_block = jnp.einsum("brk,brn->bkn", xb.astype(jnp.float32),
+                           dy.reshape(-1, block_rows, dy.shape[1])
+                           .astype(jnp.float32))
+    dw = jax.ops.segment_sum(per_block, block_expert,
+                             num_segments=w.shape[0]).astype(w.dtype)
+    return dx, dw, None, None
+
+
+grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def moe_block_rows(n_tokens: int) -> int:
+    """Rows of one block of the grouped product."""
+    return 256 if n_tokens >= 2048 else max(8, min(128, n_tokens // 4))
+
+
+def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
+              live=None, block_rows: int = 0):
+    """The held experts' part of the expert layer for u [T, D].
+
+    w_gate_up [held, D, 2 F] (gate beside up), w_down [held, F, D];
+    the held experts are ids first .. first + held - 1. `live` [T] bool
+    leaves padding tokens out (they would load the experts for
+    nothing). Returns ([T, D] float32, MoeStats)."""
+    T, D = u.shape
+    held, F = w_down.shape[0], w_down.shape[1]
+    k = routing.experts.shape[1]
+    tm = block_rows or moe_block_rows(T)
+    local = routing.experts - first
+    here = (local >= 0) & (local < held)
+    if live is not None:
+        here = here & live[:, None]
+    # every pair, sorted by held expert; the others carry id `held` and
+    # sort to the end
+    e_flat = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(e_flat, stable=True)
+    e_sorted = e_flat[order]
+    tok_sorted = (order // k).astype(jnp.int32)
+    w_sorted = routing.weights.reshape(-1)[order]
+    counts = jnp.bincount(e_flat, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    n_pairs = ends[-1]
+    cap = T                                # pairs one buffer takes
+    n_blocks = -(-cap // tm) + held        # every group's padding fits
+    rows = n_blocks * tm
+    ub = u.astype(w_gate_up.dtype)
+    u_ext = jnp.concatenate([ub, jnp.zeros((1, D), ub.dtype)])
+
+    def one_buffer(c, out):
+        lo = c * cap
+        # this buffer's slice of each expert's group, padded to blocks
+        g_lo = jnp.clip(starts, lo, lo + cap)
+        g_n = jnp.clip(ends, lo, lo + cap) - g_lo
+        g_pad = -(-g_n // tm) * tm
+        g_end = jnp.cumsum(g_pad)
+        g_off = g_end - g_pad
+        pos = lo + jnp.arange(cap, dtype=jnp.int32)
+        e = jax.lax.dynamic_slice(e_sorted, (lo,), (cap,))
+        tok = jax.lax.dynamic_slice(tok_sorted, (lo,), (cap,))
+        wt = jax.lax.dynamic_slice(w_sorted, (lo,), (cap,))
+        ec = jnp.minimum(e, held - 1)
+        dest = jnp.where(e < held, g_off[ec] + pos - g_lo[ec], rows)
+        row_tok = jnp.full((rows,), T, jnp.int32).at[dest].set(
+            tok, mode="drop")
+        row_w = jnp.zeros((rows,), jnp.float32).at[dest].set(
+            wt, mode="drop")
+        # block b is of the first expert whose padded group ends past it
+        block_expert = jnp.minimum(
+            (g_end[None, :] // tm <= jnp.arange(n_blocks)[:, None]).sum(1),
+            held - 1).astype(jnp.int32)
+        n_used = g_end[-1] // tm
+        x = u_ext[row_tok]
+        gu = grouped_matmul(x, w_gate_up, block_expert, n_used, tm)
+        h = (jax.nn.silu(gu[:, :F].astype(jnp.float32))
+             * gu[:, F:].astype(jnp.float32)).astype(x.dtype)
+        y = grouped_matmul(h, w_down, block_expert, n_used, tm, 512,
+                           jnp.float32)
+        # rows past the used blocks were never written: they carry row
+        # T, which the scatter drops
+        return out.at[row_tok].add(y * row_w[:, None], mode="drop")
+
+    # at most k buffers (every pair of every token held here); one that
+    # starts past the last pair is skipped. A scan over a cond, not a
+    # loop to a computed bound, so that the small training runs can
+    # differentiate it.
+    def step(out, c):
+        return jax.lax.cond(c * cap < n_pairs,
+                            lambda o: one_buffer(c, o), lambda o: o,
+                            out), None
+
+    out, _ = jax.lax.scan(step, jnp.zeros((T, D), jnp.float32),
+                          jnp.arange(k, dtype=jnp.int32))
+    any_here = here.any(axis=1)
+    n_live = T if live is None else live.sum()
+    return out, MoeStats(counts, (n_live - any_here.sum()).astype(jnp.int32))

@@ -23,113 +23,104 @@ TPU design:
   - in-batch sampled softmax against the batch's target items (the
     two-tower recipe) — no [B, n_items] logits materialize in training.
 
-Serving encodes the user's RECENT history read from the event store at
-query time (the e-commerce template's serve-time-read pattern,
-ECommAlgorithm.scala:331-430) and scores all items with one masked
-top-k matmul (`ops.topk`).
+The layer stack is data (`ops/backbone.py`): this module's own small
+block is the configuration `sasrec`, and a public architecture's stack
+at its published widths is another configuration of the same code.
+
+Serving reads the user's history from the event store at query time
+(the e-commerce template's serve-time-read pattern,
+ECommAlgorithm.scala:331-430), PACKS the batch's histories on one token
+axis (`PackedEncoder`: a few token buckets compiled ahead of time, so
+no query compiles), and hands each history's last position to the
+catalog top-k serve plan (`ops.topk`).
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+import functools
+from dataclasses import dataclass, replace
 from functools import partial
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.ops.attention import ring_attention
+from predictionio_tpu.obs import trace
+from predictionio_tpu.ops import backbone as bb
+from predictionio_tpu.ops.attention import packed_attention, ring_attention
 
 
 @dataclass
 class SeqRecModel:
-    params: dict           # transformer weights (numpy pytree)
-    seq_len: int
+    params: dict           # the stack's weights, one pytree keyed by layer
     n_items: int
-    n_heads: int
+    backbone: Dict[str, Any]    # `bb.config_dict` of the stack's config
+
+    @functools.cached_property
+    def config(self) -> bb.BackboneConfig:
+        return bb.config_of(self.backbone)
 
     @property
+    def seq_len(self) -> int:
+        return self.config.max_history
+
+    @functools.cached_property
     def item_emb(self) -> np.ndarray:
-        """[n_items, D] tied output/input item table (PAD row dropped)."""
-        return np.asarray(self.params["item_table"])[:self.n_items]
+        """[n_items, D] float32, the table the last position is scored
+        against (the item table itself where it is tied; PAD row
+        dropped)."""
+        return np.asarray(bb.head_table(self.params, self.config),
+                          np.float32)[:self.n_items]
 
     def sanity_check(self):
-        assert all(np.isfinite(v).all() for v in
+        assert all(np.isfinite(np.asarray(v, np.float32)).all() for v in
                    jax.tree_util.tree_leaves(self.params))
 
     def __getstate__(self):
         # the serve-time device-param cache (_devp) must not be pickled
         # with the model (persistence stores numpy weights only)
         d = dict(self.__dict__)
-        d.pop("_devp", None)
+        for cached in ("_devp", "config", "item_emb"):
+            d.pop(cached, None)
         return d
 
 
-def _init_params(key, n_items: int, seq_len: int, dim: int,
-                 n_layers: int):
-    ks = iter(jax.random.split(key, 4 + 7 * n_layers))
-
-    def dense(fan_in, fan_out):
-        return (jax.random.normal(next(ks), (fan_in, fan_out),
-                                  jnp.float32) / np.sqrt(fan_in))
-
-    p = {
-        # row n_items is the PAD embedding (kept at its random init;
-        # attention masks PAD keys so it never leaks into real rows)
-        "item_table": jax.random.normal(
-            next(ks), (n_items + 1, dim), jnp.float32) / np.sqrt(dim),
-        "pos_emb": jax.random.normal(
-            next(ks), (seq_len, dim), jnp.float32) * 0.02,
-        "ln_f": jnp.ones(dim), "ln_f_b": jnp.zeros(dim),
-    }
-    for layer in range(n_layers):
-        p[f"l{layer}"] = {
-            "ln1": jnp.ones(dim), "ln1_b": jnp.zeros(dim),
-            "wq": dense(dim, dim), "wk": dense(dim, dim),
-            "wv": dense(dim, dim), "wo": dense(dim, dim),
-            "ln2": jnp.ones(dim), "ln2_b": jnp.zeros(dim),
-            "w1": dense(dim, 2 * dim), "w2": dense(2 * dim, dim),
-        }
-    return p
+def resolve_backbone(backbone: str, *, dim: int, n_heads: int,
+                     n_layers: int, seq_len: int,
+                     n_items: int) -> bb.BackboneConfig:
+    """`sasrec` is built from the template's own parameters; anything
+    else is a configuration file, a stack whose vocabulary is this
+    catalog."""
+    if backbone == "sasrec":
+        return bb.sasrec_config(dim=dim, n_heads=n_heads,
+                                n_layers=n_layers, seq_len=seq_len,
+                                n_items=n_items)
+    return replace(bb.load_config(backbone), vocab=n_items)
 
 
-def _ln(x, g, b):
-    mu = x.mean(-1, keepdims=True)
-    var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + 1e-6) * g + b
-
-
-def _encode(params, seqs, *, n_items: int, n_heads: int, n_layers: int,
+def _encode(params, seqs, *, cfg: bb.BackboneConfig, n_items: int,
             mesh=None):
     """seqs [B, S] int32 (PAD = n_items, right-aligned) -> [B, D] the
     final-position representation."""
-    B, S = seqs.shape
-    D = params["pos_emb"].shape[1]
-    Dh = D // n_heads
     valid = seqs != n_items                                # [B, S]
-    x = params["item_table"][seqs] * np.sqrt(D) + params["pos_emb"]
+    if cfg.positions:           # learned: a slot of the padded window
+        pos = jnp.broadcast_to(jnp.arange(seqs.shape[1]), seqs.shape)
+    else:                       # the event's index in its own history
+        pos = jnp.maximum(jnp.cumsum(valid, axis=1) - 1, 0)
 
     # ring_attention's trivial-axis fall-through handles mesh=None too
-    attend = partial(ring_attention, mesh=mesh)
-    for layer in range(n_layers):
-        lp = params[f"l{layer}"]
-        h = _ln(x, lp["ln1"], lp["ln1_b"])
-        q = (h @ lp["wq"]).reshape(B, S, n_heads, Dh)
-        k = (h @ lp["wk"]).reshape(B, S, n_heads, Dh)
-        v = (h @ lp["wv"]).reshape(B, S, n_heads, Dh)
-        a = attend(q, k, v, causal=True, kv_mask=valid)
-        x = x + a.reshape(B, S, D) @ lp["wo"]
-        h = _ln(x, lp["ln2"], lp["ln2_b"])
-        x = x + jax.nn.relu(h @ lp["w1"]) @ lp["w2"]
-    x = _ln(x, params["ln_f"], params["ln_f_b"])
+    def attend(q, k, v, *, window, sink):
+        return ring_attention(q, k, v, mesh, causal=True, kv_mask=valid,
+                              window=window, sink=sink)
+
+    x, _ = bb.forward(params, cfg, seqs, pos, attend, valid=valid)
     return x[:, -1, :]                     # right-aligned: last = newest
 
 
-def _loss_fn(params, seqs, targets, temperature, *, n_items, n_heads,
-             n_layers, mesh):
-    u = _encode(params, seqs, n_items=n_items, n_heads=n_heads,
-                n_layers=n_layers, mesh=mesh)
-    t = params["item_table"][targets]                      # [B, D]
+def _loss_fn(params, seqs, targets, temperature, *, cfg, n_items, mesh):
+    u = _encode(params, seqs, cfg=cfg, n_items=n_items, mesh=mesh)
+    t = bb.head_table(params, cfg)[targets].astype(jnp.float32)  # [B, D]
     logits = (u @ t.T) / temperature                       # in-batch
     labels = jnp.arange(seqs.shape[0])
     return -jnp.mean(jax.nn.log_softmax(logits)[labels, labels])
@@ -141,21 +132,27 @@ def seqrec_train(sequences: np.ndarray, targets: np.ndarray, *,
                  batch_size: int = 256, epochs: int = 5,
                  lr: float = 3e-3, temperature: float = 0.07,
                  seed: int = 0, mesh=None,
-                 init_params=None) -> SeqRecModel:
+                 init_params=None, backbone: str = "sasrec",
+                 losses: Optional[List[float]] = None) -> SeqRecModel:
     """Train on [N, seq_len] right-aligned item-id sequences (PAD =
     n_items) with [N] next-item targets. `mesh` shards the batch over
     "data" and — when the mesh has an "sp" axis — the sequence over it
     via ring attention. `init_params` resumes from a prior model's
     weights (the streaming warm-start mini-epoch); optimizer state
-    starts fresh."""
+    starts fresh. `backbone` is the stack (`sasrec`: built from dim /
+    n_heads / n_layers; else a configuration file). A router's correction bias is held
+    constant (its balancing update is no gradient step and is not
+    made here). `losses` collects every step's loss."""
     import optax
 
     assert sequences.shape[1] == seq_len
+    cfg = resolve_backbone(backbone, dim=dim, n_heads=n_heads,
+                           n_layers=n_layers, seq_len=seq_len,
+                           n_items=n_items)
     if init_params is not None:
         params = jax.tree_util.tree_map(jnp.asarray, init_params)
     else:
-        params = _init_params(jax.random.PRNGKey(seed), n_items,
-                              seq_len, dim, n_layers)
+        params = bb.init_params(jax.random.PRNGKey(seed), cfg)
     opt = optax.adam(lr)
     opt_state = opt.init(params)
     n = (len(sequences) // batch_size) * batch_size
@@ -168,22 +165,24 @@ def seqrec_train(sequences: np.ndarray, targets: np.ndarray, *,
                           .astype(np.int32))
 
     loss = partial(_loss_fn, temperature=jnp.float32(temperature),
-                   n_items=n_items, n_heads=n_heads, n_layers=n_layers,
-                   mesh=mesh)
+                   cfg=cfg, n_items=n_items, mesh=mesh)
 
     @jax.jit
     def epoch(params, opt_state, seq_all, tgt_all):
         def body(carry, batch):
             params, opt_state = carry
             seqs, tgts = batch
-            g = jax.grad(loss)(params, seqs, tgts)
+            value, g = jax.value_and_grad(loss)(params, seqs, tgts)
+            for layer in range(len(cfg.layers)):
+                ffn = g[f"l{layer}"]["ffn"]
+                if "bias" in ffn:       # the correction bias is no weight
+                    ffn["bias"] = jnp.zeros_like(ffn["bias"])
             updates, opt_state = opt.update(g, opt_state, params)
             return (optax.apply_updates(params, updates),
-                    opt_state), None
+                    opt_state), value
 
-        (params, opt_state), _ = jax.lax.scan(
-            body, (params, opt_state), (seq_all, tgt_all))
-        return params, opt_state
+        return jax.lax.scan(body, (params, opt_state),
+                            (seq_all, tgt_all))
 
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -192,34 +191,215 @@ def seqrec_train(sequences: np.ndarray, targets: np.ndarray, *,
         tgt_all = jax.device_put(
             tgt_all, NamedSharding(mesh, P(None, "data")))
     for _ in range(epochs):
-        params, opt_state = epoch(params, opt_state, seq_all, tgt_all)
+        (params, opt_state), values = epoch(params, opt_state, seq_all,
+                                            tgt_all)
+        if losses is not None:
+            losses.extend(float(v) for v in np.asarray(values))
     params_np = jax.tree_util.tree_map(np.asarray, params)
-    return SeqRecModel(params=params_np, seq_len=seq_len,
-                       n_items=n_items, n_heads=n_heads)
+    return SeqRecModel(params=params_np, n_items=n_items,
+                       backbone=bb.config_dict(cfg))
 
 
-@partial(jax.jit, static_argnames=("n_items", "n_heads", "n_layers"))
-def _encode_jit(params, seqs, *, n_items, n_heads, n_layers):
-    return _encode(params, seqs, n_items=n_items, n_heads=n_heads,
-                   n_layers=n_layers, mesh=None)
+@partial(jax.jit, static_argnames=("cfg", "n_items"))
+def _encode_jit(params, seqs, *, cfg, n_items):
+    return _encode(params, seqs, cfg=cfg, n_items=n_items, mesh=None)
 
 
-def seqrec_encode(model: SeqRecModel, seqs: np.ndarray) -> np.ndarray:
-    """[B, seq_len] histories -> [B, D] user representations. The
-    SERVING hot path: device-resident params are cached on the model
-    (outside its pickled state, see SeqRecModel.__getstate__) and the
-    encoder runs as one jitted program instead of eager per-op
-    dispatch."""
+def device_params(model: SeqRecModel):
+    """The model's weights on the device, cached on the model outside
+    its pickled state (see SeqRecModel.__getstate__)."""
     devp = getattr(model, "_devp", None)
     if devp is None:
         devp = jax.tree_util.tree_map(jnp.asarray, model.params)
         model._devp = devp
-    n_layers = sum(1 for k in model.params if k.startswith("l")
-                   and k[1:].isdigit())
-    out = _encode_jit(devp, jnp.asarray(seqs.astype(np.int32)),
-                      n_items=model.n_items, n_heads=model.n_heads,
-                      n_layers=n_layers)
+    return devp
+
+
+def seqrec_encode(model: SeqRecModel, seqs: np.ndarray) -> np.ndarray:
+    """[B, seq_len] padded histories -> [B, D] user representations:
+    the offline form (evaluation, tests), one jitted program a shape.
+    The serve path packs instead (`PackedEncoder`)."""
+    out = _encode_jit(device_params(model),
+                      jnp.asarray(seqs.astype(np.int32)),
+                      cfg=model.config, n_items=model.n_items)
     return np.asarray(out)
+
+
+# -- the serve path: packed histories in token buckets ------------------------
+
+_SEQ_METRICS = None
+
+
+def _seq_metrics():
+    """The packed encoder's counters, in the process-default registry
+    (lazy, like `ops/topk._dispatch_total`)."""
+    global _SEQ_METRICS
+    if _SEQ_METRICS is None:
+        from predictionio_tpu.obs import get_registry
+        reg = get_registry()
+        share = (0.01, 0.02, 0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7,
+                 0.8, 0.9, 1.0)
+        _SEQ_METRICS = {
+            "tokens": reg.histogram(
+                "pio_seq_call_tokens",
+                "Live tokens (history events) of one call of the "
+                "packed stack",
+                buckets=tuple(float(2 ** i) for i in range(4, 15))),
+            "pad": reg.histogram(
+                "pio_seq_pad_share",
+                "Padding tokens over the token bucket, per call of the "
+                "packed stack", buckets=share),
+            "events": reg.histogram(
+                "pio_seq_history_events",
+                "Events of one query's history as encoded (after the "
+                "cut to max_history)",
+                buckets=tuple(float(2 ** i) for i in range(0, 13))),
+            "load": reg.histogram(
+                "pio_moe_expert_tokens_max_over_mean",
+                "Tokens of the busiest held expert over the held "
+                "experts' mean, mean over the expert layers, per call",
+                buckets=(1.0, 1.1, 1.2, 1.35, 1.5, 1.75, 2.0, 2.5, 3.0,
+                         4.0, 6.0, 8.0, 16.0)),
+            "unrouted": reg.histogram(
+                "pio_moe_unrouted_share",
+                "Share of a call's live tokens none of whose experts is "
+                "held here, mean over the expert layers", buckets=share),
+            "pairs": reg.histogram(
+                "pio_moe_expert_pairs",
+                "(Token, held expert) pairs one call computed, summed "
+                "over its expert layers",
+                buckets=tuple(float(2 ** i) for i in range(6, 21))),
+        }
+    return _SEQ_METRICS
+
+
+def _packed_last(params, tokens, seg, start, last, *, cfg):
+    """One call of the packed stack: tokens / seg / start [Tb], `last`
+    [rows] the token index of each history's newest event -> ([rows,
+    D] float32, the expert layers' counts)."""
+    Tb, rows = tokens.shape[0], last.shape[0]
+    live = seg != rows              # padding is segment `rows`
+    idx = jnp.arange(Tb, dtype=jnp.int32) - start
+    if cfg.positions:               # learned: slots of a right-aligned row
+        seg_len = jnp.zeros((rows + 1,), jnp.int32).at[seg].add(1)[seg]
+        idx = idx + cfg.positions - seg_len
+
+    def attend(q, k, v, *, window, sink):
+        return packed_attention(q, k, v, seg, start, window=window,
+                                sink=sink, max_segment=cfg.max_history)
+
+    with jax.named_scope("seq_stack"):
+        x, stats = bb.forward(params, cfg, tokens, idx, attend, valid=live)
+    return x[last], stats
+
+
+class PackedEncoder:
+    """The sequence model's serve plan: the weights pinned on the
+    device and one executable a token bucket, compiled ahead of time
+    (`warm`). A call packs histories in arrival order into stack calls
+    of at most `max_batch_tokens` tokens and `rows` histories, pads
+    each to the next bucket (the padding is one more segment that no
+    history sees and no expert is loaded for), and returns each
+    history's last-position vector. Steady state compiles nothing."""
+
+    def __init__(self, model: SeqRecModel, *, rows: int):
+        self.cfg = model.config
+        self.params = device_params(model)
+        self.rows = int(rows)
+        self.buckets = tuple(sorted(self.cfg.token_buckets))
+        if not self.buckets or self.buckets[-1] < self.cfg.max_history:
+            raise ValueError(
+                f"backbone {self.cfg.name!r}: token buckets "
+                f"{self.buckets} do not hold a history of "
+                f"{self.cfg.max_history}")
+        self.max_tokens = min(self.cfg.max_batch_tokens or self.buckets[-1],
+                              self.buckets[-1])
+        self._exe: Dict[int, Any] = {}
+
+    def warm(self) -> int:
+        def seq_stack_call(params, tokens, seg, start, last):
+            return _packed_last(params, tokens, seg, start, last,
+                                cfg=self.cfg)
+
+        fn = jax.jit(seq_stack_call)     # the trace names it by this
+        compiled = 0
+        for b in self.buckets:
+            if b in self._exe:
+                continue
+            ints = jax.ShapeDtypeStruct((b,), np.int32)
+            self._exe[b] = fn.lower(
+                self.params, ints, ints, ints,
+                jax.ShapeDtypeStruct((self.rows,), np.int32)).compile()
+            compiled += 1
+        return compiled
+
+    def _calls(self, histories: Sequence[Sequence[int]]):
+        """Arrival order, cut where the next history would pass the
+        call's tokens or rows."""
+        calls, cur, used = [], [], 0
+        for h in histories:
+            if cur and (used + len(h) > self.max_tokens
+                        or len(cur) == self.rows):
+                calls.append(cur)
+                cur, used = [], 0
+            cur.append(h)
+            used += len(h)
+        if cur:
+            calls.append(cur)
+        return calls
+
+    def __call__(self, histories: Sequence[Sequence[int]]) -> np.ndarray:
+        """histories: item indexes, oldest first, each 1 to max_history
+        long -> [n, D] float32, one row a history."""
+        metrics = _seq_metrics()
+        launched = []
+        for call in self._calls(histories):
+            with trace.stage("seq_pack"):
+                lens = np.fromiter(map(len, call), np.int64, len(call))
+                n_tok = int(lens.sum())
+                bucket = next(b for b in self.buckets if b >= n_tok)
+                ends = np.cumsum(lens)
+                tokens = np.zeros(bucket, np.int32)
+                tokens[:n_tok] = np.concatenate(
+                    [np.asarray(h, np.int32) for h in call])  # lint: ok
+                seg = np.full(bucket, self.rows, np.int32)
+                seg[:n_tok] = np.repeat(np.arange(len(call)), lens)
+                start = np.full(bucket, n_tok, np.int32)
+                start[:n_tok] = np.repeat(ends - lens, lens)
+                last = np.zeros(self.rows, np.int32)
+                last[:len(call)] = ends - 1
+            with trace.stage("seq_launch"):
+                exe = self._exe.get(bucket)
+                if exe is None:
+                    raise RuntimeError(
+                        f"PackedEncoder bucket {bucket} not warmed; "
+                        "call warm() at deploy time")
+                launched.append((len(call), n_tok, bucket, lens,
+                                 exe(self.params, tokens, seg, start,
+                                     last)))
+        out = []
+        with trace.stage("seq_fetch"):
+            fetched = [jax.device_get(x[-1]) for x in launched]
+        for (n, n_tok, bucket, lens, _), (vecs, stats) in zip(launched,
+                                                              fetched):
+            out.append(vecs[:n])
+            try:
+                metrics["tokens"].observe(n_tok)
+                metrics["pad"].observe(1.0 - n_tok / bucket)
+                for ln in lens:
+                    metrics["events"].observe(float(ln))
+                if stats is not None:
+                    per = np.asarray(stats.expert_tokens, np.float64)
+                    mean = per.mean(axis=1)
+                    if (mean > 0).all():
+                        metrics["load"].observe(
+                            float((per.max(axis=1) / mean).mean()))
+                    metrics["unrouted"].observe(float(
+                        np.asarray(stats.unrouted).mean() / n_tok))
+                    metrics["pairs"].observe(float(per.sum()))
+            except Exception:
+                pass  # metrics must never fail a serve call
+        return np.concatenate(out).astype(np.float32)
 
 
 def build_sequences(user_ix: np.ndarray, item_ix: np.ndarray,

@@ -639,8 +639,8 @@ class BucketedTopK:
             if exe is not None:
                 self.fused_buckets += 1
                 self._fused_sizes.add(b)
-                self._gate_blocks = fused_topk.gate_blocks(self.n_items,
-                                                           self.k)
+                self._gate_blocks = fused_topk.gate_blocks(
+                    self.n_items, self.k, self.rank)
             else:
                 vec_spec = jax.ShapeDtypeStruct((b, self.rank),
                                                 np.float32)

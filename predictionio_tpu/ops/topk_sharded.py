@@ -400,7 +400,7 @@ class ShardedBucketedTopK(_ShardedPlanBase):
             if local is None:
                 return None
             self.fused = True
-            self._gate_blocks = (fused_topk.gate_blocks(per, kk)
+            self._gate_blocks = (fused_topk.gate_blocks(per, kk, self.rank)
                                  * self.n_shards)
 
         def body(vecs, factors_local, banned):

@@ -1,0 +1,545 @@
+"""The sequence recommender's backbone at the `tiny-mimo` preset (hidden
+64, 4 heads, 1 / 2 KV heads, head sizes 24 / 16 with 8 rotary, window 8,
+16 experts top-2 of which 4 held, dense width 128, expert width 32,
+vocabulary 97, the 7-layer pattern), against the plain reference of the
+benchmark (`benchmark/seq_reference.py`, which imports nothing of the
+program) on seeded weights."""
+
+import json
+import sys
+import threading
+import time
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT / "benchmark") not in sys.path:
+    sys.path.append(str(ROOT / "benchmark"))
+
+import seq_datagen                                         # noqa: E402
+import seq_reference as ref                                # noqa: E402
+
+from predictionio_tpu.obs import trace                     # noqa: E402
+from predictionio_tpu.ops import backbone as bb            # noqa: E402
+from predictionio_tpu.ops import moe                       # noqa: E402
+from predictionio_tpu.ops.attention import (               # noqa: E402
+    attention_reference, packed_attention,
+)
+from predictionio_tpu.ops.seqrec import (                  # noqa: E402
+    PackedEncoder, SeqRecModel, build_sequences, seqrec_train,
+)
+
+SEED = 5
+DOC = json.loads((ROOT / "benchmark" / "configs" / "tiny-mimo.json")
+                 .read_text())
+CFG = bb.config_from_json(DOC, "tiny-mimo")
+ARCH = ref.arch(DOC)
+LENGTHS = (7, 8, 9, 40)            # around the window of 8
+
+
+@pytest.fixture(scope="module")
+def weights():
+    """(the program's pytree, the reference's dict): the same draws."""
+    return (seq_datagen.program_params(DOC, SEED, jnp.float32),
+            seq_datagen.reference_params(DOC, SEED))
+
+
+@pytest.fixture(scope="module")
+def histories():
+    rng = np.random.default_rng(0)
+    return [rng.integers(0, CFG.vocab, n).tolist() for n in LENGTHS]
+
+
+def _encoder(params, rows=8):
+    model = SeqRecModel(params=params, n_items=CFG.vocab,
+                        backbone=bb.config_dict(CFG))
+    enc = PackedEncoder(model, rows=rows)
+    enc.warm()
+    return enc
+
+
+@pytest.fixture(scope="module")
+def encoder(weights):
+    return _encoder(weights[0])
+
+
+def _logits(params, vecs):
+    return np.asarray(vecs) @ np.asarray(params["head"], np.float32).T
+
+
+# -- each block kind against the reference -----------------------------------
+
+@pytest.mark.parametrize("layer,kind", [(0, "attn_full"), (2, "attn_window")])
+def test_attention_block_matches_reference(weights, layer, kind):
+    pp, rp = weights
+    assert CFG.layers[layer][0] == kind
+    T = 21
+    u = jnp.asarray(np.random.default_rng(1).normal(size=(T, CFG.hidden)),
+                    jnp.float32)
+    seg, start = jnp.zeros(T, jnp.int32), jnp.zeros(T, jnp.int32)
+
+    def attend(q, k, v, *, window, sink):
+        pad = 32 - T          # the packed kernel takes whole blocks
+        q, k, v = (jnp.pad(x, ((0, pad), (0, 0), (0, 0))) for x in (q, k, v))
+        s = jnp.concatenate([seg, jnp.ones(pad, jnp.int32)])
+        st = jnp.concatenate([start, jnp.full(pad, T, jnp.int32)])
+        return packed_attention(q, k, v, s, st, window=window, sink=sink,
+                                max_segment=CFG.max_history, block_q=8,
+                                block_k=8)[:T]
+
+    got = bb.attention_block(pp[f"l{layer}"]["attn"], CFG, kind, u,
+                             jnp.arange(T), attend)
+    p = {k: jnp.asarray(v) for k, v in rp[f"l{layer}"].items()}
+    with jax.default_matmul_precision("highest"):
+        want = ref.attention(ARCH, ARCH["layers"][layer][0], p, u)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_dense_block_matches_reference(weights):
+    pp, rp = weights
+    u = jnp.asarray(np.random.default_rng(2).normal(size=(13, CFG.hidden)),
+                    jnp.float32)
+    p = {k: jnp.asarray(v) for k, v in rp["l0"].items()}
+    np.testing.assert_allclose(bb.ffn_dense(pp["l0"]["ffn"], CFG, u),
+                               ref.dense_ffn(p, u), atol=1e-5)
+
+
+def test_expert_block_matches_reference_share(weights):
+    pp, rp = weights
+    u = jnp.asarray(np.random.default_rng(3).normal(size=(50, CFG.hidden)),
+                    jnp.float32)
+    got, stats = bb.ffn_moe(pp["l1"]["ffn"], CFG, u)
+    p = {k: jnp.asarray(v) for k, v in rp["l1"].items()}
+    want = ref.expert_ffn(ARCH, p, u, (ARCH["first"], ARCH["held"]))
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    assert np.abs(np.asarray(want)).max() > 1e-3
+    sel, _ = ref.route(ARCH, p, u)
+    held = (np.asarray(sel) >= ARCH["first"]) \
+        & (np.asarray(sel) < ARCH["first"] + ARCH["held"])
+    assert int(stats.unrouted) == int((~held.any(axis=1)).sum())
+    np.testing.assert_array_equal(
+        stats.expert_tokens,
+        [(np.asarray(sel) == ARCH["first"] + e).sum()
+         for e in range(ARCH["held"])])
+
+
+# -- the whole stack, packed --------------------------------------------------
+
+def test_packed_stack_matches_reference_one_history_at_a_time(
+        weights, encoder, histories):
+    pp, rp = weights
+    got = _logits(pp, encoder(histories))
+    for row, h in zip(got, histories):
+        np.testing.assert_allclose(row, ref.forward(DOC, rp, h), atol=1e-5)
+
+
+def test_a_history_alone_and_packed_among_others_agree(encoder, histories):
+    enc = encoder
+    packed = enc(histories)
+    for j, h in enumerate(histories):
+        np.testing.assert_allclose(enc([h])[0], packed[j], atol=1e-5)
+
+
+def test_calls_split_at_the_token_budget_and_stay_in_order(encoder):
+    enc = encoder
+    rng = np.random.default_rng(4)
+    hs = [rng.integers(0, CFG.vocab, n).tolist()
+          for n in (48, 48, 48, 48, 48, 48, 3, 5, 7, 9, 11, 2, 2, 2, 2, 2)]
+    calls = enc._calls(hs)
+    assert [h for c in calls for h in c] == hs
+    assert all(sum(map(len, c)) <= enc.max_tokens and len(c) <= enc.rows
+               for c in calls) and len(calls) > 2
+    assert any(len(c) == enc.rows for c in calls)
+    got = enc(hs)
+    np.testing.assert_allclose(got[6], enc([hs[6]])[0], atol=1e-5)
+
+
+def test_layerwise_reference_is_the_reference(weights, histories):
+    _, rp = weights
+    layerwise = ref.forward_layerwise(
+        DOC, seq_datagen.layer_stream(DOC, SEED), histories)
+    for row, h in zip(layerwise, histories):
+        np.testing.assert_allclose(row, ref.forward(DOC, rp, h), atol=2e-5)
+
+
+# -- the attention kernel's arguments ----------------------------------------
+
+def _packed_case(window, sink, T=64, H=4, Hkv=2, Dq=24, Dv=16):
+    rng = np.random.default_rng(7)
+    lens = [7, 8, 9, 25]
+    pad = T - sum(lens)
+    seg = np.concatenate([np.full(n, i) for i, n in enumerate(lens + [pad])])
+    start = np.concatenate([np.full(n, s) for n, s in zip(
+        lens + [pad], np.cumsum([0] + lens))])
+    q = jnp.asarray(rng.normal(size=(T, H, Dq)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(T, Hkv, Dq)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(T, Hkv, Dv)), jnp.float32)
+    seg, start = jnp.asarray(seg, jnp.int32), jnp.asarray(start, jnp.int32)
+    got = packed_attention(q, k, v, seg, start, window=window, sink=sink,
+                           max_segment=25, block_q=8, block_k=8)
+    want = attention_reference(q[None], k[None], v[None], causal=True,
+                               window=window, sink=sink,
+                               segment_ids=seg[None])[0]
+    return got, want, sum(lens)
+
+
+_SINK = jnp.asarray([0.3, -1.2, 2.0, 0.0], jnp.float32)
+
+
+@pytest.mark.parametrize("window,sink", [(None, None), (8, None),
+                                         (None, _SINK), (8, _SINK)])
+def test_packed_attention_matches_plain(window, sink):
+    got, want, live = _packed_case(window, sink)
+    np.testing.assert_allclose(got[:live], want[:live], atol=1e-5)
+
+
+def test_sink_at_minus_infinity_is_no_sink_and_a_finite_one_is_not():
+    none, _, live = _packed_case(8, None)
+    minus_inf, _, _ = _packed_case(8, jnp.full((4,), -jnp.inf))
+    finite, _, _ = _packed_case(8, _SINK)
+    np.testing.assert_array_equal(none[:live], minus_inf[:live])
+    assert np.abs(np.asarray(finite - none)[:live]).max() > 1e-2
+
+
+def test_the_window_counts_the_query_itself():
+    got8, _, live = _packed_case(8, None)
+    got9, _, _ = _packed_case(9, None)
+    # token 6 of the first history (7 events) sees all 7 under both;
+    # token 8 of the third (9 events) sees 8 under window 8, 9 under 9
+    np.testing.assert_allclose(got8[6], got9[6], atol=1e-6)
+    assert np.abs(np.asarray(got8[7 + 8 + 8] - got9[7 + 8 + 8])).max() > 1e-4
+
+
+# -- the router and the shares ------------------------------------------------
+
+def test_selection_uses_s_plus_c_and_weights_use_s():
+    u = jnp.eye(4, dtype=jnp.float32)[:1] * 0.0          # s = 0.5 everywhere
+    w = jnp.zeros((4, 6), jnp.float32)
+    c = jnp.asarray([0.0, 0.3, 0.0, 0.2, 0.0, 0.1], jnp.float32)
+    r = moe.route(u, w, c, top_k=2, norm_topk_prob=False)
+    assert sorted(np.asarray(r.experts[0]).tolist()) == [1, 3]
+    np.testing.assert_allclose(r.weights[0], [0.5, 0.5])  # s, not s + c
+    r = moe.route(u, w, c, top_k=2)
+    np.testing.assert_allclose(r.weights[0], [0.5, 0.5])  # normalised s
+
+
+@pytest.mark.parametrize("live", [None, "some"])
+def test_tokens_over_experts_sum_to_t_times_k(weights, live):
+    pp, _ = weights
+    f = pp["l1"]["ffn"]
+    T = 37
+    u = jnp.asarray(np.random.default_rng(5).normal(size=(T, CFG.hidden)),
+                    jnp.float32)
+    mask = None if live is None else jnp.arange(T) < 30
+    routing = moe.route(u, f["router"], f["bias"], top_k=CFG.top_k)
+    total = 0
+    for share in range(4):
+        first, held = moe.expert_share(share, 4, CFG.n_experts)
+        _, stats = moe.moe_apply(u, routing, f["w_gate_up"], f["w_down"],
+                                 first=first, live=mask)
+        total += int(stats.expert_tokens.sum())
+    assert total == (T if live is None else 30) * CFG.top_k
+
+
+def test_the_four_shares_add_up_to_the_uncut_expert_layer():
+    """All 16 experts drawn, each share's part computed by the program
+    with its own 4, and the parts summed: the reference's whole layer."""
+    whole = dict(DOC, n_routed_experts=16,
+                 expert_share={"index": 0, "count": 1})
+    a = ref.arch(whole)
+    p = {k: jnp.asarray(v) for k, v in
+         seq_datagen.reference_params(whole, SEED)["l1"].items()}
+    u = jnp.asarray(np.random.default_rng(6).normal(size=(45, CFG.hidden)),
+                    jnp.float32)
+    want = ref.expert_ffn(a, p, u, None)
+    routing = moe.route(u, p["router"], p["bias"], top_k=CFG.top_k)
+    total = np.zeros_like(np.asarray(want))
+    for share in range(4):
+        first, held = moe.expert_share(share, 4, 16)
+        part, _ = moe.moe_apply(
+            u, routing, p["w_gate_up"][first:first + held],
+            p["w_down"][first:first + held], first=first)
+        assert np.abs(np.asarray(part)).max() > 1e-4
+        total += np.asarray(part)
+    np.testing.assert_allclose(total, want, atol=1e-5)
+
+
+def test_more_pairs_than_one_buffer_holds_are_all_computed(weights):
+    """Every token's two experts held here: twice the buffer."""
+    pp, _ = weights
+    f = pp["l1"]["ffn"]
+    T = 24
+    u = jnp.asarray(np.random.default_rng(8).normal(size=(T, CFG.hidden)),
+                    jnp.float32)
+    routing = moe.Routing(jnp.tile(jnp.asarray([[0, 3]], jnp.int32), (T, 1)),
+                          jnp.full((T, 2), 0.5, jnp.float32))
+    got, stats = moe.moe_apply(u, routing, f["w_gate_up"], f["w_down"],
+                               first=0)
+    want = 0
+    for e in (0, 3):
+        gu = u @ f["w_gate_up"][e]
+        F = CFG.expert_width
+        want = want + 0.5 * (jax.nn.silu(gu[:, :F]) * gu[:, F:]) \
+            @ f["w_down"][e]
+    assert int(stats.expert_tokens.sum()) == 2 * T
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+# -- precision: the stated one inside, the one below outside ------------------
+
+def test_bfloat16_path_inside_its_tolerance_and_the_control_outside(
+        histories):
+    """As `seq_control.py` on the chip, at the toy size: the program in
+    bfloat16 and the reference with bfloat16 operands read alike and
+    under the cell's limits; float8 operands read over one."""
+    import seq_control
+    limits = json.loads((ROOT / "benchmark" / "workloads"
+                         / "mimo25-hist-c32.json").read_text())[
+                             "correct"]["limits"]
+    rp = seq_datagen.reference_params(DOC, SEED)
+    truth = np.stack([ref.forward(DOC, rp, h) for h in histories])
+    pp = seq_datagen.program_params(DOC, SEED)             # bfloat16
+    program = _logits(pp, _encoder(pp)(histories))
+    with ref.operands("bf16"):
+        stated = np.stack([ref.forward(DOC, rp, h) for h in histories])
+    with ref.operands("fp8"):
+        control = np.stack([ref.forward(DOC, rp, h) for h in histories])
+    k = 10
+    for got in (program, stated):
+        n = seq_control.numbers(got, truth, k)
+        assert n["score_err"] <= limits["score_err"]
+        assert n["rank_gap"] <= limits["rank_gap"]
+    n = seq_control.numbers(control, truth, k)
+    assert max(n["score_err"] / limits["score_err"],
+               n["rank_gap"] / limits["rank_gap"]) > 1.0
+
+
+# -- configurations -----------------------------------------------------------
+
+CONFIGS = ROOT / "benchmark" / "configs"
+
+
+@pytest.mark.parametrize("name", ["tiny-mimo", "mimo-v2.5-ep16-7l"])
+def test_a_configuration_is_its_file_and_survives_the_model_blob(name):
+    doc = json.loads((CONFIGS / f"{name}.json").read_text())
+    cfg = bb.load_config(str(CONFIGS / f"{name}.json"))
+    assert cfg == bb.config_from_json(doc, name) and cfg.name == name
+    assert bb.config_of(json.loads(json.dumps(bb.config_dict(cfg)))) == cfg
+    with pytest.raises(ValueError):
+        bb.load_config(name)               # a name is no file
+
+
+def test_published_widths_and_the_cut():
+    cfg = bb.load_config(str(CONFIGS / "mimo-v2.5-ep16-7l.json"))
+    assert (cfg.hidden, cfg.n_heads, cfg.kv_heads_full, cfg.kv_heads_window,
+            cfg.qk_dim, cfg.v_dim, cfg.rotary_dim, cfg.window) == (
+                4096, 64, 4, 8, 192, 128, 64, 128)
+    assert (cfg.n_experts, cfg.top_k, cfg.experts_held, cfg.expert_width,
+            cfg.dense_width, cfg.vocab) == (256, 8, 16, 2048, 16384, 19072)
+    assert cfg.layers == (("attn_full", "ffn_dense"),
+                          ("attn_full", "ffn_moe")) \
+        + (("attn_window", "ffn_moe"),) * 5
+    assert bb.n_params(cfg) == 3_429_955_392
+
+
+def test_sasrec_is_a_configuration_of_the_same_stack():
+    cfg = bb.sasrec_config(dim=16, n_heads=2, n_layers=2, seq_len=8,
+                           n_items=30)
+    p = bb.init_params(jax.random.PRNGKey(0), cfg)
+    assert set(p) == {"embed", "pos", "norm_f", "l0", "l1"}
+    assert p["embed"].shape == (31, 16) and "head" not in p
+    assert set(p["l0"]["ffn"]) == {"w1", "w2"}
+    assert bb.config_of(bb.config_dict(cfg)) == cfg
+
+
+# -- training at the toy preset -----------------------------------------------
+
+def test_seqrec_train_smoke_at_the_tiny_preset():
+    rng = np.random.default_rng(0)
+    n_users, n_items = 96, 40
+    us = np.repeat(np.arange(n_users), 9)
+    starts = rng.integers(0, n_items, n_users)
+    its = (np.repeat(starts, 9) + np.tile(np.arange(9), n_users)) % n_items
+    ts = np.tile(np.arange(9), n_users)
+    seqs, targets = build_sequences(us, its, ts, n_items=n_items, seq_len=8)
+    losses = []
+    m = seqrec_train(seqs, targets, n_items=n_items, seq_len=8,
+                     batch_size=48, epochs=10, lr=3e-3, seed=0,
+                     backbone=str(CONFIGS / "tiny-mimo.json"), losses=losses)
+    assert len(losses) == 20 and np.isfinite(losses).all()
+    assert np.mean(losses[-4:]) < 0.8 * np.mean(losses[:4])
+    assert m.config.vocab == n_items and m.item_emb.shape == (n_items, 64)
+    # the correction bias is held constant (a departure: its balancing
+    # update is no gradient step)
+    init = bb.init_params(jax.random.PRNGKey(0), m.config)
+    np.testing.assert_array_equal(m.params["l1"]["ffn"]["bias"],
+                                  init["l1"]["ffn"]["bias"])
+    assert np.abs(m.params["l1"]["ffn"]["router"]
+                  - np.asarray(init["l1"]["ffn"]["router"])).max() > 0
+
+
+# -- the serve path -----------------------------------------------------------
+
+@pytest.fixture
+def served(weights):
+    """A PredictionServer over a tiny-mimo instance, histories in the
+    MEM store, loaded through prepare_deploy -> warm_deploy."""
+    from drivers.http_closed import _store_instance
+    from drivers.seq_http_closed import write_histories
+    from predictionio_tpu.core import EngineParams, workflow
+    from predictionio_tpu.data.storage import StorageRegistry
+    from predictionio_tpu.ingest.bimap import BiMap
+    from predictionio_tpu.models import seqrec as sr
+    from predictionio_tpu.obs.metrics import MetricsRegistry
+    from predictionio_tpu.serving import PredictionServer, ServerConfig
+
+    pp, rp = weights
+    registry = StorageRegistry({
+        "PIO_STORAGE_SOURCES_MEM_TYPE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "MEM"})
+    lengths = np.asarray([3, 8, 9, 40, 48, 17, 1, 30])
+    items = seq_datagen.histories(lengths, CFG.vocab, 1.1, SEED)
+    write_histories(registry, lengths, items)
+    model = sr.SeqRecServingModel(
+        SeqRecModel(params=pp, n_items=CFG.vocab,
+                    backbone=bb.config_dict(CFG)),
+        BiMap({f"u{n}": n for n in range(len(lengths))}),
+        BiMap({f"i{n}": n for n in range(CFG.vocab)}))
+    params = EngineParams(
+        data_source_params=("", sr.DataSourceParams(app_name="benchapp")),
+        algorithm_params_list=(("seqrec", sr.SeqRecParams(
+            app_name="benchapp", event_names=("view",),
+            backbone=str(CONFIGS / "tiny-mimo.json"))),))
+    _store_instance(registry, params)
+    original = workflow.deserialize_models
+    workflow.deserialize_models = lambda *a, **k: [model]
+    try:
+        srv = PredictionServer(
+            ServerConfig(ip="127.0.0.1", port=0, batch_window_ms=5,
+                         batch_max=8),
+            registry=registry, engine=sr.engine(),
+            metrics=MetricsRegistry())
+    finally:
+        workflow.deserialize_models = original
+    srv.start()
+    ends = np.cumsum(lengths)
+    hists = [items[e - n:e] for e, n in zip(ends, lengths)]
+    try:
+        yield srv, hists, rp
+    finally:
+        srv.shutdown()
+
+
+def call(port, method, path, body=None):
+    import urllib.request
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}{path}", method=method,
+        data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req) as resp:
+        return resp.status, json.loads(resp.read().decode())
+
+
+def _hammer(port, users, each=4):
+    out = {}
+
+    def one(n):
+        for j in range(each):
+            u = (n + j) % users
+            body = {"user": f"u{u}", "num": 5}
+            if (n + j) % 3 == 0:
+                body["blackList"] = ["i1", "i2"]
+            status, reply = call(port, "POST", "/queries.json", body)
+            assert status == 200
+            out[(u, "blackList" in body)] = reply
+    threads = [threading.Thread(target=one, args=(n,)) for n in range(6)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    return out
+
+
+def _drained(srv, timeout=10.0):
+    deadline = time.perf_counter() + timeout
+    while time.perf_counter() < deadline:
+        if not srv._batcher._draining:
+            return
+        time.sleep(0.005)
+    raise AssertionError("the drainer did not retire")
+
+
+def test_queries_through_the_server_compile_nothing_and_tile_the_cycle(
+        served):
+    from predictionio_tpu.obs import get_registry
+    from predictionio_tpu.obs.jaxprobe import compile_watch
+    srv, hists, rp = served
+    before = {name: get_registry().histogram(name).labels().count
+              for name in ("pio_seq_call_tokens", "pio_seq_pad_share",
+                           "pio_seq_history_events",
+                           "pio_moe_expert_tokens_max_over_mean",
+                           "pio_moe_unrouted_share",
+                           "pio_moe_expert_pairs")}
+    with compile_watch() as w:
+        replies = _hammer(srv.port, len(hists))
+    _drained(srv)
+    assert w.count == 0
+    # what was served is the reference's top of the user's history
+    for (u, banned), reply in replies.items():
+        logits = ref.forward(DOC, rp, hists[u]).copy()
+        if banned:
+            logits[[1, 2]] = -np.inf
+        want = np.argsort(-logits, kind="stable")[:5]
+        got = [int(s["item"][1:]) for s in reply["itemScores"]]
+        assert got == want.tolist()
+        np.testing.assert_allclose([s["score"] for s in reply["itemScores"]],
+                                   logits[want], atol=1e-4)
+    # the stages tile the cycle, the sequence model's own among them
+    tot = {key[0]: (child.count, child.sum)
+           for key, child in srv._serve_obs.stage._items()}
+    cycles, cycle_s = tot["cycle"]
+    leaves = [n for n in trace.STAGES if n != "predict"]
+    assert abs(sum(tot[n][1] for n in leaves) - cycle_s) <= 0.02 * cycle_s
+    children = trace.SEQ_STAGES + ("lookup", "pack", "launch", "fetch",
+                                   "unpack")
+    assert abs(sum(tot[n][1] for n in children) - tot["predict"][1]) \
+        <= 0.02 * tot["predict"][1]
+    assert tot["host"][1] == pytest.approx(
+        cycle_s - tot["fetch"][1] - tot["seq_fetch"][1], rel=1e-9)
+    for name in trace.STAGES + ("cycle", "host"):
+        assert tot[name][0] == cycles >= 1, name
+    # every new counter observed
+    for name, n0 in before.items():
+        assert get_registry().histogram(name).labels().count > n0, name
+
+
+def test_a_user_without_history_gets_an_empty_reply(served):
+    srv, _, _ = served
+    status, reply = call(srv.port, "POST", "/queries.json",
+                         {"user": "nobody", "num": 5})
+    assert status == 200 and reply["itemScores"] == []
+
+
+@pytest.mark.parametrize("body,allowed", [
+    # a ban list wider than the plan's 64: the index-list entry point
+    ({"blackList": [f"i{n}" for n in range(70)]}, range(70, 97)),
+    # a whitelist: the dense-mask entry point
+    ({"whiteList": ["i3", "i5", "i8", "i13"]}, (3, 5, 8, 13)),
+])
+def test_filters_the_plan_does_not_take_are_served_the_generic_way(
+        served, body, allowed):
+    srv, hists, rp = served
+    status, reply = call(srv.port, "POST", "/queries.json",
+                         {"user": "u3", "num": 4, **body})
+    assert status == 200
+    logits = ref.forward(DOC, rp, hists[3])
+    allowed = np.asarray(allowed)
+    want = allowed[np.argsort(-logits[allowed], kind="stable")[:4]]
+    assert [int(s["item"][1:]) for s in reply["itemScores"]] \
+        == want.tolist()

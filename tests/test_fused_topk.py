@@ -429,7 +429,7 @@ class TestMergeCounter:
         bans = [[], [7, n // 5], [], list(range(0, 16)), [n - 1]]
         chain, fused = _both(BucketedTopK, factors, monkeypatch, k=k,
                              bucket=8, width=width)
-        blocks = fused_topk.gate_blocks(n, k)
+        blocks = fused_topk.gate_blocks(n, k, rank)
         assert blocks == 40         # twenty grid steps of two sub-blocks
         calls = [(vecs, bans), (vecs[:2], bans[:2]), (vecs[3:], bans[3:])]
         for qs, bl in calls:
@@ -459,7 +459,7 @@ class TestMergeCounter:
             local = [[g - lo for g in bl if lo <= g < hi] for bl in bans]
             want += _count_candidate_blocks(factors[lo:hi], vecs, local,
                                             k, _GATE_SUB)
-        blocks = 8 * fused_topk.gate_blocks(per, fused.k_shard)
+        blocks = 8 * fused_topk.gate_blocks(per, fused.k_shard, rank)
         before = _merge_observations()
         fused(vecs, bans)
         count, total = _merge_observations()

@@ -348,3 +348,65 @@ class TestMetaDAOs:
 
     def test_verify_all(self, registry):
         assert registry.verify_all_data_objects() is True
+
+
+# -- the MEM store's index by entity ------------------------------------------
+
+def _scan(events_dao, app_id, **kw):
+    """What `find` returned before the index: every event of the app,
+    filtered and sorted."""
+    from predictionio_tpu.data.storage.base import match_event
+    rev = kw.pop("reversed", False)
+    limit = kw.pop("limit", None)
+    found = [e for e in events_dao._table(app_id, None).values()
+             if match_event(e, entity_type=kw.get("entity_type"),
+                            entity_id=kw.get("entity_id"),
+                            event_names=kw.get("event_names"))]
+    found.sort(key=lambda e: (e.event_time_millis, e.event_id or ""),
+               reverse=rev)
+    return found[:limit] if limit else found
+
+
+@pytest.mark.parametrize("query", [
+    dict(entity_type="user", entity_id="u3"),
+    dict(entity_type="user", entity_id="u3", reversed=True, limit=4),
+    dict(entity_type="user", entity_id="u3", event_names=["buy"]),
+    dict(entity_type="user", entity_id="gone"),
+    dict(entity_type="item", entity_id="u3"),
+    dict(entity_type="user"),
+    dict(),
+])
+def test_mem_find_by_entity_reads_the_index_and_equals_the_scan(query):
+    import random
+    reg = StorageRegistry({
+        "PIO_STORAGE_SOURCES_MEM_TYPE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "MEM"})
+    dao = reg.get_events()
+    dao.init(1)
+    rng = random.Random(7)
+    ids = []
+    for n in range(300):
+        ids.append(dao.insert(ev(
+            event=rng.choice(["view", "buy"]), eid=f"u{rng.randrange(6)}",
+            etype=rng.choice(["user", "user", "item"]),
+            t=rng.randrange(50), target=("item", f"i{n}")), 1))
+    for event_id in rng.sample(ids, 120):          # deletes, some twice
+        assert dao.delete(event_id, 1)
+        assert not dao.delete(event_id, 1)
+    for n in range(40):                            # and inserts after them
+        dao.insert(ev(eid="u3", t=rng.randrange(50),
+                      target=("item", f"j{n}")), 1)
+    # a second DAO over the same client sees the same index
+    for d in (dao, reg.get_events()):
+        got = list(d.find(1, **query))
+        assert got == _scan(d, 1, **dict(query))
+    if query.get("entity_id") == "gone":
+        assert got == []
+    # the index holds exactly the table's events
+    by = dao.c.events_by_entity[(1, None)]
+    assert sum(len(v) for v in by.values()) == len(dao._table(1, None))
+    assert all(v for v in by.values())
+    dao.remove(1)
+    assert list(dao.find(1, entity_type="user", entity_id="u3")) == []

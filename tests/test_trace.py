@@ -444,7 +444,10 @@ class TestServerTraces:
 
 # -- batch cycles: one record a cycle, stages that tile it --------------------
 
-_LEAVES = tuple(n for n in trace.STAGES if n != "predict")
+# the stages an ALS cycle passes: a sequence model's own (history and
+# the stack's pack / launch / fetch) are tests/test_backbone.py's
+_STAGES = tuple(n for n in trace.STAGES if n not in trace.SEQ_STAGES)
+_LEAVES = tuple(n for n in _STAGES if n != "predict")
 _PREDICT_CHILDREN = ("lookup", "pack", "launch", "fetch", "unpack")
 
 
@@ -513,7 +516,7 @@ class TestBatchCycle:
             # every stage of every cycle, and nothing for the empty
             # windows in which the drainer retired
             batches = srv._serve_obs.batch_size.labels().count
-            for name in trace.STAGES + ("cycle", "host"):
+            for name in _STAGES + ("cycle", "host"):
                 assert tot[name][0] == batches == cycles, name
         finally:
             srv.shutdown()
@@ -526,7 +529,7 @@ class TestBatchCycle:
             _drained(srv)
             status, text = call(srv.port, "GET", "/metrics")
             assert status == 200
-            for name in trace.STAGES + ("cycle", "host"):
+            for name in _STAGES + ("cycle", "host"):
                 line = next(ln for ln in text.splitlines() if ln.startswith(
                     f'pio_serve_stage_seconds_count{{stage="{name}"}}'))
                 assert float(line.split()[-1]) >= 1, name
@@ -634,7 +637,7 @@ class TestBatchCycle:
             assert b["bucket"] >= b["rows"]
             assert b["dispatch"] == member["dispatch"] != ""
             spans = {s["name"]: s for s in b["spans"]}
-            assert set(spans) == set(trace.STAGES)
+            assert set(spans) == set(_STAGES)
             assert spans["window"]["start_ms"] == 0.0
             assert sum(spans[n]["dur_ms"] for n in _LEAVES) \
                 == pytest.approx(b["duration_ms"], abs=0.02)
@@ -693,7 +696,7 @@ class TestBatchCycle:
             n_take = sum(name == "pio:batch.take" for name, _, _ in evs)
             n_pred = len(predicts)
             assert n_take >= n_pred >= 1
-        assert seen == {"pio:batch." + n for n in trace.STAGES}
+        assert seen == {"pio:batch." + n for n in _STAGES}
 
     def test_stage_outside_a_cycle_observes_no_histogram(self, trained):
         m = MetricsRegistry()
