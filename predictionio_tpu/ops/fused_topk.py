@@ -6,13 +6,14 @@ a scatter stamps NEG_INF over the banned columns, and `lax.top_k` sorts
 every row. This module collapses the chain into ONE Pallas launch per
 batch bucket:
 
-  - the item catalog streams through VMEM in `PIO_FUSED_TILE_ITEMS`-row
-    tiles (grid over item tiles; the full score matrix never exists in
-    HBM), and a grid step walks its tile in gate sub-blocks of
-    `_SUB_ITEMS` rows;
+  - the item catalog streams through VMEM in tiles of
+    `PIO_FUSED_TILE_ITEMS` items (grid over item tiles; the full score
+    matrix never exists in HBM), read from HBM in the layout it lies in
+    (below), and a grid step walks its tile in gate sub-blocks of
+    `_SUB_ITEMS` items;
   - each sub-block's scores are computed on the MXU
     (`preferred_element_type=f32`, `Precision.HIGHEST` — identical math
-    to the XLA chain) and catalog-padding rows are masked to NEG_INF;
+    to the XLA chain) and catalog-padding items are masked to NEG_INF;
   - THE GATE: a running (score, id) scoreboard is carried in the output
     blocks, sorted best first, so its column k-1 is each row's k-th
     best score so far. A sub-block can change a row's top-k only if one
@@ -61,8 +62,32 @@ at the sentinels), the sub-block is a multiple of 128, so the
 scoreboard/sub-block concatenation is lane-aligned, and the ban block
 arrives twice: as [b, W] rows for the range test and as [W, b, 1] so
 each banned id is read as a [b, 1] column by a leading-dim index. The
-jitted wrapper pads and re-lays the inputs and slices `[bucket, k]`
-back out.
+jitted wrapper pads the query and ban blocks (a few KB) and slices
+`[bucket, k]` back out; it never moves the catalog.
+
+THE CATALOG'S ORIENTATION is read off its shape (`_items_on_lanes`), in
+one place, and no option states it. The TPU compiler keeps a
+`[n_items, rank]` float32 array whose rows are not whole 128-lane
+groups (rank 64, and every rank of the ALS templates) with the items on
+the lanes, `{0,1:T(8,128)}`, so that nothing is padded. Blocks of
+`(tile, rank)` rows ask for the other layout, and the compiler then
+transposes the whole catalog into rows padded to 128 lanes before every
+call: at 12,047,500 x 64 a 6.17 GB temporary written and read for a
+3.08 GB catalog, 14.4 ms of a 31.8 ms call, and the reason the
+24,095,000-row catalog did not compile on one chip (PERF.md section 6,
+PR 28). So for such a rank the kernel takes `(rank, tile)` blocks of
+`factors.T` — the same bytes under another shape, a bitcast in the
+compiled call — and each sub-block's product is a plain `[b, rank] x
+[rank, sub]`. Rows of whole lane groups (a sequence model's head:
+19,072 x 4,096) lie rows first, `{1,0:T(8,128)}`; there `(tile, rank)`
+blocks are the copy-free form and the transpose would be the copy
+(measured: 2.04 ms a call against 2.89). Either way the compiled call
+holds no operation that moves the catalog, which the plans publish as
+`pio_serve_plan_temp_bytes` and `tests/test_backbone_compile.py` holds
+for a described v5e. Everything after the product sees the same
+`[b, sub]` scores in both orientations. The last tile's out-of-bounds
+part (along the lanes or along the rows) holds whatever the DMA left
+there; the padding select covers it.
 
 `PIO_SERVE_FUSED` selects the kernel:
 
@@ -164,6 +189,14 @@ def _tile_items(n_rows: int, k: int, rank: int) -> tuple:
     return min(_round_up(tile, sub), _round_up(n_rows, sub)), sub
 
 
+def _items_on_lanes(rank: int) -> bool:
+    """Whether the catalog's copy-free blocks are `(rank, tile)` blocks
+    of its transpose (rows that are not whole 128-lane groups: the
+    compiler keeps the items on the lanes) or `(tile, rank)` blocks of
+    the array as it is. Module docstring, THE CATALOG'S ORIENTATION."""
+    return rank % _LANES != 0
+
+
 def _any(mask) -> jax.Array:
     """Scalar: whether any cell of a 2-D boolean block is set."""
     return jnp.max(jnp.where(mask, np.int32(1), np.int32(0))) > 0
@@ -221,7 +254,7 @@ def _merge(base, scores, banrow_ref, ban_ref, out_s_ref, out_i_ref, *,
 
 def _merge_body(n_valid, t, vecs_ref, fac_ref, banrow_ref, ban_ref,
                 out_s_ref, out_i_ref, cnt_ref, *, k: int, tile: int,
-                sub: int, n_banned: int) -> None:
+                sub: int, n_banned: int, lanes: bool) -> None:
     """One grid step: score this item tile sub-block by sub-block, and
     merge into the running scoreboard carried by the output blocks the
     sub-blocks that hold a score above some row's k-th best."""
@@ -238,13 +271,17 @@ def _merge_body(n_valid, t, vecs_ref, fac_ref, banrow_ref, ban_ref,
 
     def sub_block(s, carry):
         base = t * tile + s * sub
-        fac = fac_ref[pl.ds(pl.multiple_of(s * sub, sub), sub), :]
-        # [b, sub] scores — same contraction/precision as the chain
+        at = pl.ds(pl.multiple_of(s * sub, sub), sub)
+        # [b, sub] scores — same contraction/precision as the chain,
+        # over a [rank, sub] block (items on the lanes) or a [sub, rank]
+        # one (`_items_on_lanes`)
+        fac, over = ((fac_ref[:, at], 0) if lanes
+                     else (fac_ref[at, :], 1))
         scores = jax.lax.dot_general(
-            vecs, fac, (((1,), (1,)), ((), ())),
+            vecs, fac, (((1,), (over,)), ((), ())),
             precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
-        # rows past n_valid are catalog padding or the out-of-bounds
+        # items past n_valid are catalog padding or the out-of-bounds
         # part of the last tile (whatever the DMA left there): select,
         # never add
         scores = jnp.where(lane < n_valid - base, scores,
@@ -295,15 +332,18 @@ def _pallas_topk(n_rows: int, rank: int, *, k: int, bucket: int,
     shard_map."""
     interpret = interpreted()
     tile, sub = _tile_items(n_rows, k, rank)
+    lanes = _items_on_lanes(rank)
     nt = -(-n_rows // tile)
     rows = _round_up(bucket, _SUBLANES)
     board = _round_up(k, _LANES)
     smem = pl.BlockSpec(memory_space=None if interpret else pltpu.SMEM)
     specs = [pl.BlockSpec((rows, rank), lambda i: (0, 0)),
-             pl.BlockSpec((tile, rank), lambda i: (i, 0)),
+             (pl.BlockSpec((rank, tile), lambda i: (0, i)) if lanes
+              else pl.BlockSpec((tile, rank), lambda i: (i, 0))),
              pl.BlockSpec((rows, banned_width), lambda i: (0, 0)),
              pl.BlockSpec((banned_width, rows, 1), lambda i: (0, 0, 0))]
-    static = dict(k=k, tile=tile, sub=sub, n_banned=banned_width)
+    static = dict(k=k, tile=tile, sub=sub, n_banned=banned_width,
+                  lanes=lanes)
     if n_valid is None:
         kern = functools.partial(_kernel_dynamic, **static)
         specs = [smem] + specs
@@ -332,7 +372,10 @@ def _pallas_topk(n_rows: int, rank: int, *, k: int, bucket: int,
         # block goes in as [W, rows, 1] so the kernel reads one banned
         # id per row with a leading-dim index (no lane slicing)
         ban_rows = jnp.pad(banned, pad, constant_values=-1)
-        out_s, out_i, merged = call(*bound, jnp.pad(vecs, pad), factors,
+        # the transpose of a catalog that lies items-on-lanes is a
+        # bitcast: the compiled call moves nothing
+        out_s, out_i, merged = call(*bound, jnp.pad(vecs, pad),
+                                    factors.T if lanes else factors,
                                     ban_rows, ban_rows.T[..., None])
         return out_s[:bucket, :k], out_i[:bucket, :k], merged[0, 0]
 

@@ -130,6 +130,27 @@ def _observe_merge_share(merged, blocks: int) -> None:
         pass  # metrics must never fail a serve call
 
 
+def _publish_plan_temp_bytes(fused_exes) -> None:
+    """Gauge `pio_serve_plan_temp_bytes` from a warmed plan's fused
+    bucket executables: the largest one's temporaries by the compiler's
+    own count (`memory_analysis().temp_size_in_bytes`), which the
+    device's peak-bytes statistic does not see. A kernel handed its
+    catalog in another layout than it lies in shows here as a
+    catalog-sized temporary (ops/fused_topk.py). Metrics never fail a
+    deploy: a backend that gives no analysis leaves the gauge absent."""
+    try:
+        sizes = [exe.memory_analysis().temp_size_in_bytes
+                 for exe in fused_exes]
+        if sizes:
+            from predictionio_tpu.obs import get_registry
+            get_registry().gauge(
+                "pio_serve_plan_temp_bytes",
+                "Temporaries of one fused top-k call, by the "
+                "compiler's count (largest warmed bucket)").set(max(sizes))
+    except Exception:
+        pass
+
+
 class DispatchPolicy:
     """Amortized host/device dispatch from observed per-path latency.
 
@@ -650,6 +671,9 @@ class BucketedTopK:
                                k=self.k, has_bans=True).compile()
             self._exe[b] = exe
             compiled += 1
+        if compiled:
+            _publish_plan_temp_bytes(self._exe[b]
+                                     for b in self._fused_sizes)
         return compiled
 
     def bucket_kernels(self) -> dict:

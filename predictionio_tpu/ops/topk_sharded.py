@@ -65,7 +65,8 @@ from predictionio_tpu.obs import trace
 from predictionio_tpu.ops import topk
 from predictionio_tpu.ops.topk import (
     DEFAULT_SERVE_BUCKETS, NEG_INF, BucketedSimilar, BucketedTopK,
-    _next_pow2, _observe_merge_share, _record_dispatch,
+    _next_pow2, _observe_merge_share, _publish_plan_temp_bytes,
+    _record_dispatch,
 )
 from predictionio_tpu.parallel.mesh import (  # noqa: F401 — re-export
     parse_fleet_mesh, shard_put,
@@ -463,6 +464,8 @@ class ShardedBucketedTopK(_ShardedPlanBase):
             self._exe[b] = fn.lower(vec_spec, self.factors,
                                     ban_spec).compile()
             compiled += 1
+        if compiled and self.fused:
+            _publish_plan_temp_bytes(self._exe.values())
         return compiled
 
     def bucket_kernels(self) -> dict:

@@ -1,15 +1,20 @@
-"""The sequence backbone's kernels compiled for a TPU v5e at the
-published widths, with no chip: the chip's own compiler is installed
-here and compiles for a described device (nothing runs, so these say
-nothing of results or times). What interpret mode cannot show: a block
-Mosaic refuses, more fast memory than a kernel may use.
+"""The sequence backbone's kernels and the fused top-k kernel compiled
+for a TPU v5e at the widths the benchmark runs, with no chip: the
+chip's own compiler is installed here and compiles for a described
+device (nothing runs, so these say nothing of results or times). What
+interpret mode cannot show: a block Mosaic refuses, more fast memory
+than a kernel may use, a catalog the call copies before it reads it.
 
 All of them live in this one file, and the topology is described inside
 a fixture: only the worker that is handed this file loads the TPU's
 library."""
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -80,22 +85,101 @@ def test_grouped_matmul_compiles_at_width(one_chip, as_tpu, k_dim, n_dim):
     assert _compiled_kernels(compiled) == 1
 
 
-@pytest.mark.parametrize("bucket", [1, 64])
-def test_fused_topk_compiles_over_the_heads_rows(one_chip, monkeypatch,
-                                                 bucket):
-    """19,072 rows of 4,096: the tile is cut to fit the fast memory
-    (rank 64 keeps its 4,096-row tile)."""
+def _moves_of(compiled, cells: int) -> list:
+    """The compiled program's `copy` and `transpose` instructions whose
+    result holds at least `cells` elements: a catalog that the call
+    re-lays before the kernel reads it."""
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",")) >= cells:
+            found.append(line.strip()[:200])
+    return found
+
+
+def _assert_reads_the_catalog_where_it_lies(compiled, n_rows, rank):
+    assert _compiled_kernels(compiled) == 1
+    assert _moves_of(compiled, n_rows * rank) == []
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.05 * n_rows * rank * 4
+
+
+def _compiled_fused_bucket(one_chip, monkeypatch, n_items, rank, bucket):
+    """The single-device fused call over `[n_items, rank]`, compiled
+    for the described chip."""
     from predictionio_tpu.ops import fused_topk
     monkeypatch.setattr(fused_topk, "interpreted", lambda: False)
-    assert fused_topk._tile_items(19072, 10, 4096) == (256, 256)
-    assert fused_topk._tile_items(12_047_500, 10, 64) == (4096, 1024)
-    call = fused_topk._pallas_topk(19072, 4096, k=10, bucket=bucket,
-                                   banned_width=64, n_valid=19072)
+    call = fused_topk._pallas_topk(n_items, rank, k=10, bucket=bucket,
+                                   banned_width=64, n_valid=n_items)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = jax.jit(call).lower(
-        sds((bucket, 4096), jnp.float32), sds((19072, 4096), jnp.float32),
+    return jax.jit(call).lower(
+        sds((bucket, rank), jnp.float32), sds((n_items, rank), jnp.float32),
         sds((bucket, 64), jnp.int32)).compile()
-    assert _compiled_kernels(compiled) == 1
+
+
+@pytest.mark.parametrize("bucket", [1, 64])
+@pytest.mark.parametrize("n_items", [12_047_500, 24_095_000])
+def test_fused_topk_reads_the_rank64_catalog_where_it_lies(
+        one_chip, monkeypatch, n_items, bucket):
+    """The quarter and the half of the Amazon-23 catalog at rank 64:
+    the compiler keeps `f32[n, 64]` with the items on the lanes, the
+    kernel takes `(64, tile)` blocks of its transpose, and the call
+    holds no copy of the catalog (the half catalog with one ended in
+    RESOURCE_EXHAUSTED)."""
+    from predictionio_tpu.ops import fused_topk
+    assert fused_topk._items_on_lanes(64)
+    compiled = _compiled_fused_bucket(one_chip, monkeypatch, n_items, 64,
+                                      bucket)
+    _assert_reads_the_catalog_where_it_lies(compiled, n_items, 64)
+
+
+@pytest.mark.parametrize("bucket", [1, 64])
+def test_sharded_fused_topk_reads_each_shard_where_it_lies(
+        topo, monkeypatch, bucket):
+    """`ShardedBucketedTopK`'s program over the 2x2 host, 12,047,500
+    rows of 64 a chip: the per-shard kernel inside the shard_map reads
+    its `[per_shard, 64]` block with no copy of it."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from predictionio_tpu.ops import fused_topk
+    from predictionio_tpu.ops.topk_sharded import (
+        SHARD_AXIS, ShardedBucketedTopK)
+    monkeypatch.setattr(fused_topk, "interpreted", lambda: False)
+    monkeypatch.setenv("PIO_SERVE_FUSED", "on")
+    per, rank, n_shards = 12_047_500, 64, len(topo.devices)
+    mesh = Mesh(np.array(topo.devices), (SHARD_AXIS,))
+    # the plan's own program, without its constructor's device_put
+    # (a described device holds no array)
+    plan = ShardedBucketedTopK.__new__(ShardedBucketedTopK)
+    plan.mesh, plan.n_shards, plan.rank = mesh, n_shards, rank
+    plan.per_shard, plan.n_items = per, per * n_shards - 3
+    plan.k = plan.k_shard = 10
+    plan.banned_width = 64
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    compiled = plan._build(bucket=bucket).lower(
+        sds((bucket, rank), jnp.float32, P()),
+        sds((per * n_shards, rank), jnp.float32, P(SHARD_AXIS, None)),
+        sds((bucket, 64), jnp.int32, P())).compile()
+    assert plan.fused
+    _assert_reads_the_catalog_where_it_lies(compiled, per, rank)
+
+
+@pytest.mark.parametrize("bucket", [1, 64])
+def test_fused_topk_compiles_over_the_heads_rows(one_chip, monkeypatch,
+                                                 bucket):
+    """19,072 rows of 4,096: the tile is cut to fit the fast memory
+    (rank 64 keeps its 4,096-row tile), and rows of whole lane groups
+    keep their `(tile, rank)` blocks, which read them with no copy."""
+    from predictionio_tpu.ops import fused_topk
+    assert fused_topk._tile_items(19072, 10, 4096) == (256, 256)
+    assert fused_topk._tile_items(12_047_500, 10, 64) == (4096, 1024)
+    assert not fused_topk._items_on_lanes(4096)
+    compiled = _compiled_fused_bucket(one_chip, monkeypatch, 19072, 4096,
+                                      bucket)
+    _assert_reads_the_catalog_where_it_lies(compiled, 19072, 4096)

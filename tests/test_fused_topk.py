@@ -4,7 +4,13 @@ AND scores, ties included — to the XLA-chain oracles it replaces, on
 both the single-device `BucketedTopK` plan and the conftest-forced
 8-device CPU mesh's `ShardedBucketedTopK`, while preserving the
 swap_factors / zero-recompile / fallback contracts. Integer-valued
-factors make the matmuls exact so bitwise parity is well-defined."""
+factors make the matmuls exact so bitwise parity is well-defined.
+
+The kernel reads the catalog in one of two block orientations, chosen
+from the rank alone (`fused_topk._items_on_lanes`): items on the lanes
+in `(rank, tile)` blocks where a row is not whole 128-lane groups, rows
+first in `(tile, rank)` blocks where it is. The parity cases run at
+ranks of both kinds; every catalog here ends in a ragged last tile."""
 
 import numpy as np
 import pytest
@@ -15,6 +21,10 @@ from predictionio_tpu.ops.topk import BucketedTopK
 from predictionio_tpu.ops.topk_sharded import ShardedBucketedTopK
 
 pytestmark = pytest.mark.fused
+
+# items on the lanes with the rank's sublanes padded (8 whole, 20 not),
+# the benchmark's 64, and rows first (whole lane groups)
+_RANKS = (8, 20, 64, 128)
 
 
 def _mesh():
@@ -73,11 +83,45 @@ class TestGates:
             axis=topk_sharded.SHARD_AXIS) is None
 
 
+class TestOrientation:
+    def test_follows_the_lanes_a_row_fills(self):
+        assert all(fused_topk._items_on_lanes(r)
+                   for r in (3, 8, 20, 64, 100, 192))
+        assert not any(fused_topk._items_on_lanes(r)
+                       for r in (128, 256, 4096))
+
+    @pytest.mark.parametrize("rank", _RANKS)
+    def test_blocks_lie_as_the_orientation_says(self, rank, monkeypatch):
+        """The catalog operand reaches the kernel as `[rank, n]` with
+        `(rank, tile)` blocks, or as `[n, rank]` with `(tile, rank)`
+        ones."""
+        import jax
+        monkeypatch.setenv("PIO_SERVE_FUSED", "on")
+        n = 3000
+        fn = fused_topk._pallas_topk(n, rank, k=6, bucket=8,
+                                     banned_width=16, n_valid=n)
+        jaxpr = jax.make_jaxpr(fn)(
+            np.zeros((8, rank), np.float32),
+            np.zeros((n, rank), np.float32),
+            np.zeros((8, 16), np.int32))
+        call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        shape = call.invars[1].aval.shape
+        block = call.params["grid_mapping"].block_mappings[1].block_shape
+        tile, _ = fused_topk._tile_items(n, 6, rank)
+        if fused_topk._items_on_lanes(rank):
+            assert shape == (rank, n)
+            assert tuple(d.block_size for d in block) == (rank, tile)
+        else:
+            assert shape == (n, rank)
+            assert tuple(d.block_size for d in block) == (tile, rank)
+
+
 class TestBucketParity:
-    @pytest.fixture()
-    def plans(self, monkeypatch):
-        """The same 203x8 catalog warmed fused and unfused."""
-        factors = _int_factors(203, 8)
+    @pytest.fixture(params=_RANKS)
+    def plans(self, request, monkeypatch):
+        """The same 203-row catalog warmed fused and unfused, at a rank
+        of each orientation."""
+        factors = _int_factors(203, request.param)
         monkeypatch.setenv("PIO_SERVE_FUSED", "off")
         chain = BucketedTopK(factors, k=6, buckets=(1, 2, 4, 8),
                              banned_width=16)
@@ -92,7 +136,7 @@ class TestBucketParity:
     def test_bit_identical_across_buckets_and_bans(self, plans):
         chain, fused = plans
         for b in (1, 2, 3, 5, 8):
-            vecs = _queries(b, 8, seed=b)
+            vecs = _queries(b, fused.rank, seed=b)
             for case in _ban_cases(203, 16):
                 bans = [case if r % 2 == 0 else [] for r in range(b)]
                 cs, ci = chain(vecs, bans)
@@ -103,7 +147,7 @@ class TestBucketParity:
     def test_matches_host_stable_argsort_oracle(self, plans):
         _, fused = plans
         factors = fused._host_factors
-        vecs = _queries(4, 8, seed=99)
+        vecs = _queries(4, fused.rank, seed=99)
         bans = [[0, 7, 202], [], [5], list(range(0, 16))]
         fs, fi = fused(vecs, bans)
         for row in range(4):
@@ -133,15 +177,16 @@ class TestBucketParity:
         np.testing.assert_array_equal(cs, fs)
         assert len(set(fi[0].tolist())) == 4
 
-    def test_swap_factors_zero_recompile(self, monkeypatch):
+    @pytest.mark.parametrize("rank", (4,) + _RANKS)
+    def test_swap_factors_zero_recompile(self, rank, monkeypatch):
         monkeypatch.setenv("PIO_SERVE_FUSED", "on")
-        plan = BucketedTopK(_int_factors(64, 4), k=5, buckets=(1, 4),
+        plan = BucketedTopK(_int_factors(64, rank), k=5, buckets=(1, 4),
                             banned_width=8)
         plan.warm()
         assert plan.fused_buckets == 2
-        vecs = _queries(4, 4)
+        vecs = _queries(4, rank)
         before, _ = plan(vecs, [[], [], [], []])
-        new = _int_factors(64, 4, seed=123)
+        new = _int_factors(64, rank, seed=123)
         with compile_watch() as w:
             plan.swap_factors(new)
             after, ai = plan(vecs, [[], [], [], []])
@@ -164,11 +209,11 @@ class TestBucketParity:
 
 
 class TestShardedParity:
-    @pytest.fixture()
-    def plans(self, monkeypatch):
+    @pytest.fixture(params=_RANKS)
+    def plans(self, request, monkeypatch):
         """203 items over 8 shards (per-shard 26, padded tail), fused
-        vs unfused."""
-        factors = _int_factors(203, 8)
+        vs unfused, at a rank of each orientation."""
+        factors = _int_factors(203, request.param)
         monkeypatch.setenv("PIO_SERVE_FUSED", "off")
         chain = ShardedBucketedTopK(factors, k=6, buckets=(1, 2, 4, 8),
                                     banned_width=16, mesh=_mesh())
@@ -184,7 +229,7 @@ class TestShardedParity:
     def test_bit_identical_on_8_device_mesh(self, plans):
         chain, fused = plans
         for b in (1, 3, 8):
-            vecs = _queries(b, 8, seed=40 + b)
+            vecs = _queries(b, fused.rank, seed=40 + b)
             for case in _ban_cases(203, 16, seed=41):
                 bans = [case if r % 2 == 0 else case[:1]
                         for r in range(b)]
@@ -197,7 +242,7 @@ class TestShardedParity:
         """Global ids around every shard boundary (per_shard=26) — the
         local translation must drop out-of-shard ids, not wrap them."""
         chain, fused = plans
-        vecs = _queries(2, 8, seed=77)
+        vecs = _queries(2, fused.rank, seed=77)
         edges = [25, 26, 27, 51, 52, 53, 201, 202]
         cs, ci = chain(vecs, [edges, []])
         fs, fi = fused(vecs, [edges, []])
@@ -211,7 +256,7 @@ class TestShardedParity:
         single = BucketedTopK(fused._host_factors, k=6,
                               buckets=(1, 2, 4, 8), banned_width=16)
         single.warm()
-        vecs = _queries(5, 8, seed=3)
+        vecs = _queries(5, fused.rank, seed=3)
         bans = [[], [7], [0, 1, 2], [100, 200], [50]]
         ss, si = single(vecs, bans)
         hs, hi = fused(vecs, bans)
@@ -220,10 +265,10 @@ class TestShardedParity:
 
     def test_sharded_swap_factors_zero_recompile(self, plans):
         _, fused = plans
-        vecs = _queries(2, 8, seed=5)
+        vecs = _queries(2, fused.rank, seed=5)
         before, _ = fused(vecs, [[], []])
         with compile_watch() as w:
-            fused.swap_factors(_int_factors(203, 8, seed=321))
+            fused.swap_factors(_int_factors(203, fused.rank, seed=321))
             after, _ = fused(vecs, [[], []])
         assert w.count == 0
         assert not np.array_equal(before, after)
@@ -255,10 +300,10 @@ def _unit(rank, rows=1, scale=1.0):
     return vecs
 
 
-def _gate_case(name, n):
-    """(factors [n, 8], vecs, bans, k, banned_width) for one of the
+def _gate_case(name, n, rank=8):
+    """(factors [n, rank], vecs, bans, k, banned_width) for one of the
     gate's edge cases."""
-    rank, k, width = 8, 6, 16
+    k, width = 6, 16
     ids = np.arange(n)
     if name == "descending":        # only the first sub-blocks merge
         return (_axis_catalog(n, rank, n - ids), _unit(rank, 2),
@@ -315,10 +360,11 @@ def _both(cls, factors, monkeypatch, *, k, bucket, width, **kw):
 
 
 class TestGatedMerge:
+    @pytest.mark.parametrize("rank", _RANKS)
     @pytest.mark.parametrize("case", _GATE_CASES)
-    def test_single_device_bit_identical(self, case, monkeypatch):
+    def test_single_device_bit_identical(self, case, rank, monkeypatch):
         n = _GATE_N_SMALL if case.startswith("all_") else _GATE_N
-        factors, vecs, bans, k, width = _gate_case(case, n)
+        factors, vecs, bans, k, width = _gate_case(case, n, rank)
         chain, fused = _both(BucketedTopK, factors, monkeypatch, k=k,
                              bucket=8, width=width)
         assert fused.fused_buckets == 1
@@ -327,12 +373,13 @@ class TestGatedMerge:
         np.testing.assert_array_equal(ci, fi)
         np.testing.assert_array_equal(cs, fs)
 
+    @pytest.mark.parametrize("rank", _RANKS)
     @pytest.mark.parametrize("case", _GATE_CASES)
-    def test_sharded_bit_identical(self, case, monkeypatch):
+    def test_sharded_bit_identical(self, case, rank, monkeypatch):
         # 8 shards, the last one short by five rows
         n = 8 * (_GATE_N_SMALL if case.startswith("all_")
                  else _GATE_N) - 5
-        factors, vecs, bans, k, width = _gate_case(case, n)
+        factors, vecs, bans, k, width = _gate_case(case, n, rank)
         if case.startswith("all_"):
             # a ban list wider than the plan holds cannot be served:
             # ban a whole shard's rows and all but three of the next's
@@ -346,16 +393,18 @@ class TestGatedMerge:
         np.testing.assert_array_equal(ci, fi)
         np.testing.assert_array_equal(cs, fs)
 
+    @pytest.mark.parametrize("rank", _RANKS)
     @pytest.mark.parametrize("poison", [np.nan, np.inf])
-    def test_poisoned_tail_past_n_valid_is_never_read(self, poison,
+    def test_poisoned_tail_past_n_valid_is_never_read(self, poison, rank,
                                                       monkeypatch):
         """`n_items` is not a multiple of the tile and the operand's
-        rows past it hold NaN / +inf: they neither open the gate nor
-        reach the scoreboard."""
+        items past it hold NaN / +inf (along the lanes or along the
+        rows, as the rank lays the blocks): they neither open the gate
+        nor reach the scoreboard."""
         import jax
         monkeypatch.setenv("PIO_SERVE_FUSED", "on")
         monkeypatch.setenv("PIO_FUSED_TILE_ITEMS", str(_GATE_TILE))
-        n, pad_n, rank, k = _GATE_N, 2 * _GATE_TILE, 8, 6
+        n, pad_n, k = _GATE_N, 2 * _GATE_TILE, 6
         factors = _int_factors(n, rank)
         padded = np.full((pad_n, rank), poison, np.float32)
         padded[:n] = factors
@@ -376,9 +425,10 @@ class TestGatedMerge:
         # the sub-block that lies wholly past n_valid never merged
         assert 1 <= int(merged) <= 3
 
-    def test_worst_case_every_block_merges(self, monkeypatch):
+    @pytest.mark.parametrize("rank", _RANKS)
+    def test_worst_case_every_block_merges(self, rank, monkeypatch):
         factors, vecs, bans, k, width = _gate_case("ascending",
-                                                   2 * _GATE_TILE)
+                                                   2 * _GATE_TILE, rank)
         _, fused = _both(BucketedTopK, factors, monkeypatch, k=k,
                          bucket=8, width=width)
         before = _merge_observations()
@@ -465,3 +515,53 @@ class TestMergeCounter:
         count, total = _merge_observations()
         assert count == before[0] + 1
         assert total - before[1] == pytest.approx(want / blocks)
+
+
+class _FakeExe:
+    """A compiled bucket as far as the gauge reads it."""
+
+    def __init__(self, temp):
+        self._temp = temp
+
+    def memory_analysis(self):
+        if self._temp is None:
+            raise NotImplementedError("no analysis on this backend")
+        import types
+        return types.SimpleNamespace(temp_size_in_bytes=self._temp)
+
+
+def _temp_gauge():
+    from predictionio_tpu.obs import get_registry
+    return get_registry().value("pio_serve_plan_temp_bytes")
+
+
+class TestPlanTempGauge:
+    def test_reads_the_largest_bucket(self):
+        topk._publish_plan_temp_bytes(
+            _FakeExe(t) for t in (5, 6_170_564_608, 7))
+        assert _temp_gauge() == 6_170_564_608
+
+    def test_no_analysis_or_no_fused_bucket_leaves_it_alone(self):
+        topk._publish_plan_temp_bytes([_FakeExe(11)])
+        topk._publish_plan_temp_bytes([_FakeExe(3), _FakeExe(None)])
+        topk._publish_plan_temp_bytes([])
+        assert _temp_gauge() == 11
+
+    @pytest.mark.parametrize("cls", [BucketedTopK, ShardedBucketedTopK])
+    def test_warm_publishes_the_fused_buckets_analysis(self, cls,
+                                                       monkeypatch):
+        kw = {"mesh": _mesh()} if cls is ShardedBucketedTopK else {}
+        factors = _int_factors(300, 8)
+        topk._publish_plan_temp_bytes([_FakeExe(-1)])
+        monkeypatch.setenv("PIO_SERVE_FUSED", "off")
+        chain = cls(factors, k=5, buckets=(1, 8), banned_width=8, **kw)
+        chain.warm()
+        assert set(chain.bucket_kernels().values()) == {"xla"}
+        assert _temp_gauge() == -1      # the XLA chain publishes nothing
+        monkeypatch.setenv("PIO_SERVE_FUSED", "on")
+        fused = cls(factors, k=5, buckets=(1, 8), banned_width=8, **kw)
+        fused.warm()
+        assert set(fused.bucket_kernels().values()) == {"fused"}
+        want = max(exe.memory_analysis().temp_size_in_bytes
+                   for exe in fused._exe.values())
+        assert _temp_gauge() == want
