@@ -139,6 +139,14 @@ class Algorithm(_Component, Generic[PD, M, Q, P]):
         `CoreWorkflow.prepare_deploy` after models are loaded."""
         return 0
 
+    def serve_plans(self) -> tuple:
+        """The top-k plan(s) `warm_serving` built (ops/topk.py), for
+        what looks at a deployment from outside: `/status`, the plan
+        gauges, the pager, the streaming refresher. The default answers
+        from `_serve_plan`, where the templates keep theirs."""
+        plan = getattr(self, "_serve_plan", None)
+        return () if plan is None else (plan,)
+
 
 class Serving(_Component, Generic[Q, P]):
     """Query supplement + multi-algorithm result combination

@@ -11,33 +11,52 @@ from predictionio_tpu.ops.topk import build_mask
 
 
 def score_and_rank(vecs: np.ndarray, item_emb: np.ndarray,
-                   items: BiMap, live: Sequence[tuple]):
-    """The shared embedding-scoring tail of the neural recommenders
-    (two-tower, seqrec): per-query masks from white/black lists, one
-    masked top-k matmul over the catalog, ItemScore assembly. `live` is
+                   items: BiMap, live: Sequence[tuple], plan=None):
+    """The shared scoring tail of the catalog recommenders (ALS
+    recommendation, two-tower, seqrec): white/black lists to a filter,
+    one top-k over the catalog, ItemScore assembly. `live` is
     [(original_index, query, ...)] — only index and query are read.
+    With no whitelist in the batch the filters go as ban-index lists —
+    the filter is then built ON DEVICE, so big catalogs do not
+    re-upload a dense mask per batch — through `plan` (the algorithm's
+    warmed serve plan, if it has one) for the rows that fit it
+    (`ops/topk.score_banned`); a whitelist needs the dense mask.
+    Stages of the batch cycle (obs/trace.stage): `lookup` (ids to
+    indexes), the top-k call's own pack, launch and fetch, `unpack`.
     Returns [(original_index, PredictedResult)]."""
     from predictionio_tpu.models.recommendation import (
         ItemScore, PredictedResult,
     )
-    from predictionio_tpu.ops.topk import NEG_INF, topk_scores
+    from predictionio_tpu.obs import trace
+    from predictionio_tpu.ops.topk import NEG_INF, score_banned, topk_scores
 
-    n_items = item_emb.shape[0]
-    k = max(min(entry[1].num, n_items) for entry in live)
-    mask = np.concatenate(
-        [resolve_item_mask(items, white_list=entry[1].whiteList,
-                           black_list=entry[1].blackList or ())
-         for entry in live], axis=0)
-    scores, ixs = topk_scores(vecs.astype(np.float32), item_emb, mask,
-                              k=k)
-    scores, ixs = np.asarray(scores), np.asarray(ixs)
+    queries = [entry[1] for entry in live]
+    vecs = np.asarray(vecs, np.float32)
+    with trace.stage("lookup"):
+        n_items = item_emb.shape[0]
+        ks = [min(q.num, n_items) for q in queries]
+        banned = mask = None
+        if all(q.whiteList is None for q in queries):
+            banned = [[ix for b in (q.blackList or ())
+                       if (ix := items.get(b)) is not None]
+                      for q in queries]
+        else:
+            mask = np.concatenate(
+                [resolve_item_mask(items, white_list=q.whiteList,
+                                   black_list=q.blackList or ())
+                 for q in queries], axis=0)
+    if mask is None:
+        scores, ixs = score_banned(plan, vecs, item_emb, banned, ks)
+    else:
+        scores, ixs = topk_scores(vecs, item_emb, mask, k=max(ks))
     out = []
-    for row, entry in enumerate(live):
-        i, q = entry[0], entry[1]
-        found = [ItemScore(items.inverse(int(ix)), float(s))
-                 for s, ix in zip(scores[row], ixs[row])
-                 if s > NEG_INF / 2][:q.num]
-        out.append((i, PredictedResult(tuple(found))))
+    with trace.stage("unpack"):
+        scores, ixs = np.asarray(scores), np.asarray(ixs)
+        for row, (entry, q) in enumerate(zip(live, queries)):
+            found = [ItemScore(items.inverse(int(ix)), float(s))
+                     for s, ix in zip(scores[row], ixs[row])
+                     if s > NEG_INF / 2][:q.num]
+            out.append((entry[0], PredictedResult(tuple(found))))
     return out
 
 

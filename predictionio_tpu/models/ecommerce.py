@@ -32,7 +32,7 @@ from predictionio_tpu.data import store
 from predictionio_tpu.ingest import BiMap, RatingColumns
 from predictionio_tpu.ops import als
 from predictionio_tpu.ops.topk import (
-    NEG_INF, _next_pow2, topk_scores, topk_scores_filtered, topk_similar,
+    NEG_INF, score_banned, topk_scores, topk_similar,
 )
 
 
@@ -257,10 +257,9 @@ class ECommAlgorithm(Algorithm):
         from predictionio_tpu.ops.topk_sharded import serve_plan
         ctx = getattr(self, "_serving_ctx", None)
         n_unavail = len(self._unavailable_items(ctx)) if ctx else 0
-        width = _next_pow2(max(256, n_unavail + 128))
         self._serve_plan = serve_plan(
             model.item_factors, k=Query().num, buckets=buckets,
-            banned_width=width, mesh=mesh)
+            banned_width=max(256, n_unavail + 128), mesh=mesh)
         return self._serve_plan.warm()
 
     def fold_in(self, model: ECommModel, delta, fctx) -> ECommModel:
@@ -314,11 +313,10 @@ class ECommAlgorithm(Algorithm):
     def batch_predict(self, model, queries):
         """Batched serve path. Known-user queries without dense-mask
         needs (no categories/whiteList) coalesce into ONE banned-index
-        top-k dispatch — through the deploy-warmed `BucketedTopK` plan
-        (device-resident factors, bucket-padded static shape, zero
-        recompiles) when the batch fits it, else the generic
-        `topk_scores_filtered`. Everything else (unknown users, dense
-        filters) falls back to the per-query three-way predict."""
+        top-k dispatch (`score_banned`: the deploy-warmed plan for the
+        rows that fit it, the generic path for the rest). Everything
+        else (unknown users, dense filters) falls back to the per-query
+        three-way predict."""
         # the unavailableItems constraint read is shared across the batch
         ctx = self._ctx()
         unavailable = self._unavailable_items(ctx)
@@ -343,41 +341,20 @@ class ECommAlgorithm(Algorithm):
                                                  unavailable)))
         if not batched:
             return out
-        plan = getattr(self, "_serve_plan", None)
-
-        def _fits_plan(q, banned) -> bool:
-            return plan is not None and plan.fits(
-                max_banned=len(banned), k=min(q.num, n_items))
-
-        # PER-QUERY plan gating: one heavy user whose seen-history ban
-        # list overflows the plan's banned_width must not demote the
-        # whole coalesced batch to the generic (host-leaning) path —
-        # that all-or-nothing gate is how the r05 scale runs served
-        # hundreds of host calls and zero device batches. Only the
-        # outlier queries go generic; the rest keep the warmed plan.
-        fit = [r for r in batched if _fits_plan(r[1], r[3])]
-        rest = [r for r in batched if not _fits_plan(r[1], r[3])]
-        for rows, use_plan in ((fit, True), (rest, False)):
-            if not rows:
-                continue
-            vecs = model.user_factors[
-                np.array([u for _, _, u, _ in rows])].astype(np.float32)
-            banned_lists = [b for _, _, _, b in rows]
-            k = max(min(q.num, n_items) for _, q, _, _ in rows)
-            if use_plan:
-                scores, ixs = plan(vecs, banned_lists)
-            else:
-                scores, ixs = topk_scores_filtered(
-                    vecs, model.item_factors, banned_lists, k=k)
-            scores, ixs = np.asarray(scores), np.asarray(ixs)
-            for row, (i, q, _, _) in enumerate(rows):
-                items = []
-                for s, ix in zip(scores[row], ixs[row]):
-                    if s <= NEG_INF / 2 or len(items) >= q.num:
-                        continue
-                    items.append(ItemScore(model.items.inverse(int(ix)),
-                                           float(s)))
-                out.append((i, PredictedResult(tuple(items))))
+        vecs = model.user_factors[
+            np.array([u for _, _, u, _ in batched])].astype(np.float32)
+        scores, ixs = score_banned(
+            getattr(self, "_serve_plan", None), vecs, model.item_factors,
+            [b for _, _, _, b in batched],
+            [min(q.num, n_items) for _, q, _, _ in batched])
+        for row, (i, q, _, _) in enumerate(batched):
+            items = []
+            for s, ix in zip(scores[row], ixs[row]):
+                if s <= NEG_INF / 2 or len(items) >= q.num:
+                    continue
+                items.append(ItemScore(model.items.inverse(int(ix)),
+                                       float(s)))
+            out.append((i, PredictedResult(tuple(items))))
         return out
 
     def with_serving_context(self, ctx: RuntimeContext) -> "ECommAlgorithm":

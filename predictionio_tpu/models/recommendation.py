@@ -29,10 +29,9 @@ from predictionio_tpu.core import (
 )
 from predictionio_tpu.data import store
 from predictionio_tpu.ingest import RatingColumns
+from predictionio_tpu.models.common import score_and_rank
 from predictionio_tpu.obs import trace
 from predictionio_tpu.ops import als
-from predictionio_tpu.ops.topk import (NEG_INF, topk_scores,
-                                       topk_scores_filtered)
 
 
 # -- queries and results (wire-format parity) -------------------------------
@@ -212,12 +211,9 @@ class ALSAlgorithm(Algorithm):
                       queries: Sequence[Tuple[int, Query]]
                       ) -> List[Tuple[int, PredictedResult]]:
         """One jit'd matmul+top_k over the whole batch; unknown users get
-        empty results (ALSAlgorithm.scala:96-112 semantics).
-
-        Three stages of the batch cycle (obs/trace.stage): `lookup` up
-        to the plan call (id maps, the user-factor gather, ban lists
-        resolved to indexes), the plan's own pack, launch and fetch,
-        and `unpack` (ItemScores through the inverse id map)."""
+        empty results (ALSAlgorithm.scala:96-112 semantics). `lookup`
+        (obs/trace.stage) here is the user id map and the user-factor
+        gather; the rest of the cycle's stages are `score_and_rank`'s."""
         with trace.stage("lookup"):
             known = [(i, q, model.users.get(q.user)) for i, q in queries]
             out: List[Tuple[int, PredictedResult]] = [
@@ -225,48 +221,10 @@ class ALSAlgorithm(Algorithm):
             live = [(i, q, u) for i, q, u in known if u is not None]
             if not live:
                 return out
-            n_items = model.item_factors.shape[0]
-            k = max(min(q.num, n_items) for _, q, _ in live)
             vecs = model.user_factors[np.array([u for _, _, u in live])]
-            banned = mask = None
-            if all(q.whiteList is None for _, q, _ in live):
-                # no whitelists: blacklist filtering via the
-                # banned-index device path — the filter is built ON
-                # DEVICE from index lists, so big catalogs do not
-                # re-upload a dense mask per batch (ops/topk.py
-                # topk_scores_filtered)
-                banned = [
-                    [ix for ix in (model.items.get(b)
-                                   for b in (q.blackList or ()))
-                     if ix is not None]
-                    for _, q, _ in live]
-            else:
-                from predictionio_tpu.models.common import resolve_item_mask
-                mask = np.concatenate(
-                    [resolve_item_mask(model.items, white_list=q.whiteList,
-                                       black_list=q.blackList or ())
-                     for _, q, _ in live], axis=0)
-        if banned is not None:
-            plan = getattr(self, "_serve_plan", None)
-            if plan is not None and plan.fits(
-                    max_banned=max(map(len, banned), default=0), k=k):
-                scores, ixs = plan(vecs, banned)
-            else:
-                scores, ixs = topk_scores_filtered(
-                    vecs, model.item_factors, banned, k=k)
-        else:
-            scores, ixs = topk_scores(vecs, model.item_factors, mask, k=k)
-        with trace.stage("unpack"):
-            scores, ixs = np.asarray(scores), np.asarray(ixs)
-            for row, (i, q, _) in enumerate(live):
-                items = []
-                for s, ix in zip(scores[row], ixs[row]):
-                    if s <= NEG_INF / 2 or len(items) >= q.num:
-                        continue
-                    items.append(ItemScore(model.items.inverse(int(ix)),
-                                           float(s)))
-                out.append((i, PredictedResult(tuple(items))))
-        return out
+        return out + score_and_rank(
+            vecs, model.item_factors, model.items, live,
+            plan=getattr(self, "_serve_plan", None))
 
 
 # -- evaluation metrics (Evaluation.scala of the template) ------------------

@@ -37,15 +37,11 @@ from predictionio_tpu.core import (
 )
 from predictionio_tpu.data import store
 from predictionio_tpu.ingest import BiMap, RatingColumns
-from predictionio_tpu.models.recommendation import (
-    ItemScore, PredictedResult, Query,
-)
+from predictionio_tpu.models.common import score_and_rank
+from predictionio_tpu.models.recommendation import PredictedResult, Query
 from predictionio_tpu.obs import trace
 from predictionio_tpu.ops.seqrec import (
     PackedEncoder, SeqRecModel, build_sequences, seqrec_train,
-)
-from predictionio_tpu.ops.topk import (
-    NEG_INF, topk_scores, topk_scores_filtered,
 )
 
 
@@ -220,6 +216,10 @@ class SeqRecAlgorithm(Algorithm):
             self._serve_plans = plans
         return plans[1], plans[2]
 
+    def serve_plans(self) -> tuple:
+        plans = getattr(self, "_serve_plans", None)
+        return () if plans is None else (plans[2],)
+
     def warm_serving(self, model: SeqRecServingModel, buckets,
                      mesh=None) -> int:
         """Deploy warm-up: pin the stack's weights and the head's rows
@@ -237,8 +237,7 @@ class SeqRecAlgorithm(Algorithm):
         positions scored by the catalog top-k plan. Stages of the batch
         cycle (obs/trace.stage): `history` (store read to item
         indexes), the encoder's `seq_pack` / `seq_launch` /
-        `seq_fetch`, `lookup` (ban lists to indexes), the plan's own
-        pack, launch and fetch, and `unpack`."""
+        `seq_fetch`, then `score_and_rank`'s."""
         out: List[Tuple[int, PredictedResult]] = []
         live = []
         with trace.stage("history"):
@@ -253,37 +252,8 @@ class SeqRecAlgorithm(Algorithm):
             return out
         encoder, plan = self._plans(model)
         vecs = encoder([h for _, _, h in live])
-        n_items = model.net.n_items
-        with trace.stage("lookup"):
-            k = max(min(q.num, n_items) for _, q, _ in live)
-            banned = mask = None
-            if all(q.whiteList is None for _, q, _ in live):
-                banned = [
-                    [ix for ix in (model.items.get(b)
-                                   for b in (q.blackList or ()))
-                     if ix is not None]
-                    for _, q, _ in live]
-            else:
-                from predictionio_tpu.models.common import resolve_item_mask
-                mask = np.concatenate(
-                    [resolve_item_mask(model.items, white_list=q.whiteList,
-                                       black_list=q.blackList or ())
-                     for _, q, _ in live], axis=0)
-        if banned is None:
-            scores, ixs = topk_scores(vecs, model.net.item_emb, mask, k=k)
-        elif plan.fits(max_banned=max(map(len, banned), default=0), k=k):
-            scores, ixs = plan(vecs, banned)
-        else:   # more bans or a larger k than the plan was built for
-            scores, ixs = topk_scores_filtered(vecs, model.net.item_emb,
-                                               banned, k=k)
-        with trace.stage("unpack"):
-            scores, ixs = np.asarray(scores), np.asarray(ixs)
-            for row, (i, q, _) in enumerate(live):
-                found = [ItemScore(model.items.inverse(int(ix)), float(s))
-                         for s, ix in zip(scores[row], ixs[row])
-                         if s > NEG_INF / 2][:q.num]
-                out.append((i, PredictedResult(tuple(found))))
-        return out
+        return out + score_and_rank(vecs, model.net.item_emb, model.items,
+                                    live, plan=plan)
 
 
 class SeqRecEngine(EngineFactory):

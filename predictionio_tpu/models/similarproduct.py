@@ -34,7 +34,7 @@ from predictionio_tpu.ops import als
 from predictionio_tpu.ops.cooccur import (
     CooccurrenceModel, top_cooccurrences_from_pairs,
 )
-from predictionio_tpu.ops.topk import NEG_INF, topk_similar
+from predictionio_tpu.ops.topk import NEG_INF, score_similar
 
 
 @dataclass(frozen=True)
@@ -161,18 +161,15 @@ class _FactorSimilarityAlgorithm(Algorithm):
         if not live:
             return out
         n_items = model.item_factors.shape[0]
-        k = max(min(q.num, n_items) for _, q, _ in live)
+        ks = [min(q.num, n_items) for _, q, _ in live]
         vecs = np.stack([model.item_factors[ixs].mean(axis=0)
                          for _, _, ixs in live])
         mask = np.concatenate(
             [_resolve_filters(model.items, model.item_categories, q)
              for _, q, _ in live], axis=0)
-        plan = getattr(self, "_serve_plan", None)
-        if plan is not None and plan.fits(k=k):
-            scores, ixs = plan(vecs.astype(np.float32), mask)
-        else:
-            scores, ixs = topk_similar(vecs.astype(np.float32),
-                                       model.item_factors, mask, k=k)
+        scores, ixs = score_similar(
+            getattr(self, "_serve_plan", None), vecs.astype(np.float32),
+            model.item_factors, mask, ks)
         scores, ixs = np.asarray(scores), np.asarray(ixs)
         for row, (i, q, _) in enumerate(live):
             items = [ItemScore(model.items.inverse(int(ix)), float(s))

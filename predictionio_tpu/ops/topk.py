@@ -4,40 +4,53 @@ The reference serves queries one at a time and even notes "TODO:
 Parallelize" (`core/.../workflow/CreateServer.scala:494`); its per-query
 work is a driver-side loop over `recommendProducts`
 (`examples/.../ALSAlgorithm.scala:96-112`). Here scoring is one
-program: a query batch of user vectors against the full item factor matrix
-(a matmul), additive masks for blacklist/seen/whitelist filters, then
-top-k — so batching queries is free.
+program: a query batch of user vectors against the full item factor
+matrix (a matmul), banned-index or dense-mask filters, then top-k — so
+batching queries is free.
 
-Host/device dispatch: `topk_scores`/`topk_similar` route by score-matrix
-size. Small problems (a handful of live queries against a catalog of
-thousands) run as host BLAS in microseconds — pushing them through the
-accelerator costs a dispatch + a device->host readback round trip that
-dwarfs the compute. Large batches (offline batchpredict, eval sweeps,
-big catalogs) go to the jit'd device kernel where the MXU matmul wins and
-the transfer amortizes. Inside a jit trace the device path is always used
-(host numpy cannot trace).
+Who owns what:
 
-Two dispatch refinements on top of the static size rule:
-
-  - `DispatchPolicy` — an amortized policy that keeps latency EWMAs per
-    path and can PROMOTE sub-crossover problems to the device once the
-    observed device round trip beats the predicted (GIL-contended) host
-    time. The static `HOST_CROSSOVER_CELLS` stays the upper bound: at or
-    above it the device always wins, exactly as before.
-  - `BucketedTopK` — the serving plan: per-bucket AOT-compiled
-    executables over a device-resident factor matrix, built at deploy
-    warmup. Calls go straight to the compiled executable (never the jit
-    tracing cache), so steady-state serving is zero-recompile by
-    construction.
-
-A third path lives in `ops/topk_sharded.py`: `ShardedBucketedTopK` /
-`ShardedBucketedSimilar` partition the catalog row-wise across a device
-mesh (per-shard partial top-k + allgather merge) when a mesh is
-configured or the catalog exceeds one device's capacity.
+  - **The serving plan, `BucketedPlan`**: the k clamp and the pow2
+    bucket grid, chunking past the largest bucket, the idempotent
+    `warm` loop, the call cycle (`pack` -> `launch` -> `fetch` stages,
+    the merge-share observation, `_record_dispatch`, the slice back to
+    the batch), `swap_factors`, `fits`. Written once, here. Calls go
+    straight to AOT-compiled executables over a device-resident factor
+    matrix (never the jit tracing cache), so steady-state serving is
+    zero-recompile by construction.
+  - **Its four leaves** supply how the factors are placed, one bucket's
+    executable, the filter block a call carries and the dispatch label,
+    and nothing else: `BucketedTopK` (ban lists; fused kernel or XLA
+    chain) and `BucketedSimilar` (dense mask, cosine) here, on one
+    device; `ShardedBucketedTopK` / `ShardedBucketedSimilar` in
+    `ops/topk_sharded.py`, the same two with the catalog row-sharded
+    over a mesh (per-shard partial top-k + allgather merge). That
+    module also chooses among them (`serve_plan` / `similar_plan`).
+    `TieredTopK` and `ShardSliceTopK` wrap a plan and translate ids
+    around it; they hold no copy of the cycle.
+  - **`score_banned` / `score_similar`**: which rows of a template's
+    batch go through its plan, and which through the generic entry
+    points. The templates call these and decide nothing.
+  - **The generic entry points** `topk_scores` / `topk_similar` /
+    `topk_scores_filtered` route by score-matrix size. Small problems
+    (a handful of live queries against a catalog of thousands) run as
+    host BLAS in microseconds — pushing them through the accelerator
+    costs a dispatch + a device->host readback round trip that dwarfs
+    the compute. Large ones (offline batchpredict, eval sweeps, big
+    catalogs) go to the jit'd chain where the MXU matmul wins and the
+    transfer amortizes. Inside a jit trace the device path is always
+    used (host numpy cannot trace). `DispatchPolicy` refines the static
+    size rule: it keeps a latency EWMA per path and can PROMOTE
+    sub-crossover problems to the device once the observed device round
+    trip beats the predicted (GIL-contended) host time; the static
+    `HOST_CROSSOVER_CELLS` stays the upper bound.
+  - **The XLA scoring chain** (`exact_scores`, `masked_topk`,
+    `drop_banned`, `unit_rows`): shared by the jitted generic paths,
+    the plans' chain buckets and the shard-local bodies.
 
 Every dispatch lands in `pio_topk_dispatch_total{path=host|device|
-sharded}` (the process-default metrics registry) and in
-`DISPATCH_COUNTS`; the `DispatchPolicy` keeps a latency EWMA per path.
+fused|sharded}` (the process-default metrics registry) and in
+`DISPATCH_COUNTS`.
 """
 
 from __future__ import annotations
@@ -276,35 +289,66 @@ def _record_dispatch(path: str, cells: int,
     DISPATCH_POLICY.observe(path, cells, seconds)
 
 
-@partial(jax.jit, static_argnames=("k",))
-def _topk_scores_device(user_vecs, item_factors, mask, *, k: int):
+def exact_scores(vecs, item_factors):
+    """[b, rank] x [n, rank] -> [b, n] scores, the product every XLA
+    scoring chain starts with (the shard-local bodies of
+    ops/topk_sharded.py too)."""
     # HIGHEST precision: the host path computes exact f32, and the two
     # paths must rank near-tied scores identically (default TPU matmul
     # precision is bf16-pass and would reorder them)
-    scores = jnp.matmul(user_vecs, item_factors.T,
-                        precision=jax.lax.Precision.HIGHEST)
-    scores = jnp.where(mask, scores, NEG_INF)
-    return jax.lax.top_k(scores, k)
+    return jnp.matmul(vecs, item_factors.T,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def unit_rows(x, xp=jnp):
+    """Rows scaled to unit length, the cosine scorer's half of the
+    chain (`xp=np` on the host path)."""
+    return x / (xp.linalg.norm(x, axis=-1, keepdims=True) + 1e-9)
+
+
+def masked_topk(vecs, item_factors, mask, k: int):
+    """The dense-mask chain: scores, disallowed cells to NEG_INF,
+    top-k."""
+    return jax.lax.top_k(
+        jnp.where(mask, exact_scores(vecs, item_factors), NEG_INF), k)
+
+
+def drop_banned(scores, banned):
+    """Scores with each row's banned columns at NEG_INF; out-of-range
+    fill indices (== the column count) are dropped."""
+    rows = jnp.arange(scores.shape[0])[:, None]
+    return scores.at[rows, banned].set(NEG_INF, mode="drop")
+
+
+def _jit_pair(raw, static: tuple):
+    """`raw` jitted twice: plain, and donating the per-call uploads
+    (the padded query block, arg 0, and its filter block, arg 2) so XLA
+    reuses their buffers instead of allocating fresh ones every drain.
+    The factor matrix (arg 1) is the resident model state and is never
+    donated. Both keep `raw`'s name, which is the compiled module's
+    name in a profile."""
+    jit = partial(jax.jit, static_argnames=static)
+    return jit(raw), jit(raw, donate_argnums=(0, 2))
+
+
+def _plan_jit(plain, donated):
+    """Which of a pair a serving plan compiles: CPU backends cannot
+    donate and would warn per compile."""
+    return plain if jax.default_backend() == "cpu" else donated
+
+
+@partial(jax.jit, static_argnames=("k",))
+def _topk_scores_device(user_vecs, item_factors, mask, *, k: int):
+    return masked_topk(user_vecs, item_factors, mask, k)
 
 
 def _topk_similar_raw(query_vecs, item_factors, mask, *, k: int):
-    qn = query_vecs / (jnp.linalg.norm(query_vecs, axis=-1, keepdims=True)
-                       + 1e-9)
-    fn = item_factors / (jnp.linalg.norm(item_factors, axis=-1, keepdims=True)
-                         + 1e-9)
-    scores = jnp.matmul(qn, fn.T, precision=jax.lax.Precision.HIGHEST)
-    scores = jnp.where(mask, scores, NEG_INF)
-    return jax.lax.top_k(scores, k)
+    return masked_topk(unit_rows(query_vecs), unit_rows(item_factors),
+                       mask, k)
 
 
-_topk_similar_device = partial(
-    jax.jit, static_argnames=("k",))(_topk_similar_raw)
-
-# AOT serving-plan variant (BucketedSimilar): donates the per-call query
-# block and dense mask off-CPU, mirroring _topk_scores_banned_donated
-_topk_similar_donated = partial(
-    jax.jit, static_argnames=("k",),
-    donate_argnums=(0, 2))(_topk_similar_raw)
+_topk_similar_device, _topk_similar_donated = _jit_pair(
+    _topk_similar_raw, ("k",))
 
 
 def _is_traced(*arrays) -> bool:
@@ -396,31 +440,29 @@ def plan_resident_bytes() -> float:
 
 def _topk_scores_banned(user_vecs, item_factors, banned, *,
                         k: int, has_bans: bool):
-    scores = jnp.matmul(user_vecs, item_factors.T,
-                        precision=jax.lax.Precision.HIGHEST)
+    scores = exact_scores(user_vecs, item_factors)
     if has_bans:
-        rows = jnp.arange(scores.shape[0])[:, None]
-        # out-of-range fill indices (== n_items) are dropped
-        scores = scores.at[rows, banned].set(NEG_INF, mode="drop")
+        scores = drop_banned(scores, banned)
     return jax.lax.top_k(scores, k)
 
 
-_topk_scores_banned_device = partial(
-    jax.jit, static_argnames=("k", "has_bans"))(_topk_scores_banned)
-
-# The AOT serving-plan variant donates the per-call uploads (the padded
-# query block and its banned-index block) so XLA reuses their buffers
-# instead of allocating fresh ones every drain. The factor matrix (arg 1)
-# is the device-resident model state and is NOT donated. CPU backends
-# can't donate and would warn per compile, so the plan only picks this
-# variant off-CPU.
-_topk_scores_banned_donated = partial(
-    jax.jit, static_argnames=("k", "has_bans"),
-    donate_argnums=(0, 2))(_topk_scores_banned)
+_topk_scores_banned_device, _topk_scores_banned_donated = _jit_pair(
+    _topk_scores_banned, ("k", "has_bans"))
 
 
 def _next_pow2(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length() if n > 1 else 1
+
+
+def _ban_block(rows: int, width: int, n_items: int,
+               banned_lists) -> np.ndarray:
+    """[rows, width] int32 ban indexes, one list a row from the top,
+    the rest `n_items`: the fill the scatter drops."""
+    block = np.full((rows, width), n_items, np.int32)
+    for row, bl in enumerate(banned_lists):
+        if len(bl):
+            block[row, :len(bl)] = np.asarray(bl, np.int32)  # lint: ok
+    return block
 
 
 def topk_scores_filtered(user_vecs, item_factors, banned_lists, *, k: int):
@@ -454,16 +496,13 @@ def topk_scores_filtered(user_vecs, item_factors, banned_lists, *, k: int):
             if len(banned):
                 mask[row, np.asarray(banned, int)] = False  # lint: ok
         return topk_scores(user_vecs, item_factors, mask, k=k)
-    banned_np = np.full((b, max(wp, 1)), n_items, np.int32)
-    for row, bl in enumerate(banned_lists):
-        if len(bl):
-            banned_np[row, :len(bl)] = np.asarray(bl, np.int32)  # lint: ok
     if traced or on_dev:
         # traced / already-on-device inputs: no host-side padding
         # round-trip; shapes are what the trace gives us
         _record_dispatch("device", cells)
+        banned = _ban_block(b, max(wp, 1), n_items, banned_lists)
         out = _topk_scores_banned_device(
-            user_vecs, item_factors, jnp.asarray(banned_np), k=k,
+            user_vecs, item_factors, jnp.asarray(banned), k=k,
             has_bans=wp > 0)
         return out if traced else jax.device_get(out)
     # host inputs: pad batch to a power of two to bound jit variants
@@ -472,16 +511,49 @@ def topk_scores_filtered(user_vecs, item_factors, banned_lists, *, k: int):
         bp = _next_pow2(b)
         vecs = np.zeros((bp, user_vecs.shape[1]), np.float32)
         vecs[:b] = user_vecs
-        banned_pad = np.full((bp, max(wp, 1)), n_items, np.int32)
-        banned_pad[:b] = banned_np
+        banned = _ban_block(bp, max(wp, 1), n_items, banned_lists)
     with trace.stage("launch"):
         out = _topk_scores_banned_device(
             jnp.asarray(vecs), device_resident(item_factors),
-            jnp.asarray(banned_pad), k=k, has_bans=wp > 0)
+            jnp.asarray(banned), k=k, has_bans=wp > 0)
     with trace.stage("fetch"):
         scores, ixs = jax.device_get(out)
     _record_dispatch("device", cells, time.perf_counter() - t0, bp)
     return scores[:b], ixs[:b]
+
+
+def _topk_dense(device_fn, cosine: bool, vecs, item_factors, mask, k: int):
+    """`topk_scores` and `topk_similar`: one dense-mask top-k, the
+    scorer (`device_fn` on the device, rows normalised first on the
+    host when `cosine`) its argument. Dispatches host/device by problem
+    size (see module docstring)."""
+    traced = _is_traced(vecs, item_factors, mask)
+    k = min(k, item_factors.shape[0])   # both paths clamp identically
+    cells = vecs.shape[0] * item_factors.shape[0]
+    if traced:
+        _record_dispatch("device", cells)
+        return device_fn(vecs, item_factors, mask, k=k)
+    if _on_device(vecs, item_factors) \
+            or DISPATCH_POLICY.choose(cells) == "device":
+        t0 = time.perf_counter()
+        item_factors = device_resident(item_factors)
+        out = jax.device_get(device_fn(vecs, item_factors, mask, k=k))
+        _record_dispatch("device", cells, time.perf_counter() - t0)
+        return out
+    t0 = time.perf_counter()
+    DISPATCH_POLICY.host_begin()
+    try:
+        q = np.asarray(vecs)            # lint: ok — host-path arrays
+        f = np.asarray(item_factors)    # lint: ok — host-path arrays
+        if cosine:
+            q, f = unit_rows(q, np), unit_rows(f, np)
+        scores = np.where(np.asarray(mask), q @ f.T,  # lint: ok — host mask
+                          np.float32(NEG_INF))
+        out = _topk_host(scores, k)
+    finally:
+        DISPATCH_POLICY.host_end()
+    _record_dispatch("host", cells, time.perf_counter() - t0)
+    return out
 
 
 def topk_scores(user_vecs, item_factors, mask, *, k: int):
@@ -491,68 +563,17 @@ def topk_scores(user_vecs, item_factors, mask, *, k: int):
     item_factors: [n_items, rank]
     mask:         [b, n_items] bool — True = item allowed for that query
     Returns (scores [b, k], indexes [b, k]); masked-out slots score NEG_INF.
-    Dispatches host/device by problem size (see module docstring).
     """
-    traced = _is_traced(user_vecs, item_factors, mask)
-    k = min(k, item_factors.shape[0])   # both paths clamp identically
-    cells = user_vecs.shape[0] * item_factors.shape[0]
-    if traced:
-        _record_dispatch("device", cells)
-        return _topk_scores_device(user_vecs, item_factors, mask, k=k)
-    if _on_device(user_vecs, item_factors) \
-            or DISPATCH_POLICY.choose(cells) == "device":
-        t0 = time.perf_counter()
-        item_factors = device_resident(item_factors)
-        out = jax.device_get(
-            _topk_scores_device(user_vecs, item_factors, mask, k=k))
-        _record_dispatch("device", cells, time.perf_counter() - t0)
-        return out
-    t0 = time.perf_counter()
-    DISPATCH_POLICY.host_begin()
-    try:
-        scores = np.asarray(user_vecs) @ np.asarray(item_factors).T  # lint: ok
-        scores = np.where(np.asarray(mask), scores,  # lint: ok — host mask
-                          np.float32(NEG_INF))
-        out = _topk_host(scores, k)
-    finally:
-        DISPATCH_POLICY.host_end()
-    _record_dispatch("host", cells, time.perf_counter() - t0)
-    return out
+    return _topk_dense(_topk_scores_device, False, user_vecs,
+                       item_factors, mask, k)
 
 
 def topk_similar(query_vecs, item_factors, mask, *, k: int):
     """Cosine-similarity top-k: used by the similarproduct template
     (`examples/scala-parallel-similarproduct/.../ALSAlgorithm.scala`
-    cosine scoring). query_vecs [b, rank] are typically item vectors.
-    Dispatches host/device by problem size (see module docstring)."""
-    traced = _is_traced(query_vecs, item_factors, mask)
-    k = min(k, item_factors.shape[0])   # both paths clamp identically
-    cells = query_vecs.shape[0] * item_factors.shape[0]
-    if traced:
-        _record_dispatch("device", cells)
-        return _topk_similar_device(query_vecs, item_factors, mask, k=k)
-    if _on_device(query_vecs, item_factors) \
-            or DISPATCH_POLICY.choose(cells) == "device":
-        t0 = time.perf_counter()
-        item_factors = device_resident(item_factors)
-        out = jax.device_get(
-            _topk_similar_device(query_vecs, item_factors, mask, k=k))
-        _record_dispatch("device", cells, time.perf_counter() - t0)
-        return out
-    t0 = time.perf_counter()
-    DISPATCH_POLICY.host_begin()
-    try:
-        q = np.asarray(query_vecs)      # lint: ok — host-path arrays
-        f = np.asarray(item_factors)    # lint: ok — host-path arrays
-        qn = q / (np.linalg.norm(q, axis=-1, keepdims=True) + 1e-9)
-        fn = f / (np.linalg.norm(f, axis=-1, keepdims=True) + 1e-9)
-        scores = np.where(np.asarray(mask), qn @ fn.T,  # lint: ok
-                          np.float32(NEG_INF))
-        out = _topk_host(scores, k)
-    finally:
-        DISPATCH_POLICY.host_end()
-    _record_dispatch("host", cells, time.perf_counter() - t0)
-    return out
+    cosine scoring). query_vecs [b, rank] are typically item vectors."""
+    return _topk_dense(_topk_similar_device, True, query_vecs,
+                       item_factors, mask, k)
 
 
 def build_mask(n_items: int,
@@ -580,97 +601,102 @@ def build_mask(n_items: int,
 DEFAULT_SERVE_BUCKETS = (1, 2, 4, 8, 16, 32, 64)
 
 
-class BucketedTopK:
-    """Per-model serving plan: banned-index top-k over a device-resident
-    factor matrix, one AOT-compiled executable per batch bucket.
+class BucketedPlan:
+    """A deploy-warmed serving plan: top-k over a device-resident
+    factor matrix through one AOT-compiled executable per batch bucket
+    (module docstring: what is here, what a leaf supplies).
 
     Built once at deploy warmup (`Algorithm.warm_serving` via
     `CoreWorkflow.prepare_deploy`):
 
-      - the factor matrix is device-put ONCE and pinned for the plan's
+      - the factor matrix is placed ONCE and pinned for the plan's
         lifetime (no per-call re-transfer);
       - every bucket in `buckets` is `.lower(...).compile()`d up front
-        with a FIXED banned width, so a serve call dispatches straight to
-        a compiled executable — the jit tracing cache is never consulted
-        and steady state is zero-recompile by construction (jaxprobe's
-        `pio_jax_backend_compiles_total` stays flat across drains);
-      - off-CPU, the padded query block and banned block are donated
+        with a FIXED filter width, so a serve call dispatches straight
+        to a compiled executable — the jit tracing cache is never
+        consulted and steady state is zero-recompile by construction
+        (jaxprobe's `pio_jax_backend_compiles_total` stays flat across
+        drains);
+      - off-CPU, the padded query block and filter block are donated
         (their buffers are dead after the call by construction).
 
-    A call pads the batch up to the smallest warmed bucket (padded lanes:
-    zero vectors + all-filler bans; they are sliced off before return and
-    can never leak into results) and pads/fills the banned block to the
-    fixed width with `n_items`, which the scatter drops. Batches larger
-    than the biggest bucket are chunked. Queries that DON'T fit the plan
-    (k above `self.k`, more bans than `banned_width`, whitelists or
-    category filters needing a dense mask) go through the generic
-    `topk_scores*` entry points instead — callers gate on `fits()`.
+    A call pads the batch up to the smallest warmed bucket (padded
+    lanes: zero vectors and an empty filter; they are sliced off before
+    return and can never leak into results).
     """
 
+    # what `_record_dispatch` is told of a call through the XLA chain
+    # and through the fused kernel
+    path = "device"
+    fused_path = "fused"
+    # ban lists longer than this do not fit; a dense-mask plan takes none
+    banned_width = 0
+
     def __init__(self, item_factors, *, k: int,
-                 buckets: Sequence[int] = DEFAULT_SERVE_BUCKETS,
-                 banned_width: int = 256):
+                 buckets: Sequence[int] = DEFAULT_SERVE_BUCKETS):
         host = np.ascontiguousarray(item_factors, dtype=np.float32)
         self.n_items, self.rank = host.shape
         self.k = max(1, min(k, self.n_items))
         self.buckets = tuple(sorted({_next_pow2(b)
                                      for b in buckets if b > 0})) or (1,)
-        self.banned_width = _next_pow2(max(1, banned_width))
-        # share the identity-keyed residency cache with the generic paths
-        # (keep the host alias alive so the weakref cache entry survives)
-        self._host_factors = host
-        self.factors = device_resident(host)
         self._exe: dict = {}
-        # buckets served by the single-launch fused kernel (see
-        # ops/fused_topk.py); the rest keep the XLA chain
-        self.fused_buckets = 0
-        # which bucket sizes went fused, so dispatch attribution can
-        # tag "fused" vs "device" per call
+        # bucket sizes served by the single-launch fused kernel
+        # (ops/fused_topk.py; the rest keep the XLA chain), and the
+        # sub-blocks its gate judges in one call
         self._fused_sizes: set = set()
-        # sub-blocks the fused kernel's gate judges in one call
         self._gate_blocks = 0
+        # the host alias is the rollback token of the next swap (and
+        # keeps `device_resident`'s weakref cache entry alive)
+        self._host_factors = host
+        self.factors = self._place(host)
         register_resident_plan(self)
 
+    # -- what a leaf supplies ------------------------------------------------
+    def _place(self, host: np.ndarray):
+        """`host` on the device(s), as the bucket executables take it.
+        Here: whole, on one device, through the identity-keyed
+        residency cache the generic paths share."""
+        return device_resident(host)
+
     def resident_per_device_bytes(self) -> float:
-        """Bytes this plan pins on ONE device (the whole factor block:
-        single-device plans are not sharded)."""
+        """Bytes this plan pins on ONE device (here the whole block)."""
         return float(self._host_factors.nbytes)
+
+    def _compile_bucket(self, bucket: int):
+        """The compiled `(vecs, factors, filter) -> (scores, ids[,
+        merged])` executable of one bucket; a fused one goes through
+        `_mark_fused`."""
+        raise NotImplementedError
+
+    def _filter_spec(self, bucket: int) -> jax.ShapeDtypeStruct:
+        raise NotImplementedError
+
+    def _filter_block(self, bucket: int, filt) -> np.ndarray:
+        """One call's filter, padded to the bucket's fixed shape."""
+        raise NotImplementedError
+
+    # -- the shared machinery ------------------------------------------------
+    def _lower(self, fn, bucket: int, **static):
+        return fn.lower(
+            jax.ShapeDtypeStruct((bucket, self.rank), np.float32),
+            self.factors, self._filter_spec(bucket), **static).compile()
+
+    def _mark_fused(self, bucket: int, gate_blocks: int) -> None:
+        self._fused_sizes.add(bucket)
+        self._gate_blocks = gate_blocks
+
+    @property
+    def fused_buckets(self) -> int:
+        return len(self._fused_sizes)
 
     def warm(self) -> int:
         """AOT-lower/compile every bucket executable; returns how many
-        were compiled (idempotent: already-warm buckets are skipped).
-
-        With fusion on (`ops/fused_topk.py`, PIO_SERVE_FUSED gate)
-        every bucket compiles the single-launch fused kernel, and a
-        kernel that does not compile fails the warm-up; otherwise every
-        bucket compiles the AOT XLA chain. Both have the same
-        `(vecs, factors, banned)` signature, so `swap_factors` and the
-        zero-recompile contract hold either way."""
-        from predictionio_tpu.ops import fused_topk
-        fn = (_topk_scores_banned_device
-              if jax.default_backend() == "cpu"
-              else _topk_scores_banned_donated)
+        were compiled (idempotent: already-warm buckets are skipped)."""
         compiled = 0
         for b in self.buckets:
-            if b in self._exe:
-                continue
-            exe = fused_topk.maybe_build_bucket(
-                self.factors, n_items=self.n_items, rank=self.rank,
-                k=self.k, bucket=b, banned_width=self.banned_width)
-            if exe is not None:
-                self.fused_buckets += 1
-                self._fused_sizes.add(b)
-                self._gate_blocks = fused_topk.gate_blocks(
-                    self.n_items, self.k, self.rank)
-            else:
-                vec_spec = jax.ShapeDtypeStruct((b, self.rank),
-                                                np.float32)
-                ban_spec = jax.ShapeDtypeStruct((b, self.banned_width),
-                                                np.int32)
-                exe = fn.lower(vec_spec, self.factors, ban_spec,
-                               k=self.k, has_bans=True).compile()
-            self._exe[b] = exe
-            compiled += 1
+            if b not in self._exe:
+                self._exe[b] = self._compile_bucket(b)
+                compiled += 1
         if compiled:
             _publish_plan_temp_bytes(self._exe[b]
                                      for b in self._fused_sizes)
@@ -694,16 +720,16 @@ class BucketedTopK:
                 f"swap_factors shape {host.shape} != "
                 f"{(self.n_items, self.rank)}: catalog changed — a hot "
                 "swap cannot resize the AOT plan; re-warm instead")
-        prev = self._host_factors
-        self._host_factors = host
-        self.factors = device_resident(host)
+        factors = self._place(host)   # a failed placement swaps nothing
+        prev, self._host_factors, self.factors = (
+            self._host_factors, host, factors)
         return prev
 
     @property
     def max_bucket(self) -> int:
         return self.buckets[-1]
 
-    def fits(self, *, max_banned: int, k: int) -> bool:
+    def fits(self, *, k: int, max_banned: int = 0) -> bool:
         """Whether a batch with these parameters can use the plan."""
         return (bool(self._exe)
                 and k <= self.k and max_banned <= self.banned_width)
@@ -714,145 +740,157 @@ class BucketedTopK:
                 return bucket
         return self.max_bucket
 
-    def __call__(self, user_vecs, banned_lists: Sequence[Sequence[int]]):
-        """Score `user_vecs` [b, rank] against the resident factors with
-        per-row banned-index lists; returns host (scores [b, k],
-        indexes [b, k]). Pads to the bucket grid; chunks past the biggest
-        bucket."""
-        user_vecs = np.asarray(user_vecs, np.float32)  # lint: ok — host in
-        b = user_vecs.shape[0]
-        if b > self.max_bucket:
-            parts = [self(user_vecs[lo:lo + self.max_bucket],
-                          banned_lists[lo:lo + self.max_bucket])
-                     for lo in range(0, b, self.max_bucket)]
+    def __call__(self, vecs, filt):
+        """Score `vecs` [b, rank] against the resident factors under the
+        leaf's filter (`filt`: one entry a row); returns host (scores
+        [b, k], indexes [b, k]). Pads to the bucket grid; chunks past
+        the biggest bucket."""
+        vecs = np.asarray(vecs, np.float32)  # lint: ok — host in
+        b = vecs.shape[0]
+        top = self.max_bucket
+        if b > top:
+            parts = [self(vecs[lo:lo + top], filt[lo:lo + top])
+                     for lo in range(0, b, top)]
             return (np.concatenate([p[0] for p in parts]),
                     np.concatenate([p[1] for p in parts]))
         bucket = self._bucket_for(b)
         exe = self._exe.get(bucket)
         if exe is None:
             raise RuntimeError(
-                f"BucketedTopK bucket {bucket} not warmed; call warm() "
-                "at deploy time")
+                f"{type(self).__name__} bucket {bucket} not warmed; "
+                "call warm() at deploy time")
         t0 = time.perf_counter()
         with trace.stage("pack"):
-            vecs = np.zeros((bucket, self.rank), np.float32)
-            vecs[:b] = user_vecs
-            banned = np.full((bucket, self.banned_width), self.n_items,
-                             np.int32)
-            for row, bl in enumerate(banned_lists):
-                if len(bl):
-                    banned[row, :len(bl)] = np.asarray(bl, np.int32)  # lint: ok
+            block = np.zeros((bucket, self.rank), np.float32)
+            block[:b] = vecs
+            filt = self._filter_block(bucket, filt)
         with trace.stage("launch"):
-            out = exe(vecs, self.factors, banned)
+            out = exe(block, self.factors, filt)
         with trace.stage("fetch"):
             # a fused bucket also returns how many sub-blocks it merged
             scores, ixs, *merged = jax.device_get(out)
         if merged:
             _observe_merge_share(merged[0], self._gate_blocks)
         _record_dispatch(
-            "fused" if bucket in self._fused_sizes else "device",
+            self.fused_path if bucket in self._fused_sizes else self.path,
             bucket * self.n_items, time.perf_counter() - t0, bucket)
         return scores[:b], ixs[:b]
 
 
-class BucketedSimilar:
-    """Serving plan for the dense-mask cosine path (the similar-product
-    template's `batch_predict`): item factors pinned device-resident and
-    one AOT-compiled `_topk_similar_raw` executable per batch bucket, so
-    a warmed deployment serves its first similar-items request — and
-    every coalesced batch after it — without touching the jit tracing
-    cache.
-
-    Unlike `BucketedTopK` the filter here is the template's dense
-    [b, n_items] category/white/black mask, so the mask block is padded
-    to the bucket with all-False rows (their lanes score NEG_INF and are
-    sliced off before return). Batches above the biggest bucket chunk.
-    """
+class BucketedTopK(BucketedPlan):
+    """The ban-list plan on one device. Its filter block is the rows'
+    banned item indexes at a FIXED width, filled with `n_items`, which
+    the scatter drops. Queries with more bans than `banned_width`, a k
+    above `self.k`, or whitelists / category filters that need a dense
+    mask do not fit (`score_banned`)."""
 
     def __init__(self, item_factors, *, k: int,
-                 buckets: Sequence[int] = DEFAULT_SERVE_BUCKETS):
-        host = np.ascontiguousarray(item_factors, dtype=np.float32)
-        self.n_items, self.rank = host.shape
-        self.k = max(1, min(k, self.n_items))
-        self.buckets = tuple(sorted({_next_pow2(b)
-                                     for b in buckets if b > 0})) or (1,)
-        self._host_factors = host
-        self.factors = device_resident(host)
-        self._exe: dict = {}
-        register_resident_plan(self)
+                 buckets: Sequence[int] = DEFAULT_SERVE_BUCKETS,
+                 banned_width: int = 256):
+        self.banned_width = _next_pow2(max(1, banned_width))
+        super().__init__(item_factors, k=k, buckets=buckets)
 
-    def resident_per_device_bytes(self) -> float:
-        return float(self._host_factors.nbytes)
-
-    def warm(self) -> int:
-        """AOT-lower/compile every bucket executable (idempotent)."""
-        fn = (_topk_similar_device if jax.default_backend() == "cpu"
-              else _topk_similar_donated)
-        compiled = 0
-        for b in self.buckets:
-            if b in self._exe:
-                continue
-            vec_spec = jax.ShapeDtypeStruct((b, self.rank), np.float32)
-            mask_spec = jax.ShapeDtypeStruct((b, self.n_items), np.bool_)
-            self._exe[b] = fn.lower(vec_spec, self.factors, mask_spec,
-                                    k=self.k).compile()
-            compiled += 1
-        return compiled
-
-    def swap_factors(self, item_factors) -> np.ndarray:
-        """Hot-swap the resident factor block without recompiling (the
-        executables take the factors positionally); returns the
-        previous host factors as the rollback token. See
-        `BucketedTopK.swap_factors`."""
-        host = np.ascontiguousarray(item_factors, dtype=np.float32)
-        if host.shape != (self.n_items, self.rank):
-            raise ValueError(
-                f"swap_factors shape {host.shape} != "
-                f"{(self.n_items, self.rank)}: catalog changed — a hot "
-                "swap cannot resize the AOT plan; re-warm instead")
-        prev = self._host_factors
-        self._host_factors = host
-        self.factors = device_resident(host)
-        return prev
-
-    @property
-    def max_bucket(self) -> int:
-        return self.buckets[-1]
-
-    def fits(self, *, k: int) -> bool:
-        return bool(self._exe) and k <= self.k
-
-    def _bucket_for(self, b: int) -> int:
-        for bucket in self.buckets:
-            if bucket >= b:
-                return bucket
-        return self.max_bucket
-
-    def __call__(self, query_vecs, mask):
-        """Cosine top-k of `query_vecs` [b, rank] against the resident
-        factors under dense mask [b, n_items]; returns host (scores
-        [b, k], indexes [b, k])."""
-        query_vecs = np.asarray(query_vecs, np.float32)  # lint: ok — host in
-        mask = np.asarray(mask, bool)                    # lint: ok — host in
-        b = query_vecs.shape[0]
-        if b > self.max_bucket:
-            parts = [self(query_vecs[lo:lo + self.max_bucket],
-                          mask[lo:lo + self.max_bucket])
-                     for lo in range(0, b, self.max_bucket)]
-            return (np.concatenate([p[0] for p in parts]),
-                    np.concatenate([p[1] for p in parts]))
-        bucket = self._bucket_for(b)
-        exe = self._exe.get(bucket)
+    def _compile_bucket(self, bucket: int):
+        """With fusion on (`ops/fused_topk.py`, PIO_SERVE_FUSED gate)
+        every bucket compiles the single-launch fused kernel, and a
+        kernel that does not compile fails the warm-up; otherwise every
+        bucket compiles the AOT XLA chain. Both have the same
+        `(vecs, factors, banned)` signature, so `swap_factors` and the
+        zero-recompile contract hold either way."""
+        from predictionio_tpu.ops import fused_topk
+        exe = fused_topk.maybe_build_bucket(
+            self.factors, n_items=self.n_items, rank=self.rank,
+            k=self.k, bucket=bucket, banned_width=self.banned_width)
         if exe is None:
-            raise RuntimeError(
-                f"BucketedSimilar bucket {bucket} not warmed; call warm() "
-                "at deploy time")
-        t0 = time.perf_counter()
-        vecs = np.zeros((bucket, self.rank), np.float32)
-        vecs[:b] = query_vecs
-        mask_p = np.zeros((bucket, self.n_items), bool)
-        mask_p[:b] = mask
-        scores, ixs = jax.device_get(exe(vecs, self.factors, mask_p))
-        _record_dispatch("device", bucket * self.n_items,
-                         time.perf_counter() - t0)
-        return scores[:b], ixs[:b]
+            return self._lower(
+                _plan_jit(_topk_scores_banned_device,
+                          _topk_scores_banned_donated),
+                bucket, k=self.k, has_bans=True)
+        self._mark_fused(bucket, fused_topk.gate_blocks(
+            self.n_items, self.k, self.rank))
+        return exe
+
+    def _filter_spec(self, bucket: int) -> jax.ShapeDtypeStruct:
+        return jax.ShapeDtypeStruct((bucket, self.banned_width), np.int32)
+
+    def _filter_block(self, bucket: int, banned_lists) -> np.ndarray:
+        return _ban_block(bucket, self.banned_width, self.n_items,
+                          banned_lists)
+
+
+class BucketedSimilar(BucketedPlan):
+    """The dense-mask cosine plan on one device (the similar-product
+    template's `batch_predict`). Its filter block is the template's
+    [b, n_items] category/white/black mask, padded with all-False rows
+    (their lanes score NEG_INF and are sliced off before return) and,
+    where the resident factors are padded (a mesh), all-False
+    columns."""
+
+    def _compile_bucket(self, bucket: int):
+        return self._lower(
+            _plan_jit(_topk_similar_device, _topk_similar_donated),
+            bucket, k=self.k)
+
+    def _filter_spec(self, bucket: int) -> jax.ShapeDtypeStruct:
+        return jax.ShapeDtypeStruct((bucket, self.factors.shape[0]),
+                                    np.bool_)
+
+    def _filter_block(self, bucket: int, mask) -> np.ndarray:
+        block = np.zeros((bucket, self.factors.shape[0]), bool)
+        block[:len(mask), :self.n_items] = mask
+        return block
+
+
+def _by_fit(plan, generic, vecs, filt, ks, fit):
+    """The slow road of `score_banned` / `score_similar`: some row does
+    not fit the plan. Rows that do go through it, the rest through
+    `generic(vecs, filt, k)`, and the results go back in the batch's
+    order, `max(ks)` wide (NEG_INF where a route returned fewer)."""
+    width = max(ks)
+    if not any(fit):
+        return generic(vecs, filt, width)
+    vecs = np.asarray(vecs)  # lint: ok — host in
+    scores = np.full((len(ks), width), NEG_INF, np.float32)
+    ixs = np.zeros((len(ks), width), np.int32)
+    on = [r for r, f in enumerate(fit) if f]
+    off = [r for r, f in enumerate(fit) if not f]
+    for rows, call in ((on, lambda v, f, _k: plan(v, f)), (off, generic)):
+        s, i = call(vecs[rows], [filt[r] for r in rows],
+                    max(ks[r] for r in rows))
+        w = min(width, s.shape[1])
+        scores[rows, :w], ixs[rows, :w] = s[:, :w], i[:, :w]
+    return scores, ixs
+
+
+def score_banned(plan, user_vecs, item_factors, banned_lists,
+                 ks: Sequence[int]):
+    """Ban-list top-k for a template's batch: through `plan` (a warmed
+    ban-list plan, or None) where a row fits it (its own k, `ks[row]`,
+    and its ban count), else through `topk_scores_filtered`. Decided
+    row by row: one heavy user whose seen-history ban list overflows
+    the plan's banned_width must not demote the whole coalesced batch
+    to the generic (host-leaning) path — that all-or-nothing gate is
+    how the r05 scale runs served hundreds of host calls and zero
+    device batches. Returns host (scores, indexes), at least `max(ks)`
+    wide."""
+    if plan is not None and plan.fits(
+            k=max(ks), max_banned=max(map(len, banned_lists), default=0)):
+        return plan(user_vecs, banned_lists)
+    fit = [plan is not None and plan.fits(k=k, max_banned=len(bl))
+           for k, bl in zip(ks, banned_lists)]
+    return _by_fit(
+        plan, lambda v, f, k: topk_scores_filtered(v, item_factors, f, k=k),
+        user_vecs, banned_lists, ks, fit)
+
+
+def score_similar(plan, query_vecs, item_factors, mask, ks: Sequence[int]):
+    """`score_banned`'s dense-mask twin: cosine top-k through `plan` (a
+    warmed `similar_plan`, or None) where a row's k fits it, else
+    through `topk_similar`."""
+    if plan is not None and plan.fits(k=max(ks)):
+        return plan(query_vecs, mask)
+    fit = [plan is not None and plan.fits(k=k) for k in ks]
+    return _by_fit(
+        plan, lambda v, m, k: topk_similar(
+            v, item_factors, np.asarray(m), k=k),  # lint: ok — host mask
+        query_vecs, mask, ks, fit)

@@ -6,9 +6,11 @@ module gives serving the sharded-scoring shape "Scalable ML Training
 Infrastructure at Google" describes for ads scoring: partition the
 embedding (factor) table row-wise, score locally, merge partial top-k.
 
-`ShardedBucketedTopK` / `ShardedBucketedSimilar` are drop-in serving
-plans (same `warm()/fits()/__call__` contract as their single-device
-counterparts in `ops/topk.py`):
+`ShardedBucketedTopK` / `ShardedBucketedSimilar` are the two plans of
+`ops/topk.py` with another placement and another bucket program, and
+that is all this module writes of them: the bucket grid, the call
+cycle, `swap_factors` and `fits` are `topk.BucketedPlan`'s, the ban
+and mask blocks their single-device parents'. What differs:
 
   - item factors are padded to a multiple of the shard count and
     device_put ONCE with a row sharding over the serve mesh's "items"
@@ -43,17 +45,14 @@ else the backend's reported bytes_limit; unknown capacity, e.g. host
 CPU, never auto-shards). `PIO_SERVE_SHARD=off` disables entirely and
 `PIO_SERVE_SHARDS` caps the shard count.
 
-Every sharded dispatch lands in `pio_topk_dispatch_total{path=
-"sharded"}` and `DISPATCH_COUNTS["sharded"]`, and feeds the
-`DispatchPolicy` sharded-path EWMA; plan construction publishes
-`pio_serve_shards` and per-shard `pio_serve_shard_bytes{shard=...}`
-HBM-residency gauges.
+Every sharded dispatch counts under `path="sharded"`; plan
+construction publishes `pio_serve_shards` and per-shard
+`pio_serve_shard_bytes{shard=...}` HBM-residency gauges.
 """
 
 from __future__ import annotations
 
 import os
-import time
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -61,12 +60,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.obs import trace
 from predictionio_tpu.ops import topk
 from predictionio_tpu.ops.topk import (
     DEFAULT_SERVE_BUCKETS, NEG_INF, BucketedSimilar, BucketedTopK,
-    _next_pow2, _observe_merge_share, _publish_plan_temp_bytes,
-    _record_dispatch,
+    drop_banned, exact_scores, masked_topk, unit_rows,
 )
 from predictionio_tpu.parallel.mesh import (  # noqa: F401 — re-export
     parse_fleet_mesh, shard_put,
@@ -298,121 +295,80 @@ def _jit_merged(local_candidates, k: int, mesh):
                    donate_argnums=donate)
 
 
-class _ShardedPlanBase:
-    """Shared bucketing/pad/chunk mechanics of the two sharded plans."""
+class _OverMesh:
+    """Placement over the serve mesh, for a plan of `ops/topk.py` named
+    after it in the bases: the (zero-padded) factors row-sharded ONCE
+    with `shard_put`, each device holding `per_shard` rows of the
+    `n_pad`, and every dispatch labelled `sharded`. Same shape => same
+    mesh/axis sharding, so a `swap_factors` keeps the bucket
+    executables."""
 
-    def __init__(self, item_factors, *, k: int, buckets: Sequence[int],
-                 mesh):
-        host = np.ascontiguousarray(item_factors, dtype=np.float32)
-        self.n_items, self.rank = host.shape
-        self.k = max(1, min(k, self.n_items))
-        self.buckets = tuple(sorted({_next_pow2(b)
-                                     for b in buckets if b > 0})) or (1,)
+    path = fused_path = "sharded"
+
+    def __init__(self, item_factors, *, mesh=None, **plan_args):
         self.mesh = mesh
         self.n_shards = int(mesh.shape[SHARD_AXIS])  # lint: ok — host
-        # row-shard the (zero-padded) factors across the mesh ONCE; the
-        # sharded array is the plan's resident model state
-        self._host_factors = host
-        self.factors, _ = shard_put(host, mesh, SHARD_AXIS)
-        self.n_pad = int(self.factors.shape[0])  # lint: ok — shape meta
+        super().__init__(item_factors, **plan_args)
+        _publish_shard_gauges(self.n_shards, self.per_shard, self.rank)
+
+    def _place(self, host: np.ndarray):
+        factors, _ = shard_put(host, self.mesh, SHARD_AXIS)
+        self.n_pad = int(factors.shape[0])  # lint: ok — shape meta
         self.per_shard = self.n_pad // self.n_shards
         # per-shard candidate count: a shard can never contribute more
         # rows than it holds (k > per_shard clamps, the merge still
         # sees >= k real candidates overall)
         self.k_shard = min(self.k, self.per_shard)
-        self._exe: dict = {}
-        topk.register_resident_plan(self)
-        _publish_shard_gauges(self.n_shards, self.per_shard, self.rank)
+        return factors
 
     def resident_per_device_bytes(self) -> float:
         """Bytes this plan pins per device: one padded shard's rows."""
         return float(self.per_shard * self.rank * 4)
 
-    def swap_factors(self, item_factors) -> np.ndarray:
-        """Hot-swap the sharded resident factors (streaming refresher
-        commit): same shape => same mesh/axis sharding => the per-bucket
-        executables (which take the factor operand positionally) keep
-        serving with zero recompiles; only the new rows cross to the
-        devices. Returns the previous host factors (rollback token)."""
-        host = np.ascontiguousarray(item_factors, dtype=np.float32)
-        if host.shape != (self.n_items, self.rank):
-            raise ValueError(
-                f"swap_factors shape {host.shape} != "
-                f"{(self.n_items, self.rank)}: catalog changed — a hot "
-                "swap cannot resize the AOT plan; re-warm instead")
-        factors, _ = shard_put(host, self.mesh, SHARD_AXIS)
-        prev = self._host_factors
-        self._host_factors = host
-        self.factors = factors
-        return prev
-
-    @property
-    def max_bucket(self) -> int:
-        return self.buckets[-1]
-
-    def _bucket_for(self, b: int) -> int:
-        for bucket in self.buckets:
-            if bucket >= b:
-                return bucket
-        return self.max_bucket
-
-    def _require_exe(self, bucket: int):
-        exe = self._exe.get(bucket)
-        if exe is None:
-            raise RuntimeError(
-                f"{type(self).__name__} bucket {bucket} not warmed; "
-                "call warm() at deploy time")
-        return exe
+    def _merged(self, body, filter_spec, n_out: int = 2, check=True):
+        """`body` over the shards, then the global merge, as one jit."""
+        from jax.sharding import PartitionSpec as P
+        return _jit_merged(jax.shard_map(
+            body, mesh=self.mesh,
+            in_specs=(P(), P(SHARD_AXIS, None), filter_spec),
+            out_specs=(P(SHARD_AXIS),) * n_out,
+            check_vma=check), self.k, self.mesh)
 
 
-class ShardedBucketedTopK(_ShardedPlanBase):
+class ShardedBucketedTopK(_OverMesh, BucketedTopK):
     """Banned-index top-k over a row-sharded resident factor matrix:
     per-shard partial top-k on-device, allgather + merge to the global
     top-k (module docstring has the full program shape and the
-    tie-parity argument). Drop-in for `BucketedTopK`."""
+    tie-parity argument). Drop-in for `BucketedTopK`: ban lists stay
+    in GLOBAL id space."""
 
-    def __init__(self, item_factors, *, k: int,
-                 buckets: Sequence[int] = DEFAULT_SERVE_BUCKETS,
-                 banned_width: int = 256, mesh=None):
-        super().__init__(item_factors, k=k, buckets=buckets, mesh=mesh)
-        self.banned_width = _next_pow2(max(1, banned_width))
-        # whether the per-shard local-candidate stage runs as the
-        # single-launch fused kernel (ops/fused_topk.py)
-        self.fused = False
-        # sub-blocks the fused kernels' gates judge in one call, all
-        # shards together
-        self._gate_blocks = 0
-        self._fn = self._build()
-
-    def _build(self, bucket: Optional[int] = None):
+    def _build(self, bucket: int):
+        """The jitted program of one bucket. With fusion on
+        (PIO_SERVE_FUSED gate) the per-shard stage is the fused kernel,
+        whose grid is specialised to the bucket, and a kernel that does
+        not compile fails the warm-up; otherwise the XLA body."""
         from jax.sharding import PartitionSpec as P
         from predictionio_tpu.ops import fused_topk
-        per, n_items, kk, k = (self.per_shard, self.n_items,
-                               self.k_shard, self.k)
+        per, n_items, kk = self.per_shard, self.n_items, self.k_shard
+        local = fused_topk.shard_local_candidates(
+            per, self.rank, k=kk, bucket=bucket,
+            banned_width=self.banned_width, axis=SHARD_AXIS)
+        if local is not None:
+            self._mark_fused(bucket, fused_topk.gate_blocks(
+                per, kk, self.rank) * self.n_shards)
 
-        # the fused per-shard local-candidate kernel needs the batch
-        # bucket at build time (its grid is shape-specialized); the XLA
-        # body below shape-polymorphically covers every bucket
-        local = None
-        if bucket is not None:
-            local = fused_topk.shard_local_candidates(
-                per, self.rank, k=kk, bucket=bucket,
-                banned_width=self.banned_width, axis=SHARD_AXIS)
-            if local is None:
-                return None
-            self.fused = True
-            self._gate_blocks = (fused_topk.gate_blocks(per, kk, self.rank)
-                                 * self.n_shards)
-
+        # Not `topk`'s ban-list chain: a shard must translate the
+        # GLOBAL ban ids to its own columns and bound its rows by
+        # `n_items`, which the single-device body has no mesh position
+        # to do. The product and the scatter are the shared ones.
         def body(vecs, factors_local, banned):
             # vecs [b, rank] + banned [b, W] replicated; factors_local
             # [per_shard, rank] is this shard's catalog slice
             base = jax.lax.axis_index(SHARD_AXIS) * per
-            # banned ids are GLOBAL: translate to this shard's local
-            # columns. Out-of-shard ids (and the n_items filler) must be
-            # routed to an explicitly out-of-bounds slot BEFORE the
-            # scatter — `.at[]` wraps negative indices NumPy-style even
-            # under mode="drop", so a bare `banned - base` would make a
+            # Out-of-shard ids (and the n_items filler) must be routed
+            # to an explicitly out-of-bounds slot BEFORE the scatter —
+            # `.at[]` wraps negative indices NumPy-style even under
+            # mode="drop", so a bare `banned - base` would make a
             # banned id g also ban g + per_shard on the next shard.
             loc = banned - base
             loc = jnp.where((loc >= 0) & (loc < per), loc, per)
@@ -424,15 +380,10 @@ class ShardedBucketedTopK(_ShardedPlanBase):
                               per).astype(jnp.int32).reshape((1,))
                 s, ix, merged = local(nv, vecs, factors_local, loc)
                 return s[None], (ix + base)[None], merged[None]
-            else:
-                scores = jnp.matmul(vecs, factors_local.T,
-                                    precision=jax.lax.Precision.HIGHEST)
-                rows = jnp.arange(scores.shape[0])[:, None]
-                scores = scores.at[rows, loc].set(NEG_INF, mode="drop")
-                gids = base + jnp.arange(per)
-                scores = jnp.where(gids[None, :] < n_items, scores,
-                                   NEG_INF)
-                s, ix = jax.lax.top_k(scores, kk)
+            scores = drop_banned(exact_scores(vecs, factors_local), loc)
+            gids = base + jnp.arange(per)
+            scores = jnp.where(gids[None, :] < n_items, scores, NEG_INF)
+            s, ix = jax.lax.top_k(scores, kk)
             return s[None], (ix + base)[None]
 
         # Pallas' HLO interpreter (the CPU parity tests' stand-in for
@@ -441,80 +392,13 @@ class ShardedBucketedTopK(_ShardedPlanBase):
         # kernel is traced with the check off by pallas_call itself, so
         # only the interpreted form has to opt out
         check = local is None or not fused_topk.interpreted()
-        return _jit_merged(jax.shard_map(
-            body, mesh=self.mesh,
-            in_specs=(P(), P(SHARD_AXIS, None), P()),
-            out_specs=(P(SHARD_AXIS),) * (2 if local is None else 3),
-            check_vma=check), k, self.mesh)
+        return self._merged(body, P(), 2 if local is None else 3, check)
 
-    def warm(self) -> int:
-        """AOT-lower/compile every bucket executable against the
-        resident sharded factors (idempotent). With fusion on
-        (PIO_SERVE_FUSED gate) each bucket's per-shard stage is the
-        fused kernel, and a kernel that does not compile fails the
-        warm-up; otherwise every bucket compiles the XLA body."""
-        compiled = 0
-        for b in self.buckets:
-            if b in self._exe:
-                continue
-            vec_spec = jax.ShapeDtypeStruct((b, self.rank), np.float32)
-            ban_spec = jax.ShapeDtypeStruct((b, self.banned_width),
-                                            np.int32)
-            fn = self._build(bucket=b) or self._fn
-            self._exe[b] = fn.lower(vec_spec, self.factors,
-                                    ban_spec).compile()
-            compiled += 1
-        if compiled and self.fused:
-            _publish_plan_temp_bytes(self._exe.values())
-        return compiled
-
-    def bucket_kernels(self) -> dict:
-        """Which per-shard kernel serves each warmed bucket."""
-        return {b: "fused" if self.fused else "xla"
-                for b in sorted(self._exe)}
-
-    def fits(self, *, max_banned: int, k: int) -> bool:
-        """Same gate as `BucketedTopK.fits`."""
-        return (bool(self._exe)
-                and k <= self.k and max_banned <= self.banned_width)
-
-    def __call__(self, user_vecs, banned_lists: Sequence[Sequence[int]]):
-        """Score [b, rank] queries against the sharded catalog with
-        per-row GLOBAL banned-id lists; returns host (scores [b, k],
-        ids [b, k]). Pads to the bucket grid; chunks past the biggest
-        bucket."""
-        user_vecs = np.asarray(user_vecs, np.float32)  # lint: ok — host in
-        b = user_vecs.shape[0]
-        if b > self.max_bucket:
-            parts = [self(user_vecs[lo:lo + self.max_bucket],
-                          banned_lists[lo:lo + self.max_bucket])
-                     for lo in range(0, b, self.max_bucket)]
-            return (np.concatenate([p[0] for p in parts]),
-                    np.concatenate([p[1] for p in parts]))
-        bucket = self._bucket_for(b)
-        exe = self._require_exe(bucket)
-        t0 = time.perf_counter()
-        with trace.stage("pack"):
-            vecs = np.zeros((bucket, self.rank), np.float32)
-            vecs[:b] = user_vecs
-            banned = np.full((bucket, self.banned_width), self.n_items,
-                             np.int32)
-            for row, bl in enumerate(banned_lists):
-                if len(bl):
-                    banned[row, :len(bl)] = np.asarray(bl, np.int32)  # lint: ok
-        with trace.stage("launch"):
-            out = exe(vecs, self.factors, banned)
-        with trace.stage("fetch"):
-            # fused shards also return their summed merge count
-            scores, ixs, *merged = jax.device_get(out)
-        if merged:
-            _observe_merge_share(merged[0], self._gate_blocks)
-        _record_dispatch("sharded", bucket * self.n_items,
-                         time.perf_counter() - t0, bucket)
-        return scores[:b], ixs[:b]
+    def _compile_bucket(self, bucket: int):
+        return self._lower(self._build(bucket), bucket)
 
 
-class ShardedBucketedSimilar(_ShardedPlanBase):
+class ShardedBucketedSimilar(_OverMesh, BucketedSimilar):
     """Dense-mask cosine top-k over a row-sharded resident factor
     matrix (the similar-product template's filter shape): the mask is
     column-sharded to match the catalog rows, each shard normalizes
@@ -522,76 +406,19 @@ class ShardedBucketedSimilar(_ShardedPlanBase):
     single-device program), partial top-k, allgather + merge. Drop-in
     for `BucketedSimilar`."""
 
-    def __init__(self, item_factors, *, k: int,
-                 buckets: Sequence[int] = DEFAULT_SERVE_BUCKETS,
-                 mesh=None):
-        super().__init__(item_factors, k=k, buckets=buckets, mesh=mesh)
-        self._fn = self._build()
-
-    def _build(self):
+    def _compile_bucket(self, bucket: int):
         from jax.sharding import PartitionSpec as P
-        per, kk, k = self.per_shard, self.k_shard, self.k
+        per, kk = self.per_shard, self.k_shard
 
         def body(query_vecs, factors_local, mask_local):
-            base = jax.lax.axis_index(SHARD_AXIS) * per
-            qn = query_vecs / (jnp.linalg.norm(query_vecs, axis=-1,
-                                               keepdims=True) + 1e-9)
-            fn = factors_local / (jnp.linalg.norm(factors_local, axis=-1,
-                                                  keepdims=True) + 1e-9)
-            scores = jnp.matmul(qn, fn.T,
-                                precision=jax.lax.Precision.HIGHEST)
-            # padding rows arrive masked False (the caller pads the
-            # mask columns with False), so no gid test is needed here
-            scores = jnp.where(mask_local, scores, NEG_INF)
-            s, ix = jax.lax.top_k(scores, kk)
-            return s[None], (ix + base)[None]
+            # the single-device chain on this shard's rows: padding
+            # rows arrive masked False (the filter block pads the mask
+            # columns with False), so no gid test is needed here
+            s, ix = masked_topk(unit_rows(query_vecs),
+                                unit_rows(factors_local), mask_local, kk)
+            return s[None], (ix + jax.lax.axis_index(SHARD_AXIS) * per)[None]
 
-        return _jit_merged(jax.shard_map(
-            body, mesh=self.mesh,
-            in_specs=(P(), P(SHARD_AXIS, None), P(None, SHARD_AXIS)),
-            out_specs=(P(SHARD_AXIS), P(SHARD_AXIS))), k, self.mesh)
-
-    def warm(self) -> int:
-        """AOT-lower/compile every bucket executable (idempotent)."""
-        compiled = 0
-        for b in self.buckets:
-            if b in self._exe:
-                continue
-            vec_spec = jax.ShapeDtypeStruct((b, self.rank), np.float32)
-            mask_spec = jax.ShapeDtypeStruct((b, self.n_pad), np.bool_)
-            self._exe[b] = self._fn.lower(vec_spec, self.factors,
-                                          mask_spec).compile()
-            compiled += 1
-        return compiled
-
-    def fits(self, *, k: int) -> bool:
-        return bool(self._exe) and k <= self.k
-
-    def __call__(self, query_vecs, mask):
-        """Cosine top-k of [b, rank] queries against the sharded
-        catalog under a dense [b, n_items] mask; returns host (scores
-        [b, k], ids [b, k])."""
-        query_vecs = np.asarray(query_vecs, np.float32)  # lint: ok — host in
-        mask = np.asarray(mask, bool)                    # lint: ok — host in
-        b = query_vecs.shape[0]
-        if b > self.max_bucket:
-            parts = [self(query_vecs[lo:lo + self.max_bucket],
-                          mask[lo:lo + self.max_bucket])
-                     for lo in range(0, b, self.max_bucket)]
-            return (np.concatenate([p[0] for p in parts]),
-                    np.concatenate([p[1] for p in parts]))
-        bucket = self._bucket_for(b)
-        exe = self._require_exe(bucket)
-        t0 = time.perf_counter()
-        vecs = np.zeros((bucket, self.rank), np.float32)
-        vecs[:b] = query_vecs
-        # padding lanes AND padding catalog columns are all-False
-        mask_p = np.zeros((bucket, self.n_pad), bool)
-        mask_p[:b, :self.n_items] = mask
-        scores, ixs = jax.device_get(exe(vecs, self.factors, mask_p))
-        _record_dispatch("sharded", bucket * self.n_items,
-                         time.perf_counter() - t0)
-        return scores[:b], ixs[:b]
+        return self._lower(self._merged(body, P(None, SHARD_AXIS)), bucket)
 
 
 class ShardSliceTopK:
@@ -654,9 +481,7 @@ class ShardSliceTopK:
         return self._inner.warm()
 
     def bucket_kernels(self) -> dict:
-        kernels = getattr(self._inner, "bucket_kernels", None)
-        return (kernels() if kernels is not None
-                else {b: "xla" for b in self._inner.buckets})
+        return self._inner.bucket_kernels()
 
     def fits(self, *, max_banned: int, k: int) -> bool:
         # k above the slice's own candidate count still FITS: the

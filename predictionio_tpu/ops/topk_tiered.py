@@ -140,6 +140,9 @@ class TieredTopK:
     def warm(self) -> int:
         return self._hot.warm()
 
+    def bucket_kernels(self) -> dict:
+        return self._hot.bucket_kernels()
+
     def fits(self, *, max_banned: int, k: int) -> bool:
         return (self._hot.fits(max_banned=max_banned, k=self._hot.k)
                 and k <= self.k and max_banned <= self.banned_width)

@@ -1092,11 +1092,8 @@ class PredictionServer(HTTPServerBase):
             "pio_plan_resident_bytes",
             "Device-resident bytes of live serving plans by bucket",
             labels=("device", "bucket"))
-        for model in dep.models:
-            plan = getattr(model, "_serve_plan", None)
-            factors = getattr(plan, "factors", None)
-            if factors is None:
-                continue
+        for _, plan in _plans_of(dep):
+            factors = plan.factors
             try:
                 dev_obj = next(iter(factors.devices()))
                 device = f"{dev_obj.platform}:{dev_obj.id}"
@@ -1105,11 +1102,9 @@ class PredictionServer(HTTPServerBase):
                 continue
             gauge.labels(device=device, bucket="factors").set(
                 float(nbytes))  # lint: ok — host int
-            rank = int(getattr(plan, "rank", 0) or 0)  # lint: ok — host int
-            k = int(getattr(plan, "k", 0) or 0)  # lint: ok — host int
-            for b in getattr(plan, "buckets", ()) or ():
+            for b in plan.buckets:
                 gauge.labels(device=device, bucket=str(b)).set(
-                    float(b * (rank * 4 + k * 8)))
+                    float(b * (plan.rank * 4 + plan.k * 8)))
 
     # -- deployment lifecycle ----------------------------------------------
     def _resolve_instance(self):
@@ -1174,11 +1169,9 @@ class PredictionServer(HTTPServerBase):
         any — unwrapping one mesh-slice layer, where a giant slice
         tiers itself."""
         out = []
-        for holder in list(dep.algos) + list(dep.models):
-            plan = getattr(holder, "_serve_plan", None)
+        for _, plan in _plans_of(dep):
             plan = getattr(plan, "_inner", plan)
-            if plan is not None and hasattr(plan, "fold_accesses") \
-                    and plan not in out:
+            if hasattr(plan, "fold_accesses") and plan not in out:
                 out.append(plan)
         return out
 
@@ -1369,6 +1362,25 @@ class PredictionServer(HTTPServerBase):
         except Exception:
             return None
 
+    @staticmethod
+    def _await_release(host: str, port: int, timeout: float = 5.0) -> None:
+        """Wait until nothing accepts on the port any more. `/stop`
+        answers before the squatter has drained and closed, and the
+        selector wire's listeners are SO_REUSEPORT: a bind beside them
+        succeeds at once, and until they close the kernel hands part of
+        the NEW server's connections to them, to be reset. Past
+        `timeout` the bind goes ahead as it did."""
+        import socket
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            try:
+                socket.create_connection((host, port), timeout=0.5).close()
+            except ConnectionRefusedError:
+                return
+            except OSError:
+                pass
+            time.sleep(0.02)  # lint: ok — polls a listener, retries no call
+
     def start(self, background: bool = True) -> int:
         """Deploy first undeploys any server squatting on the target port
         (CreateServer.scala:347-357: the MasterActor sends StopServer to
@@ -1391,6 +1403,8 @@ class PredictionServer(HTTPServerBase):
                     # key-protected with a different key: let the bind
                     # retry surface EADDRINUSE
                     pass
+                else:
+                    self._await_release(host, self.port)
         port = super().start(background)
         from predictionio_tpu.resilience.watchdog import watchdog
         watchdog().attach_guard(self._pressure)
@@ -2045,26 +2059,24 @@ def install_signal_handlers(server, on_stopped=None) -> None:
         signal.signal(sig, _handle)
 
 
+def _plans_of(dep: _Deployment):
+    """(algorithm, plan) for every top-k plan the deployment's
+    algorithms warmed (`Algorithm.serve_plans`)."""
+    return [(algo, plan) for algo in dep.algos
+            for plan in algo.serve_plans()]
+
+
 def _serve_plans(dep: _Deployment) -> List[Dict[str, Any]]:
     """What the deploy warm-up built, per algorithm: the plan class,
     its shard count, and for every warmed batch bucket which kernel
     serves it ("fused": the single-launch Pallas kernel, "xla": the AOT
     XLA chain)."""
-    out = []
-    for holder in list(dep.algos) + list(dep.models):
-        plan = getattr(holder, "_serve_plan", None)
-        if plan is None:
-            continue
-        kernels = getattr(plan, "bucket_kernels", None)
-        out.append({
-            "algorithm": type(holder).__name__,
-            "plan": type(plan).__name__,
-            "shards": int(getattr(plan, "n_shards", 1)),  # lint: ok — host int
-            "buckets": ({str(b): k for b, k in kernels().items()}
-                        if kernels is not None
-                        else {str(b): "xla" for b in plan.buckets}),
-        })
-    return out
+    return [{"algorithm": type(algo).__name__,
+             "plan": type(plan).__name__,
+             "shards": int(getattr(plan, "n_shards", 1)),  # lint: ok — host int
+             "buckets": {str(b): kernel
+                         for b, kernel in plan.bucket_kernels().items()}}
+            for algo, plan in _plans_of(dep)]
 
 
 def _gen_pr_id() -> str:

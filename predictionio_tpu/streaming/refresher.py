@@ -244,10 +244,9 @@ class Refresher:
                 continue
             new_models[i] = new_model
             folded = True
-            plan = getattr(algo, "_serve_plan", None)
             factors = getattr(new_model, "item_factors", None)
-            if plan is not None and factors is not None:
-                swaps.append((plan, factors))
+            if factors is not None:
+                swaps += [(plan, factors) for plan in algo.serve_plans()]
         if not folded:
             return "no_hooks"
         self._m["folded"].labels(side="user").inc(
@@ -291,14 +290,14 @@ class Refresher:
         done, rewarm = [], []
         try:
             for algo, model in zip(dep.algos, new_models):
-                plan = getattr(algo, "_serve_plan", None)
                 factors = getattr(model, "item_factors", None)
-                if plan is None or factors is None:
+                if factors is None:
                     continue
-                if factors.shape == (plan.n_items, plan.rank):
+                for plan in algo.serve_plans():
+                    if factors.shape != (plan.n_items, plan.rank):
+                        rewarm.append((algo, model))
+                        break
                     done.append((plan, plan.swap_factors(factors)))
-                else:
-                    rewarm.append((algo, model))
             if rewarm:
                 # shape changed (catalog grew): recompile is unavoidable.
                 # Same mesh derivation and batch buckets as deploy time

@@ -519,6 +519,28 @@ def test_queries_through_the_server_compile_nothing_and_tile_the_cycle(
         assert get_registry().histogram(name).labels().count > n0, name
 
 
+def test_status_and_the_plan_gauge_show_the_sequence_templates_plan(served):
+    """The sequence template keeps (model, encoder, plan) where the
+    others keep `_serve_plan`; what looks at a deployment from outside
+    asks `Algorithm.serve_plans()` and so sees it too."""
+    import urllib.request
+    srv, _, _ = served
+    algo, model = srv._dep.algos[0], srv._dep.models[0]
+    (plan,) = algo.serve_plans()
+    assert plan is algo._plans(model)[1]
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{srv.port}/status.json") as resp:
+        (shown,) = json.loads(resp.read())["servePlans"]
+    assert shown["algorithm"] == "SeqRecAlgorithm"
+    assert shown["plan"] == "BucketedTopK" and shown["shards"] == 1
+    assert shown["buckets"] == {str(b): "xla" for b in (1, 2, 4, 8)}
+    srv._sample_plan_bytes()
+    device = next(iter(plan.factors.devices()))
+    assert srv.metrics.value(
+        "pio_plan_resident_bytes", device=f"{device.platform}:{device.id}",
+        bucket="factors") == CFG.vocab * CFG.hidden * 4
+
+
 def test_a_user_without_history_gets_an_empty_reply(served):
     srv, _, _ = served
     status, reply = call(srv.port, "POST", "/queries.json",

@@ -157,6 +157,7 @@ def test_sharded_fused_topk_reads_each_shard_where_it_lies(
     plan.per_shard, plan.n_items = per, per * n_shards - 3
     plan.k = plan.k_shard = 10
     plan.banned_width = 64
+    plan._fused_sizes = set()
 
     def sds(shape, dtype, spec):
         return jax.ShapeDtypeStruct(shape, dtype,
@@ -166,7 +167,7 @@ def test_sharded_fused_topk_reads_each_shard_where_it_lies(
         sds((bucket, rank), jnp.float32, P()),
         sds((per * n_shards, rank), jnp.float32, P(SHARD_AXIS, None)),
         sds((bucket, 64), jnp.int32, P())).compile()
-    assert plan.fused
+    assert plan.fused_buckets == 1
     _assert_reads_the_catalog_where_it_lies(compiled, per, rank)
 
 

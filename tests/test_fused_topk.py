@@ -218,12 +218,12 @@ class TestShardedParity:
         chain = ShardedBucketedTopK(factors, k=6, buckets=(1, 2, 4, 8),
                                     banned_width=16, mesh=_mesh())
         chain.warm()
-        assert not chain.fused
+        assert chain.fused_buckets == 0
         monkeypatch.setenv("PIO_SERVE_FUSED", "on")
         fused = ShardedBucketedTopK(factors, k=6, buckets=(1, 2, 4, 8),
                                     banned_width=16, mesh=_mesh())
         fused.warm()
-        assert fused.fused
+        assert fused.fused_buckets
         return chain, fused
 
     def test_bit_identical_on_8_device_mesh(self, plans):
@@ -387,7 +387,7 @@ class TestGatedMerge:
             bans = [list(range(per)), list(range(per + 3, 2 * per))]
         chain, fused = _both(ShardedBucketedTopK, factors, monkeypatch,
                              k=k, bucket=8, width=width, mesh=_mesh())
-        assert fused.fused and not chain.fused
+        assert fused.fused_buckets and not chain.fused_buckets
         cs, ci = chain(vecs, bans)
         fs, fi = fused(vecs, bans)
         np.testing.assert_array_equal(ci, fi)

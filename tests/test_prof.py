@@ -132,6 +132,8 @@ class TestSamplingProfiler:
             r["samples"] for r in snap["roles"].values()) > 0
 
     def test_collapsed_stack_format(self):
+        from predictionio_tpu.obs.profiler import _ROLE_PREFIXES
+        roles = {role for _, role in _ROLE_PREFIXES} | {"other"}
         prof = SamplingProfiler(hz=0, max_nodes=256)
         prof.sample_once()
         out = prof.collapsed()
@@ -139,9 +141,9 @@ class TestSamplingProfiler:
         for line in out.strip().splitlines():
             path, _, count = line.rpartition(" ")
             assert int(count) >= 1
-            assert path.split(";")[0] in (
-                "main", "other", "obs", "http", "worker", "reactor",
-                "drainer", "refresher", "heartbeat")
+            # any role the profiler knows: other tests of this xdist
+            # worker may have left a joiner or a supervisor alive
+            assert path.split(";")[0] in roles
         # the sampling frame itself must be on some path
         assert "profiler.py:sample_once" in out
 

@@ -238,7 +238,7 @@ class TestShardedSimilar:
 class TestPlanSelection:
     def test_no_mesh_builds_single_device(self, factors_203):
         plan = serve_plan(factors_203, k=6, buckets=(1,), mesh=None)
-        assert isinstance(plan, topk.BucketedTopK)
+        assert type(plan) is topk.BucketedTopK
 
     def test_forced_mesh_builds_sharded(self, factors_203):
         sm = ServeMesh(_mesh(), forced=True)
@@ -253,7 +253,7 @@ class TestPlanSelection:
         # capacity unknown (CPU reports nothing) -> single-device
         monkeypatch.delenv("PIO_DEVICE_HBM_BYTES", raising=False)
         plan = serve_plan(factors_203, k=6, buckets=(1,), mesh=sm)
-        assert isinstance(plan, topk.BucketedTopK)
+        assert type(plan) is topk.BucketedTopK
         # 203*8*4 = 6496 bytes of factors; a 4 KiB "HBM" overflows
         monkeypatch.setenv("PIO_DEVICE_HBM_BYTES", "4096")
         plan = serve_plan(factors_203, k=6, buckets=(1,), mesh=sm)
@@ -379,8 +379,8 @@ class TestShardedDeployE2E:
         registry, engine = trained_rec
         srv = self._start(registry, engine)
         try:
-            assert isinstance(srv._dep.algos[0]._serve_plan,
-                              topk.BucketedTopK)
+            assert type(srv._dep.algos[0]._serve_plan) \
+                is topk.BucketedTopK
         finally:
             srv.shutdown()
 

@@ -598,6 +598,19 @@ def _run_sharded(n=3):
     return srv, t
 
 
+def _settled(srv, responses, timeout=5.0):
+    """The wire's snapshot once it counts `responses`: a reply's last
+    byte reaches the client before its reactor counts it as sent
+    (`_mark_sent` follows the send), so a count read right after the
+    client's read can be one short."""
+    deadline = time.monotonic() + timeout
+    while True:
+        snap = srv.stats_snapshot()
+        if snap["responses"] >= responses or time.monotonic() > deadline:
+            return snap
+        time.sleep(0.005)
+
+
 class TestShardedWire:
     def test_reuse_port_shards_keepalive_connections(self):
         if not hasattr(socket, "SO_REUSEPORT"):
@@ -613,7 +626,7 @@ class TestShardedWire:
                         status, body, _ = _read_response(f)
                         assert status == 200
                         assert body == b"POST /echo c%d-%d" % (i, j)
-            snap = srv.stats_snapshot()
+            snap = _settled(srv, 24)
             assert snap["reactor"] == -1      # the aggregate row
             assert snap["requests"] == 24 and snap["responses"] == 24
             assert snap["accepted"] == 12
@@ -636,7 +649,7 @@ class TestShardedWire:
                     status, body, _ = _read_response(f)
                     assert status == 200
                     assert body == b"POST /echo f%d" % i
-            snap = srv.stats_snapshot()
+            snap = _settled(srv, 12)
             assert snap["responses"] == 12
             # the deal is strict round-robin, so sequential connects
             # land a third on every reactor
