@@ -27,7 +27,7 @@ Background work (refresher ticks/fold-ins, rolling reloads) records
 spans through `background()` into the same ring with `kind=
 "background"`.
 
-Batch cycles: the micro-batcher's drainer owns one `BatchTrace` per
+Batch cycles: each of the micro-batcher's drainers owns one `BatchTrace` per
 cycle (one window, one take, one device call). `stage(name)` is the one
 instrument of that path: inside a cycle it stamps the interval into the
 record (from which `pio_serve_stage_seconds{stage=...}` is observed at
@@ -171,10 +171,10 @@ class BatchTrace:
     __slots__ = ("start", "dur", "t_begin", "t_last", "last", "closed",
                  "batch_id", "rows", "bucket", "path", "hist", "solo")
 
-    def __init__(self, hist=None, t0: float = 0.0, solo: bool = False):
+    def __init__(self, hist=None, solo: bool = False):
         self.start = [0.0] * len(STAGES)
         self.dur = [0.0] * len(STAGES)
-        self.t_begin = self.t_last = t0 if t0 > 0.0 else time.perf_counter()
+        self.t_begin = self.t_last = time.perf_counter()
         self.last = -1           # the stage that closed last
         self.closed = 0          # how many closed so far
         self.batch_id = 0
@@ -791,12 +791,11 @@ def stage_close(handle: _Stage) -> None:
     handle.__exit__(None, None, None)
 
 
-def batch_begin(hist=None, t0: float = 0.0,
-                solo: bool = False) -> BatchTrace:
-    """Open this thread's cycle record. `t0` is where the cycle before
-    it ended (what `batch_end` returned), so that cycles tile the
-    drainer's life as stages tile a cycle."""
-    bt = BatchTrace(hist, t0, solo)
+def batch_begin(hist=None, solo: bool = False) -> BatchTrace:
+    """Open this thread's cycle record, now: a drainer calls it when
+    its turn to form comes and its window opens, so stages tile a cycle
+    and the cycles of two drainers overlap."""
+    bt = BatchTrace(hist, solo)
     _TLS.batch = bt
     return bt
 

@@ -114,6 +114,12 @@ class _ServeInstruments:
             "pio_serve_batch_size",
             "Coalesced device batch size per drain",
             buckets=BATCH_SIZE_BUCKETS)
+        self.cycles_in_flight = metrics.histogram(
+            "pio_serve_cycles_in_flight",
+            "Batch cycles between take and the end of wake, observed "
+            "at every take with the taken one counted (1 = serial, "
+            "2 = a batch was launched while one was in flight)",
+            buckets=(1.0, 2.0))
         self.queue_depth = metrics.gauge(
             "pio_serve_batch_queue_depth",
             "Requests waiting in the micro-batcher")
@@ -483,17 +489,31 @@ class _Deployment:
 class _MicroBatcher:
     """Coalesces concurrent requests into device batches.
 
-    Design: ONE drainer at a time (classic dynamic batching). A submit
-    either becomes the drainer (no drainer active) or just queues. The
-    drainer waits the batching window, takes EVERYTHING pending (up to
-    batch_max), processes it, and loops while more work queued up during
-    processing. Because processing happens while new requests
-    accumulate, batch sizes grow automatically under load until they
-    cross the device-dispatch threshold (`ops.topk.HOST_CROSSOVER_CELLS`)
-    — the r4 large-catalog bench measured the earlier
-    one-thread-per-window design serving 99% of a 512-request burst in
-    tiny HOST batches (concurrent GIL-bound numpy flushes) versus this
-    design reaching full device batches after the first drain.
+    Design: double-buffered dynamic batching. Up to TWO drainers live at
+    a time, each on its own thread running the whole cycle (window,
+    take, predict, encode, wake). At most one of them is FORMING, that
+    is between the opening of its window and its take, so batches form
+    as one stream under `_lock` and a row is in exactly one batch; at
+    most two cycles are IN FLIGHT, between take and the end of wake. As
+    soon as a drainer has taken, the other may open its window: the
+    next batch forms, and is launched, while one is on the device (its
+    call queues there behind the first's, and the first's unpack,
+    encode and wake run while it computes). Behind a cycle in flight a
+    window opens only once the lane holds as many rows as that cycle
+    took (`_my_turn_locked`); otherwise it opens when that cycle ends
+    and its callers can come back, as the serial loop's would. A submit
+    starts a drainer when fewer than two are alive and none is forming;
+    a drainer whose window stays empty retires. The forming drainer
+    waits the batching window, takes EVERYTHING pending (up to
+    batch_max; a full batch ships at once) and processes it. Because
+    processing happens while new requests accumulate, batch sizes grow
+    automatically under load until they cross the device-dispatch
+    threshold
+    (`ops.topk.HOST_CROSSOVER_CELLS`) — the r4 large-catalog bench
+    measured the earlier one-thread-per-window design serving 99% of a
+    512-request burst in tiny HOST batches (concurrent GIL-bound numpy
+    flushes); two cycles keep that property, since whatever arrives
+    while both are in flight waits for the next window.
 
     Device compute always runs OUTSIDE the lock so a drain never stalls
     submitters.
@@ -503,8 +523,9 @@ class _MicroBatcher:
     submit waits with a TIMEOUT — the request deadline when one applies,
     else the `submit_timeout_s` backstop — so a wedged or crashed drainer
     turns into a 504, never a stranded handler thread. A drainer that
-    dies on an unexpected error fails every pending waiter and clears
-    the drain flag so the next submit starts a fresh one.
+    dies on an unexpected error fails its own taken batch, and every
+    pending waiter too when no other drainer is alive to serve them;
+    the next submit starts a fresh one.
 
     Adaptive shedding: every drained item's enqueue->drain latency
     lands in pio_queue_delay_seconds and an EWMA of it; a submit whose
@@ -538,6 +559,9 @@ class _MicroBatcher:
 
     # EWMA smoothing for the observed enqueue->drain latency
     DELAY_ALPHA = 0.2
+    # cycles between take and the end of wake, and so live drainers:
+    # one on the device and one formed behind it is double buffering
+    CYCLES = 2
 
     def __init__(self, window_s: float, batch_max: int,
                  obs: Optional[_ServeInstruments] = None,
@@ -565,18 +589,29 @@ class _MicroBatcher:
         # out the rest of the window; also signals close() waiters on
         # retire (predicate re-checked, spurious wakeups harmless)
         self._full = threading.Condition(self._lock)
+        # a drainer waits here for its turn to form (_my_turn_locked)
+        self._turn = threading.Condition(self._lock)
         # per-tenant DRR lanes; each item: (deployment, query, done
         # event, result slot, enqueue perf_counter, tenant label,
         # pending trace or None)
         self._queue = DRRQueue()
         # links every member trace of one drained batch (batch_id)
         self._batch_seq = itertools.count(1)
-        self._draining = False
+        # live drainers (0..CYCLES); of them at most one is forming
+        # (window open, not yet taken) and `_in_flight` hold a taken
+        # batch, of `_rows_in_flight` rows together, that has not
+        # finished its wake
+        self._draining = 0
+        self._forming = False
+        self._in_flight = 0
+        self._rows_in_flight = 0
         self._closed = False
         self._delay_ewma = 0.0
-        # EWMA of _process wall time — the deadline_batch admission
-        # check's estimate of "how long until a batch admitted now
-        # actually returns"
+        # EWMA of _process wall time, take to wake of ONE cycle — the
+        # deadline_batch admission check's estimate of "how long until
+        # a batch admitted now actually returns" (with two cycles in
+        # flight it includes the wait behind the other cycle's device
+        # call, which is what an admitted request will see)
         self._drain_ewma = 0.0
         # when the estimate last saw a real drain: the deadline check
         # ages the EWMA toward zero from here, so a one-off stall (a
@@ -587,11 +622,11 @@ class _MicroBatcher:
         # observed pow2 batch-size counts (≤ log2(batch_max) keys, so
         # bounded by construction); feeds warm_deploy bucket autotune
         self._size_counts: Dict[int, int] = {}
-        # the live drainer's watchdog beat (None while idle): a WEDGED
-        # drainer can't be killed or safely superseded (two drainers
-        # would race the queue), so the watchdog degrades the owner's
-        # /ready instead and the fleet routes around it
-        self._drain_beat = None
+        # every live drainer's watchdog beat (empty while idle): a
+        # WEDGED drainer can't be killed or safely superseded (its
+        # batch is taken), so the watchdog degrades the owner's /ready
+        # instead and the fleet routes around it
+        self._drain_beats: List[Any] = []
 
     def queue_delay_ewma(self) -> float:
         """Current smoothed enqueue->drain latency estimate (seconds)."""
@@ -620,6 +655,11 @@ class _MicroBatcher:
         if idle <= grace:
             return self._drain_ewma
         return self._drain_ewma * 0.5 ** ((idle - grace) / grace)
+
+    def drain_beats(self) -> List[Any]:
+        """The watchdog beats of the drainers alive now."""
+        with self._lock:
+            return list(self._drain_beats)
 
     def size_counts(self) -> Dict[int, int]:
         """Observed batch sizes, rounded up to pow2 -> drain count."""
@@ -717,9 +757,16 @@ class _MicroBatcher:
             self.obs.queue_depth.set(float(len(self._queue)))
             if len(self._queue) >= self.batch_max:
                 self._full.notify()
-            drain = not self._draining
+            if self._rows_in_flight and not self._forming:
+                # a drainer may be waiting for the lane to reach the
+                # rows of the cycle in flight (_my_turn_locked)
+                self._turn.notify()
+            # a second drainer forms the next batch while the first's
+            # is on the device; never a third, and none beside one
+            # whose window is open (it will take this item)
+            drain = self._draining < self.CYCLES and not self._forming
             if drain:
-                self._draining = True
+                self._draining += 1
         if drain:
             threading.Thread(target=self._drain_loop, daemon=True,
                              name="pio-batch-drain").start()
@@ -741,6 +788,22 @@ class _MicroBatcher:
             raise slot["error"]
         return slot
 
+    def _my_turn_locked(self) -> bool:
+        """May a drainer open its window now? Not while the other's is
+        open; and behind a cycle in flight only once the lane holds as
+        many rows as that cycle took (or a full batch). A smaller batch
+        launched behind a larger one fragments the stream: each call
+        reads the whole catalog, or pads its tokens, for fewer rows, and
+        the callers it would have batched with are inside the cycle in
+        flight and come back when it ends. Then this drainer's turn
+        comes with them, and its window is the one the serial loop
+        would have opened. Both sides of the comparison are what the
+        batcher sees under its lock; nothing here is set by a user."""
+        if self._forming:
+            return False
+        return len(self._queue) >= min(self._rows_in_flight,
+                                       self.batch_max)
+
     def _drain_loop(self):
         batch: List[tuple] = []
         from predictionio_tpu.resilience.watchdog import watchdog
@@ -750,18 +813,25 @@ class _MicroBatcher:
         wd_beat = watchdog().register("drainer",
                                       budget_s=self.submit_timeout_s)
         wd_beat.attach()
-        self._drain_beat = wd_beat
-        # one record a cycle (obs/trace.BatchTrace); each opens where
-        # the one before it closed, so cycles tile the drainer's life
-        # as stages tile a cycle
+        with self._lock:
+            self._drain_beats.append(wd_beat)
+        # one record a cycle (obs/trace.BatchTrace), kept by the thread:
+        # it opens with the cycle's window, so stages tile a cycle and
+        # the two drainers' cycles overlap; a drainer's wait for its
+        # turn belongs to no cycle, as an empty window belongs to none
         stages = self.obs.cycle_stages
-        t_end = 0.0
+        forming = False
+        flying = 0                   # rows of this drainer's taken batch
         try:
             while True:
                 wd_beat.tick()
-                bt = trace.batch_begin(stages, t_end)
-                h = trace.stage_open("window")
                 with self._lock:
+                    while not self._my_turn_locked():
+                        self._turn.wait(self.submit_timeout_s)
+                        wd_beat.tick()
+                    self._forming = forming = True
+                    bt = trace.batch_begin(stages)
+                    h = trace.stage_open("window")
                     # wait out the window — but a full batch forming
                     # mid-window notifies the condition and ships NOW
                     self._full.wait_for(
@@ -770,18 +840,25 @@ class _MicroBatcher:
                     trace.stage_close(h)
                     h = trace.stage_open("take")
                     batch = self._queue.take(self.batch_max)
+                    self._forming = forming = False
+                    self._turn.notify_all()
                     self.obs.queue_depth.set(float(len(self._queue)))
                     if not batch:
                         # nothing arrived during the window: retire. The
-                        # flag is cleared under the same lock any submit
+                        # count falls under the same lock any submit
                         # checks, so the next arrival starts a fresh
                         # drainer; close() waiters re-check now. The
                         # empty window is no cycle and is not observed.
                         trace.stage_close(h)
                         trace.batch_drop()
-                        self._draining = False
+                        self._draining -= 1
                         self._full.notify_all()
                         return
+                    self._in_flight += 1
+                    flying = len(batch)
+                    self._rows_in_flight += flying
+                    self.obs.cycles_in_flight.observe(
+                        float(self._in_flight))  # lint: ok (host int)
                     now = time.perf_counter()
                     for _, _, _, _, t_enq, tenant, pend in batch:
                         delay = max(now - t_enq, 0.0)
@@ -796,8 +873,12 @@ class _MicroBatcher:
                 t0 = time.perf_counter()
                 self._process(batch, bt)
                 dt = time.perf_counter() - t0
-                t_end = trace.batch_end(bt)
+                trace.batch_end(bt)
                 with self._lock:
+                    self._in_flight -= 1
+                    self._rows_in_flight -= flying
+                    flying = 0
+                    self._turn.notify_all()
                     # blend into the AGED estimate: recovering from a
                     # stall starts from the decayed value instead of
                     # dragging the stale spike back in
@@ -807,15 +888,23 @@ class _MicroBatcher:
                     self._drain_t = time.perf_counter()
                 batch = []
         except BaseException as e:
-            # drainer crash: fail every waiter NOW — the dequeued batch
-            # and everything still pending — instead of leaving them to
-            # their timeouts, and clear the flag so the next submit
-            # spawns a healthy drainer
+            # drainer crash: fail its own batch NOW and, when no other
+            # drainer is alive to serve them, everything still pending,
+            # instead of leaving them to their timeouts; the count
+            # falls so the next submit spawns a healthy drainer
             with self._lock:
-                stranded = batch + self._queue.drain_all()
-                self._draining = False
+                if forming:
+                    self._forming = False
+                if flying:
+                    self._in_flight -= 1
+                    self._rows_in_flight -= flying
+                self._draining -= 1
+                stranded = batch
+                if self._draining == 0:
+                    stranded = batch + self._queue.drain_all()
+                    self.obs.queue_depth.set(0.0)
+                self._turn.notify_all()
                 self._full.notify_all()
-                self.obs.queue_depth.set(0.0)
             for _, _, done, slot, _, _, _ in stranded:
                 slot["error"] = e
                 done.set()
@@ -826,8 +915,8 @@ class _MicroBatcher:
                        stranded=len(stranded))
         finally:
             wd_beat.close()
-            if self._drain_beat is wd_beat:
-                self._drain_beat = None
+            with self._lock:
+                self._drain_beats.remove(wd_beat)
 
     def close(self, timeout: float = 30.0) -> bool:
         """Stop admitting (new submits shed with 503) and wait for
@@ -837,7 +926,7 @@ class _MicroBatcher:
         with self._lock:
             self._closed = True
             return self._full.wait_for(
-                lambda: not len(self._queue) and not self._draining,
+                lambda: not len(self._queue) and self._draining == 0,
                 timeout=timeout)
 
     def reopen(self) -> None:
@@ -1280,6 +1369,21 @@ class PredictionServer(HTTPServerBase):
         except OSError:
             pass                         # persistence is best-effort
 
+    def _wire_cover(self) -> int:
+        """With a batcher a wire worker sleeps in `submit_slot` until
+        its batch wakes, so the pool covers what the batcher admits:
+        what may be pending plus what may be in flight. A request that
+        waited for a worker would wait where `queue_max`, the DRR lanes
+        and the queue-delay shedder cannot see it, and where the
+        forming batch cannot take it."""
+        b = self._batcher
+        if b is None:
+            return 0
+        cover = b.queue_max + b.CYCLES * b.batch_max
+        if self.config.max_inflight > 0:
+            cover = min(cover, self.config.max_inflight)
+        return cover
+
     def _own_beats(self):
         """The watchdog beats whose degradation should flip THIS
         server's /ready (never another server's beats in the shared
@@ -1292,7 +1396,7 @@ class PredictionServer(HTTPServerBase):
         if self._fsck_sched is not None:
             beats.append(self._fsck_sched.beat)
         if self._batcher is not None:
-            beats.append(self._batcher._drain_beat)
+            beats.extend(self._batcher.drain_beats())
         if self._pager is not None:
             beats.append(self._pager.beat)
         beats.append(self._feedback_beat)

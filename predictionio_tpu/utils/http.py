@@ -64,7 +64,7 @@ from predictionio_tpu.resilience import (
 )
 from predictionio_tpu.utils.wire import (
     RawRequest, SelectorWire, ShardedWire, build_response,
-    reactor_count, set_trace_hooks,
+    reactor_count, set_trace_hooks, worker_count,
 )
 
 _log = get_logger("http")
@@ -488,6 +488,13 @@ class HTTPServerBase:
                 **{"Retry-After": str(max(1, round(e.retry_after)))})
 
     # -- selector-wire raw path ---------------------------------------------
+    def _wire_cover(self) -> int:
+        """How many requests this server's own admission layer lets
+        wait or run at once, for the wire to size its handler pool by
+        (`utils/wire.worker_count`); 0 = it has no such number and the
+        pool goes by the core count."""
+        return 0
+
     def _handle_raw(self, raw: RawRequest) -> Tuple[bytes, bool]:
         """The selector wire's single entry point: try the fast-route
         table on the raw frame, else materialize a full Request and run
@@ -642,11 +649,13 @@ class HTTPServerBase:
                 # unavailable); at 1 the single-reactor wire is used
                 # unchanged.
                 n = reactor_count()
+                workers = worker_count(self._wire_cover())
                 if n > 1:
                     return ShardedWire((self.host, self.port),
-                                       self._handle_raw, reactors=n)
+                                       self._handle_raw, reactors=n,
+                                       workers=workers)
                 return SelectorWire((self.host, self.port),
-                                    self._handle_raw)
+                                    self._handle_raw, workers=workers)
             return _Server((self.host, self.port), _Handler)
 
         # 3-attempt bind with backoff (the reference retries Http.Bind

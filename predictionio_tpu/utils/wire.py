@@ -138,15 +138,27 @@ def reactor_count() -> int:
     return max(1, min(4, os.cpu_count() or 1))
 
 
-def _default_workers() -> int:
-    # Workers BLOCK in the handler (device step, store reads), they are
-    # not CPU-bound — size the pool to cover the admission layer's
-    # concurrency, not the core count, or overload queues invisibly at
-    # the wire instead of shedding 429/503 with Retry-After at the app
-    # layer.
-    return int(os.environ.get(
-        "PIO_WIRE_WORKERS",
-        str(max(16, min(64, 4 * (os.cpu_count() or 4))))))
+def worker_count(cover: int = 0) -> int:
+    """The handler pool's size: `PIO_WIRE_WORKERS` if set; else the
+    larger of `cover`, the concurrency of the server's own admission
+    layer where it has one and says so (a PredictionServer with a
+    micro-batcher: what may be pending plus what may be in flight), and
+    the pool by the core count."""
+    # Workers BLOCK in the handler (device step, store reads, the wait
+    # for a batch), they are not CPU-bound and cost a stack each. A
+    # request that waits for a worker waits in `_workq`, where no
+    # admission layer sees it: a pool smaller than what that layer
+    # admits queues overload invisibly at the wire instead of shedding
+    # 429/503 with Retry-After at the app layer, and hides waiting
+    # requests from the batcher that could have batched them. A server
+    # that knows its admission layer's concurrency hands it in as
+    # `cover`; one that does not (event server, admin, dashboard, a
+    # PredictionServer without a batcher) gets a pool by cores with a
+    # floor of 16.
+    raw = os.environ.get("PIO_WIRE_WORKERS", "").strip()
+    if raw:
+        return int(raw)
+    return max(cover, 16, min(64, 4 * (os.cpu_count() or 4)))
 
 
 def _bind_listener(server_address: Tuple[str, int],
@@ -491,7 +503,7 @@ class WireStats:
 
     __slots__ = ("accepted", "requests", "bytes_in", "pipeline_hwm",
                  "errors", "lock", "bytes_out", "responses",
-                 "send_failures", "busy_workers", "flushes")
+                 "send_failures", "busy_workers", "flushes", "served")
 
     def __init__(self):
         self.accepted = 0
@@ -505,6 +517,7 @@ class WireStats:
         self.send_failures = 0
         self.busy_workers = 0
         self.flushes = 0
+        self.served = 0                 # connections a worker finished
 
 
 class SelectorWire:
@@ -538,11 +551,12 @@ class SelectorWire:
         self.stats = WireStats()
         self.beat = None                # watchdog stamp (serve_forever)
         if workers <= 0:
-            workers = _default_workers()
+            workers = worker_count()
         self._n_workers = max(1, workers)
         import queue as _queue
         self._workq: "_queue.Queue" = _queue.Queue()
         self._workers: List[threading.Thread] = []
+        self._enqueued = 0              # connections put on _workq
         # bind in the constructor so the caller's EADDRINUSE retry loop
         # wraps construction, exactly as with ThreadingHTTPServer
         if listener is None and server_address is not None:
@@ -558,11 +572,6 @@ class SelectorWire:
 
     # -- reactor -------------------------------------------------------------
     def serve_forever(self) -> None:
-        for i in range(self._n_workers):
-            t = threading.Thread(target=self._worker_loop, daemon=True,
-                                 name=f"wire-{self.index}-worker-{i}")
-            t.start()
-            self._workers.append(t)
         sel = self._sel
         if self._listener is not None:
             sel.register(self._listener, selectors.EVENT_READ, "accept")
@@ -711,6 +720,25 @@ class SelectorWire:
                 if not conn.busy and conn.pending:
                     conn.busy = True
                     self._workq.put(conn)
+                    self._grow_pool()
+
+    def _grow_pool(self) -> None:
+        """Start one more worker when more connections are queued or
+        being served than there are workers, up to the pool's size. The
+        pool covers what the server admits (hundreds, with a batcher)
+        and a worker is a thread: they start as the load asks for them,
+        not all of them before the first accept. Reactor thread only,
+        after a put; `served` is read without its lock, and a stale
+        reading starts a worker too many at worst."""
+        self._enqueued += 1
+        n = len(self._workers)
+        if n < self._n_workers and \
+                self._enqueued - self.stats.served > n:
+            t = threading.Thread(target=self._worker_loop, daemon=True,
+                                 name=f"wire-{self.index}-worker-{n}")
+            with self._lifecycle:
+                self._workers.append(t)
+            t.start()
 
     def _sweep_idle(self, now: float) -> None:
         for conn in list(self._conns.values()):
@@ -773,6 +801,7 @@ class SelectorWire:
             finally:
                 with st.lock:
                     st.busy_workers -= 1
+                    st.served += 1
 
     def _service(self, conn: _Conn) -> None:
         """Serve this connection's framed requests in order; the busy
@@ -998,7 +1027,7 @@ class ShardedWire:
                  workers: int = 0):
         n = max(1, reactors if reactors > 0 else reactor_count())
         if workers <= 0:
-            workers = _default_workers()
+            workers = worker_count()
         per = max(1, -(-workers // n))     # ceil-divided pool slice
         listeners: List[Optional[socket.socket]] = []
         self.reuse_port = False

@@ -10,8 +10,11 @@ import numpy as np
 import pytest
 
 from predictionio_tpu.obs import compile_watch, get_registry
+from predictionio_tpu.obs.metrics import MetricsRegistry
 from predictionio_tpu.ops import topk
-from predictionio_tpu.serving.server import _Deployment, _MicroBatcher
+from predictionio_tpu.serving.server import (
+    _Deployment, _MicroBatcher, _ServeInstruments,
+)
 
 
 def _host_reference(vecs, factors, banned_lists, k):
@@ -228,6 +231,338 @@ class TestDrainerWakeup:
     def test_partial_batch_still_drains_after_window(self):
         mb = _MicroBatcher(window_s=0.02, batch_max=64)
         assert mb.submit(_InstantDep(), "solo") == "r:solo"
+
+
+class _Crash(BaseException):
+    """Passes `_process`'s `except Exception` and kills the drainer."""
+
+
+class _GatedDep:
+    """Stands for the device: every `predict_batch` call waits for its
+    own gate, so a test decides which cycle finishes when."""
+    query_class = None
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.calls = []               # (queries, gate), in entry order
+        self.entered = threading.Semaphore(0)
+        self.active = self.max_active = 0
+
+    def predict_batch(self, queries):
+        gate = threading.Event()
+        with self.lock:
+            self.calls.append((list(queries), gate))
+            self.active += 1
+            self.max_active = max(self.max_active, self.active)
+        self.entered.release()
+        try:
+            assert gate.wait(10.0), "the test never opened this gate"
+            if any(q.startswith("crash") for q in queries):
+                raise _Crash("drainer killed")
+            if any(q.startswith("bad") for q in queries):
+                raise ValueError("predict failed")
+            return [f"r:{q}" for q in queries]
+        finally:
+            with self.lock:
+                self.active -= 1
+
+    def wait_entered(self, n=1):
+        for _ in range(n):
+            assert self.entered.acquire(timeout=5.0), \
+                "predict_batch was not entered"
+
+    def open(self, i):
+        self.calls[i][1].set()
+
+
+def _wait_until(pred, timeout=5.0):
+    deadline = time.perf_counter() + timeout
+    while time.perf_counter() < deadline:
+        if pred():
+            return True
+        time.sleep(0.002)
+    return pred()
+
+
+class _Overlap:
+    """A batcher with two cycles blocked on the fake device: the first
+    holds q0 and q1, the second q2 and q3 (a batch goes behind a cycle
+    in flight once it has as many rows: `_my_turn_locked`)."""
+
+    def __init__(self, first=("q0", "q1"), second=("q2", "q3")):
+        self.reg = MetricsRegistry()
+        self.mb = _MicroBatcher(window_s=0.02, batch_max=4,
+                                obs=_ServeInstruments(self.reg))
+        self.dep = _GatedDep()
+        self.results = {}
+        self.threads = []
+        self.submit(*first)
+        self.dep.wait_entered()
+        if second:
+            self.submit(*second)
+            self.dep.wait_entered()
+
+    def submit(self, *queries):
+        def worker(q):
+            try:
+                self.results[q] = self.mb.submit(self.dep, q)
+            except BaseException as e:
+                self.results[q] = e
+        # one lane, one instant: the window batches them together
+        ts = [threading.Thread(target=worker, args=(q,), daemon=True)
+              for q in queries]
+        for t in ts:
+            t.start()
+        self.threads.extend(ts)
+        assert _wait_until(lambda: all(
+            any(q in c[0] for c in self.dep.calls) or
+            len(self.mb._queue) for q in queries))
+
+    def finish(self, *order):
+        for i in order:
+            self.dep.open(i)
+        for t in self.threads:
+            t.join(5.0)
+            assert not t.is_alive()
+
+    def hist(self, name):
+        return self.reg.histogram(name).labels()
+
+
+class TestDoubleBuffer:
+    """Two cycles in flight, one forming (`_MicroBatcher`)."""
+
+    def test_second_batch_launches_while_first_is_blocked(self):
+        o = _Overlap()
+        try:
+            assert [sorted(c[0]) for c in o.dep.calls] == [
+                ["q0", "q1"], ["q2", "q3"]]
+            assert o.dep.active == 2          # both inside predict_batch
+            assert o.mb._in_flight == 2 and o.mb._draining == 2
+            assert len(o.mb.drain_beats()) == 2
+        finally:
+            o.finish(0, 1)
+        assert o.results == {q: f"r:{q}" for q in ("q0", "q1", "q2", "q3")}
+
+    def test_never_a_third_cycle_and_the_lane_keeps_what_arrives(self):
+        o = _Overlap()
+        try:
+            o.submit("q4", "q5")
+            time.sleep(5 * o.mb.window_s)
+            with o.mb._lock:
+                assert (o.mb._in_flight, o.mb._draining) == (2, 2)
+                assert not o.mb._forming
+                assert len(o.mb._queue) == 2
+            assert len(o.dep.calls) == 2 and o.dep.max_active == 2
+            o.dep.open(1)                     # the second finishes ...
+            o.dep.wait_entered()              # ... and takes the lane
+            assert sorted(o.dep.calls[2][0]) == ["q4", "q5"]
+        finally:
+            o.finish(0, 1, 2)
+        assert o.results["q4"] == "r:q4" and o.results["q5"] == "r:q5"
+        assert o.dep.max_active == 2
+
+    def test_never_two_windows_open_at_once(self):
+        mb = _MicroBatcher(window_s=0.002, batch_max=4,
+                           obs=_ServeInstruments(MetricsRegistry()))
+        opened, worst, flying = [0], [0], [0]
+        wait_for = mb._full.wait_for
+
+        def window(pred, timeout=None):
+            # the condition releases the lock while it waits: a second
+            # forming drainer would be inside at the same time
+            opened[0] += 1
+            worst[0] = max(worst[0], opened[0])
+            flying[0] = max(flying[0], mb._in_flight, mb._draining)
+            try:
+                return wait_for(pred, timeout=timeout)
+            finally:
+                opened[0] -= 1
+        mb._full.wait_for = window
+
+        class _Slow:
+            query_class = None
+
+            def predict_batch(self, queries):
+                time.sleep(0.003)
+                return [f"r:{q}" for q in queries]
+        dep, out = _Slow(), {}
+
+        def caller(c):
+            for i in range(25):
+                out[(c, i)] = mb.submit(dep, f"{c}.{i}")
+        threads = [threading.Thread(target=caller, args=(c,))
+                   for c in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(20.0)
+        assert out == {(c, i): f"r:{c}.{i}"
+                       for c in range(12) for i in range(25)}
+        assert worst[0] == 1
+        assert flying[0] <= 2
+        assert mb.close(timeout=5.0)
+
+    def test_a_smaller_batch_waits_for_the_cycle_in_flight_to_end(self):
+        o = _Overlap(("q0", "q1", "q2"), None)
+        o.submit("q3")                        # starts the second drainer
+        time.sleep(5 * o.mb.window_s)
+        with o.mb._lock:
+            assert (o.mb._in_flight, o.mb._draining) == (1, 2)
+            assert not o.mb._forming and len(o.mb._queue) == 1
+        assert len(o.dep.calls) == 1
+        o.dep.open(0)                         # its callers could come back
+        o.dep.wait_entered()
+        assert o.dep.calls[1][0] == ["q3"]
+        o.finish(1)
+        assert o.results == {q: f"r:{q}" for q in ("q0", "q1", "q2", "q3")}
+        h = o.hist("pio_serve_cycles_in_flight")
+        assert (h.count, h.sum) == (2, 2.0)   # serial, both times
+
+    @pytest.mark.parametrize("first,rest", [
+        (("q0", "q1"), ("q3",)),
+        (("q0", "q1", "q2", "q3", "q4"), ("q6", "q7"))])
+    def test_the_held_drainer_ships_once_the_lane_has_as_many_rows(
+            self, first, rest):
+        # batch_max is 4: five submits make a first cycle of 4 and leave
+        # one in the lane; a full batch never waits for more
+        o = _Overlap(first[:4], None)
+        held = first[4:] + ("q5",)
+        o.submit(*held)
+        time.sleep(3 * o.mb.window_s)
+        assert len(o.dep.calls) == 1          # fewer rows than in flight
+        o.submit(*rest)
+        o.dep.wait_entered()                  # the first still blocked
+        assert sorted(o.dep.calls[1][0]) == sorted(held + rest)
+        assert o.mb._in_flight == 2
+        o.finish(0, 1)
+        assert all(o.results[q] == f"r:{q}" for q in first + held + rest)
+
+    def test_rows_keep_their_results_when_the_second_finishes_first(self):
+        o = _Overlap()
+        o.dep.open(1)
+        assert _wait_until(lambda: len(o.results) == 2)
+        # the first still blocked
+        assert o.results == {"q2": "r:q2", "q3": "r:q3"}
+        assert _wait_until(lambda: o.mb._in_flight == 1)
+        o.finish(0)
+        assert o.results == {q: f"r:{q}" for q in ("q0", "q1", "q2", "q3")}
+
+    @pytest.mark.parametrize("victim", [0, 1])
+    def test_a_failed_predict_fails_its_own_cycle_only(self, victim):
+        first, second = ("q0", "q1"), ("q2", "q3")
+        if victim == 0:
+            first = ("bad0", "bad1")
+        else:
+            second = ("bad2", "bad3")
+        o = _Overlap(first, second)
+        o.finish(victim, 1 - victim)
+        for q in first + second:
+            if q.startswith("bad"):
+                assert isinstance(o.results[q], ValueError)
+            else:
+                assert o.results[q] == f"r:{q}"
+
+    @pytest.mark.parametrize("victim", [0, 1])
+    def test_a_dead_drainer_fails_its_batch_and_leaves_the_other(
+            self, victim):
+        first, second = ("q0", "q1"), ("q2", "q3")
+        if victim == 0:
+            first = ("crash0", "crash1")
+        else:
+            second = ("crash2", "crash3")
+        o = _Overlap(first, second)
+        o.submit("q4")                        # pending while one dies
+        o.dep.open(victim)
+        dead = first if victim == 0 else second
+        assert _wait_until(lambda: all(q in o.results for q in dead))
+        for q in dead:
+            assert isinstance(o.results[q], _Crash)
+        # the other drainer is alive: the lane was not failed with it
+        assert "q4" not in o.results
+        with o.mb._lock:
+            assert (o.mb._in_flight, o.mb._draining) == (1, 1)
+            assert o.mb._rows_in_flight == 2
+        assert _wait_until(lambda: len(o.mb.drain_beats()) == 1)
+        o.dep.open(1 - victim)
+        o.dep.wait_entered()                  # q4, by whichever drainer
+        o.finish(2)
+        for q in (second if victim == 0 else first) + ("q4",):
+            assert o.results[q] == f"r:{q}"
+        assert o.mb.close(timeout=5.0)
+
+    def test_the_last_drainer_to_die_fails_the_lane(self):
+        o = _Overlap(("crash0",), ("crash1",))
+        o.submit("q2")
+        o.finish(0, 1)
+        assert all(isinstance(o.results[q], _Crash)
+                   for q in ("crash0", "crash1", "q2"))
+        with o.mb._lock:
+            assert (o.mb._in_flight, o.mb._draining) == (0, 0)
+            assert o.mb._rows_in_flight == 0
+            assert not o.mb._forming and not len(o.mb._queue)
+        assert _wait_until(lambda: o.mb.drain_beats() == [])
+        o.submit("q3")                        # a fresh drainer serves it
+        o.dep.wait_entered()
+        o.finish(2)
+        assert o.results["q3"] == "r:q3"
+
+    def test_close_returns_only_when_both_cycles_are_done(self):
+        o = _Overlap()
+        assert not o.mb.close(timeout=0.1)
+        o.dep.open(1)
+        assert _wait_until(lambda: "q2" in o.results)
+        assert not o.mb.close(timeout=0.1)    # the first still holds rows
+        o.dep.open(0)
+        assert o.mb.close(timeout=5.0)
+        o.finish()
+        assert o.mb._draining == 0 and o.mb._in_flight == 0
+        assert o.results == {q: f"r:{q}" for q in ("q0", "q1", "q2", "q3")}
+
+    def test_both_drainers_retire_and_the_next_submit_starts_one(self):
+        o = _Overlap()
+        o.finish(0, 1)
+        assert _wait_until(lambda: o.mb._draining == 0)
+        assert _wait_until(lambda: o.mb.drain_beats() == [])
+        assert not o.mb._forming
+        o.submit("q4")
+        o.dep.wait_entered()
+        assert o.mb._draining == 1
+        o.finish(2)
+        assert o.results["q4"] == "r:q4"
+
+    def test_cycles_in_flight_counts_one_serial_and_two_overlapped(self):
+        o = _Overlap()
+        h = o.hist("pio_serve_cycles_in_flight")
+        assert (h.count, h.sum) == (2, 3.0)   # 1 at the first take, then 2
+        assert h.bucket_counts == [1, 1, 0]
+        o.finish(0, 1)
+        assert _wait_until(lambda: o.mb._draining == 0)
+        for q in ("s0", "s1", "s2"):          # one at a time: serial
+            o.submit(q)
+            o.dep.wait_entered()
+            o.dep.open(len(o.dep.calls) - 1)
+            assert _wait_until(lambda: q in o.results)
+        assert (h.count, h.sum) == (5, 6.0)
+        assert h.bucket_counts == [4, 1, 0]
+
+    def test_sizes_once_a_cycle_and_queue_delay_once_a_row(self):
+        o = _Overlap()
+        o.finish(1, 0)
+        assert o.mb.size_counts() == {2: 2}
+        sizes = o.hist("pio_serve_batch_size")
+        assert (sizes.count, sizes.sum) == (2, 4.0)
+        assert o.hist("pio_queue_delay_seconds").count == 4
+
+    def test_drain_estimate_is_one_cycle_take_to_wake(self):
+        o = _Overlap()
+        time.sleep(0.06)
+        o.dep.open(1)                         # the second: a short cycle
+        assert _wait_until(lambda: o.mb.drain_time_ewma() > 0.0)
+        short = o.mb.drain_time_ewma()
+        o.finish(0)                           # the first waited longer
+        assert _wait_until(lambda: o.mb.drain_time_ewma() > short)
+        assert 0.0 < short < o.mb.drain_time_ewma() < 5.0
 
 
 @pytest.fixture()

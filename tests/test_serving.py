@@ -322,6 +322,70 @@ class TestMicroBatch:
             srv.shutdown()
 
 
+def _pool(srv):
+    """The wire's handler pool as built (every reactor's slice)."""
+    return srv._httpd.stats_snapshot()["workers"]
+
+
+def _pool_of(workers):
+    """What a sharded wire makes of a pool: ceil-divided slices."""
+    from predictionio_tpu.utils.wire import reactor_count
+    n = reactor_count()
+    return n * -(-workers // n)
+
+
+class TestWirePoolCoversTheBatcher:
+    """A worker sleeps in the batcher until its batch wakes, so with a
+    batcher the pool is what the batcher admits: queue_max + 2 x
+    batch_max. Everything else keeps the pool by cores."""
+
+    @pytest.mark.parametrize("cfg,cover", [
+        (dict(batch_window_ms=5), 256 + 2 * 64),
+        (dict(batch_window_ms=5, queue_max=100, batch_max=32), 164),
+        (dict(batch_window_ms=5, max_inflight=200), 200),
+        (dict(batch_window_ms=5, max_inflight=1000), 384),
+        (dict(batch_window_ms=0), 0),
+        (dict(batch_window_ms=0, max_inflight=200), 0),
+    ])
+    def test_prediction_server(self, trained, monkeypatch, cfg, cover):
+        from predictionio_tpu.utils.wire import worker_count
+        monkeypatch.delenv("PIO_WIRE_WORKERS", raising=False)
+        registry, engine, _, _ = trained
+        srv = start_server(registry, engine, **cfg)
+        try:
+            assert srv._wire_cover() == cover
+            assert _pool(srv) == _pool_of(max(cover, worker_count()))
+            status, _ = call(srv.port, "POST", "/queries.json",
+                             {"user": "u1", "num": 2})
+            assert status == 200
+        finally:
+            srv.shutdown()
+
+    def test_event_server_keeps_the_pool_by_cores(self, mem_registry,
+                                                  monkeypatch):
+        from predictionio_tpu.utils.wire import worker_count
+        monkeypatch.delenv("PIO_WIRE_WORKERS", raising=False)
+        es = EventServer(EventServerConfig(ip="127.0.0.1", port=0),
+                         mem_registry)
+        es.start()
+        try:
+            assert es._wire_cover() == 0
+            assert _pool(es) == _pool_of(worker_count())
+            assert worker_count() <= 64
+        finally:
+            es.shutdown()
+
+    @pytest.mark.parametrize("window_ms", [0, 5])
+    def test_env_wins_over_both(self, trained, monkeypatch, window_ms):
+        monkeypatch.setenv("PIO_WIRE_WORKERS", "6")
+        registry, engine, _, _ = trained
+        srv = start_server(registry, engine, batch_window_ms=window_ms)
+        try:
+            assert _pool(srv) == _pool_of(6)
+        finally:
+            srv.shutdown()
+
+
 class TestStagesWithoutABatcher:
     @pytest.mark.parametrize("window_ms", [0, 5],
                              ids=["unbatched", "batched"])

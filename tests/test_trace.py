@@ -683,7 +683,7 @@ class TestBatchCycle:
             if evs:
                 lines.append(evs)
         assert lines, "no pio:batch.* event in /host:CPU"
-        seen = set()
+        seen, cycles = set(), 0
         for evs in lines:
             seen |= {name for name, _, _ in evs}
             predicts = [(a, b) for name, a, b in evs
@@ -692,10 +692,15 @@ class TestBatchCycle:
                 if name.split(".", 1)[1] in _PREDICT_CHILDREN:
                     assert any(pa <= a and b <= pb for pa, pb in predicts), \
                         f"{name} outside every predict of its thread"
-            # a drainer's line holds whole cycles: as many takes as wakes
+            # a drainer's line holds whole cycles: as many takes as
+            # predicts, and one take more where it retired. The second
+            # drainer's may hold that empty window alone (started
+            # beside a cycle in flight, its turn came with the lane
+            # empty)
             n_take = sum(name == "pio:batch.take" for name, _, _ in evs)
-            n_pred = len(predicts)
-            assert n_take >= n_pred >= 1
+            assert n_take >= len(predicts)
+            cycles += len(predicts)
+        assert cycles >= 1
         assert seen == {"pio:batch." + n for n in _STAGES}
 
     def test_stage_outside_a_cycle_observes_no_histogram(self, trained):
@@ -943,12 +948,18 @@ class TestFleetStitching:
             assert status == 200
             deadline = time.perf_counter() + 5.0
             group = []
-            while time.perf_counter() < deadline and not group:
+
+            def joined():
+                return any(e["parent_id"] == parent_span for e in group)
+            # the trace's entries reach the ring one by one (the
+            # router's hop first): wait for the one that adopted the
+            # standby's span, not for the first of them
+            while time.perf_counter() < deadline and not joined():
                 group = trace.get_recorder().snapshot(trace_id=tid)
-                if not group:
+                if not joined():
                     time.sleep(0.01)
             assert group, "redirected request never joined the trace"
-            assert any(e["parent_id"] == parent_span for e in group)
+            assert joined()
         finally:
             standby.stop()
             leader.stop()

@@ -31,6 +31,7 @@ PR 13 layers on top of both:
 """
 
 import json
+import os
 import random
 import select
 import socket
@@ -200,6 +201,37 @@ def test_default_worker_pool_covers_admission_concurrency(monkeypatch):
     srv = SelectorWire(("127.0.0.1", 0), _echo)
     try:
         assert srv._n_workers >= 16
+    finally:
+        srv.server_close()
+
+
+@pytest.mark.parametrize("env,cover,expect", [
+    ("", 0, "cores"),            # no admission layer known: by cores
+    ("", 384, 384),              # a batcher's queue_max + 2 * batch_max
+    ("", 8, "cores"),            # never under the pool by cores
+    ("6", 0, 6),                 # PIO_WIRE_WORKERS wins over both
+    ("6", 384, 6),
+])
+def test_worker_count_env_then_cover_then_cores(monkeypatch, env, cover,
+                                                expect):
+    from predictionio_tpu.utils.wire import worker_count
+    if env:
+        monkeypatch.setenv("PIO_WIRE_WORKERS", env)
+    else:
+        monkeypatch.delenv("PIO_WIRE_WORKERS", raising=False)
+    cores = max(16, min(64, 4 * (os.cpu_count() or 4)))
+    assert worker_count(cover) == (cores if expect == "cores" else expect)
+
+
+def test_sharded_slice_covers_an_uneven_share_of_the_callers(monkeypatch):
+    """SO_REUSEPORT does not deal connections evenly: of a pool that
+    covers the batcher (384 at the defaults) one of four reactors gets
+    ceil(384 / 4) = 96 workers, three quarters of c128's 128 callers."""
+    monkeypatch.delenv("PIO_WIRE_WORKERS", raising=False)
+    srv = ShardedWire(("127.0.0.1", 0), _echo, reactors=4, workers=384)
+    try:
+        assert [r._n_workers for r in srv.reactors] == [96] * 4
+        assert srv.stats_snapshot()["workers"] == 384
     finally:
         srv.server_close()
 
@@ -455,6 +487,39 @@ def _stop_wire(srv, t):
     t.join(timeout=5)
 
 
+class TestPoolGrowsWithTheLoad:
+    """The pool's size is a cap: workers start when more connections
+    have work than there are workers, not all before the first accept
+    (a PredictionServer with a batcher asks for 384)."""
+
+    @pytest.mark.parametrize("conns,cap,expect", [
+        (1, 8, 1), (3, 8, 3), (5, 2, 2)])
+    def test_workers_start_as_connections_ask(self, conns, cap, expect):
+        srv, t = _run_wire(workers=cap)
+        try:
+            assert srv._workers == []
+            assert srv.stats_snapshot()["workers"] == cap
+            socks = [_connect(srv) for _ in range(conns)]
+            try:
+                for s in socks:               # all blocked in the handler
+                    s.sendall(_req(path="/slow", body=b"x"))
+                for s in socks:
+                    with s.makefile("rb") as f:
+                        status, body, _ = _read_response(f)
+                        assert status == 200 and body == b"POST /slow x"
+                assert len(srv._workers) == expect
+                for s in socks[:1]:           # an idle worker is reused
+                    s.sendall(_req(body=b"y"))
+                    with s.makefile("rb") as f:
+                        assert _read_response(f)[0] == 200
+                assert len(srv._workers) == expect
+            finally:
+                for s in socks:
+                    s.close()
+        finally:
+            _stop_wire(srv, t)
+
+
 class TestGatheredEgress:
     def test_pipelined_burst_coalesces_in_order(self):
         srv, t = _run_wire(workers=2, sendmsg=True)
@@ -467,7 +532,7 @@ class TestGatheredEgress:
                     status, body, _ = _read_response(f)
                     assert status == 200
                     assert body == b"POST /echo b%d" % i
-            snap = srv.stats_snapshot()
+            snap = _settled(srv, n)
             assert snap["responses"] == n
             # the burst left in gathered flushes, not one send each
             assert 0 < snap["flushes"] < n
@@ -485,7 +550,7 @@ class TestGatheredEgress:
                     status, body, _ = _read_response(f)
                     assert status == 200
                     assert body == b"POST /echo p%d" % i
-            snap = srv.stats_snapshot()
+            snap = _settled(srv, n)
             assert snap["responses"] == n
             assert snap["flushes"] >= n       # one syscall per response
         finally:
