@@ -1,7 +1,7 @@
 """The sequence recommender's backbone: a layer stack built from data.
 
 A backbone is a `BackboneConfig`: widths, a list of layers each made
-of one attention kind (`attn_full`, `attn_window`) and one
+of one mixer kind (`attn_full`, `attn_window`, `conv`) and one
 feed-forward kind (`ffn_dense`, `ffn_moe`), the norm, the positions,
 and, where experts are spread over chips, the share held here. The
 item catalog is the vocabulary and a user's event history the context.
@@ -14,18 +14,28 @@ Two families of configuration are built here:
   - `config_from_json`: a public architecture's language-model stack
     from its own `config.json` keys (window and full attention with
     different KV head counts, a learned sink a head in window layers,
-    rotary position on part of the head, RMSNorm, a dense SwiGLU and
-    sigmoid-routed experts), cut as the file says: `layer_ids` picks
-    layers of the published patterns, `n_routed_experts` and
+    rotary position on part or all of the head, RMSNorm of each head's
+    q and k, gated short-convolution mixers, RMSNorm, a dense SwiGLU
+    and sigmoid-routed experts), cut as the file says: `layer_ids`
+    picks layers of the published patterns, the experts' count and
     `expert_share` say which experts live here, `vocab_size` how many
-    rows of the vocabulary.
+    rows of the vocabulary. Two families' keys are read into the one
+    `BackboneConfig`, by the keys present: `hybrid_layer_pattern` /
+    `moe_layer_freq` / `n_routed_experts` / `layernorm_epsilon`, and
+    `layer_types` / `num_dense_layers` / `num_experts` / `norm_eps` /
+    `conv_L_cache`.
 
 `init_params` / `forward` are the one stack for all of them. The
-parameters are one pytree keyed by layer (`l0`, `l1`, ...). Attention
-itself is handed in (`attend`): training runs padded batches through
+parameters are one pytree keyed by layer (`l0`, `l1`, ...), a layer's
+mixer under `attn` or `conv`. Attention itself is handed in
+(`attend`): training runs padded batches through
 `ops.attention.ring_attention`, serving runs packed histories through
-`ops.attention.packed_attention`; everything else is position-wise
-and does not know which.
+`ops.attention.packed_attention`. The convolution mixer reads its
+neighbours along the token axis and keeps a tap only where
+`positions` (each event's index in its own history, which both
+layouts hand to `forward`) says the neighbour is of the same history
+(`conv_block`); everything else is position-wise and does not know
+which layout it runs in.
 """
 
 from __future__ import annotations
@@ -49,7 +59,7 @@ class BackboneConfig:
     name: str
     hidden: int
     vocab: int                       # rows of the item table held here
-    layers: Tuple[Tuple[str, str], ...]      # (attention, feed-forward)
+    layers: Tuple[Tuple[str, str], ...]      # (mixer, feed-forward)
     n_heads: int
     kv_heads_full: int
     kv_heads_window: int
@@ -77,6 +87,9 @@ class BackboneConfig:
     routed_scale: float = 1.0
     expert_first: int = 0            # held: first .. first + held - 1
     experts_held: int = 0
+    qk_norm: bool = False            # RMSNorm of each head's q and k
+    conv_kernel: int = 0             # taps of a `conv` mixer
+    route_eps: float = 0.0           # added to the routing normaliser
     # serving: the longest history read, the tokens of one call, and
     # the padded sizes a call is compiled for
     max_history: int = 0
@@ -111,6 +124,27 @@ def _pow2_buckets(lo: int, hi: int) -> Tuple[int, ...]:
     return tuple(out) + (1 << max(hi - 1, 0).bit_length(),)
 
 
+_MIXERS = {"full_attention": "attn_full", "conv": "conv"}
+
+
+def _layers_from_json(doc: Dict[str, Any], ids) -> Tuple:
+    """(mixer, feed-forward) of the layers `ids`, from whichever
+    family's pattern keys the file has."""
+    if "layer_types" in doc:
+        unknown = sorted({t for t in doc["layer_types"]
+                          if t not in _MIXERS})
+        if unknown:
+            raise ValueError(f"layer_types names {unknown}: the mixers "
+                             f"here are {sorted(_MIXERS)}")
+        dense = int(doc.get("num_dense_layers", 0))
+        return tuple((_MIXERS[doc["layer_types"][i]],
+                      "ffn_dense" if i < dense else "ffn_moe")
+                     for i in ids)
+    swa, sparse = doc["hybrid_layer_pattern"], doc["moe_layer_freq"]
+    return tuple(("attn_window" if swa[i] else "attn_full",
+                  "ffn_moe" if sparse[i] else "ffn_dense") for i in ids)
+
+
 def config_from_json(doc: Dict[str, Any], name: str = "") -> BackboneConfig:
     """A configuration file in the architecture's own `config.json`
     keys, with the cut beside them (module docstring)."""
@@ -118,25 +152,29 @@ def config_from_json(doc: Dict[str, Any], name: str = "") -> BackboneConfig:
                or range(int(doc["num_hidden_layers"])))
     if len(ids) != int(doc["num_hidden_layers"]):
         raise ValueError("layer_ids does not list num_hidden_layers ids")
-    swa, sparse = doc["hybrid_layer_pattern"], doc["moe_layer_freq"]
-    layers = tuple(("attn_window" if swa[i] else "attn_full",
-                    "ffn_moe" if sparse[i] else "ffn_dense") for i in ids)
+    layers = _layers_from_json(doc, ids)
     if doc.get("scoring_func", "sigmoid") != "sigmoid" \
-            or int(doc.get("n_group") or 1) != 1:
-        raise ValueError("the router here scores by sigmoid, one group")
+            or int(doc.get("n_group") or 1) != 1 \
+            or not doc.get("use_expert_bias", True):
+        raise ValueError("the router here scores by sigmoid, one group, "
+                         "and selects by score plus correction bias")
+    if doc.get("conv_bias"):
+        raise ValueError("the convolution mixer here has no bias")
     share = doc.get("expert_share") or {"index": 0, "count": 1}
-    held = int(doc["n_routed_experts"])
+    held = int(doc.get("n_routed_experts") or doc.get("num_experts") or 0)
     serving = doc.get("assumed") or {}
-    qk = int(doc["head_dim"])
+    heads = int(doc["num_attention_heads"])
+    qk = int(doc.get("head_dim") or int(doc["hidden_size"]) // heads)
     return BackboneConfig(
         name=name or str(doc.get("name", "")),
         hidden=int(doc["hidden_size"]), vocab=int(doc["vocab_size"]),
-        layers=layers, n_heads=int(doc["num_attention_heads"]),
+        layers=layers, n_heads=heads,
         kv_heads_full=int(doc["num_key_value_heads"]),
         kv_heads_window=int(doc.get("swa_num_key_value_heads")
                             or doc["num_key_value_heads"]),
         qk_dim=qk, v_dim=int(doc.get("v_head_dim") or qk),
-        eps=float(doc.get("layernorm_epsilon", 1e-5)),
+        eps=float(doc.get("layernorm_epsilon")
+                  or doc.get("norm_eps", 1e-5)),
         act=str(doc.get("hidden_act", "silu")),
         dense_width=int(doc["intermediate_size"]),
         window=int(doc.get("sliding_window") or 0),
@@ -155,6 +193,9 @@ def config_from_json(doc: Dict[str, Any], name: str = "") -> BackboneConfig:
         norm_topk_prob=bool(doc.get("norm_topk_prob", True)),
         routed_scale=float(doc.get("routed_scaling_factor") or 1.0),
         expert_first=int(share["index"]) * held, experts_held=held,
+        qk_norm=bool(doc.get("qk_norm")),
+        conv_kernel=int(doc.get("conv_L_cache") or 0),
+        route_eps=float(doc.get("route_norm_eps") or 0.0),
         max_history=int(serving.get("max_history", 0)),
         max_batch_tokens=int(serving.get("max_batch_tokens", 0)),
         token_buckets=tuple(int(b) for b in
@@ -194,12 +235,20 @@ def param_shapes(cfg: BackboneConfig) -> Dict[str, Any]:
     if not cfg.tied:
         p["head"] = (cfg.vocab, D)
     p["norm_f"] = dict(norm)
-    for li, (attn, ffn) in enumerate(cfg.layers):
-        hkv = cfg.kv_heads(attn)
-        a = {"wq": (D, H * cfg.qk_dim), "wk": (D, hkv * cfg.qk_dim),
-             "wv": (D, hkv * cfg.v_dim), "wo": (H * cfg.v_dim, D)}
-        if (cfg.sink_window if attn == "attn_window" else cfg.sink_full):
-            a["sink"] = (H,)
+    for li, (mixer, ffn) in enumerate(cfg.layers):
+        if mixer == "conv":
+            m = {"w_in": (D, 3 * D), "kernel": (cfg.conv_kernel, D),
+                 "w_out": (D, D)}
+        else:
+            hkv = cfg.kv_heads(mixer)
+            m = {"wq": (D, H * cfg.qk_dim), "wk": (D, hkv * cfg.qk_dim),
+                 "wv": (D, hkv * cfg.v_dim), "wo": (H * cfg.v_dim, D)}
+            if cfg.qk_norm:     # one gain vector, shared by the heads
+                m["q_norm"] = {"g": (cfg.qk_dim,)}
+                m["k_norm"] = {"g": (cfg.qk_dim,)}
+            if (cfg.sink_window if mixer == "attn_window"
+                    else cfg.sink_full):
+                m["sink"] = (H,)
         if ffn == "ffn_moe":
             E, F = cfg.experts_held, cfg.expert_width
             f = {"router": (D, cfg.n_experts), "bias": (cfg.n_experts,),
@@ -210,7 +259,8 @@ def param_shapes(cfg: BackboneConfig) -> Dict[str, Any]:
             f = {"w_gate": (D, cfg.dense_width),
                  "w_up": (D, cfg.dense_width),
                  "w_down": (cfg.dense_width, D)}
-        p[f"l{li}"] = {"norm1": dict(norm), "attn": a,
+        p[f"l{li}"] = {"norm1": dict(norm),
+                       "conv" if mixer == "conv" else "attn": m,
                        "norm2": dict(norm), "ffn": f}
     return p
 
@@ -226,7 +276,9 @@ def init_params(key, cfg: BackboneConfig, dtype=jnp.float32):
     bias N(0, 0.01^2) and the sinks N(0, 1) (what a trained model
     carries there is not published; they must not be zero, or a test
     could not tell them from absent). The item table is N(0, 1 /
-    hidden): its fan-in is the width it is read into."""
+    hidden): its fan-in is the width it is read into. A convolution's
+    kernel [L, D] is N(0, 1 / L) by the same rule: L taps feed each
+    output."""
     flat, tree = jax.tree_util.tree_flatten_with_path(
         param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
     keys = iter(jax.random.split(key, len(flat)))
@@ -293,6 +345,8 @@ def attention_block(p, cfg: BackboneConfig, kind: str, u, positions,
     v = _mm(u, p["wv"]).reshape(*lead, hkv, cfg.v_dim)
     if cfg.value_scale != 1.0:
         v = v * cfg.value_scale
+    if cfg.qk_norm:
+        q, k = norm(q, p["q_norm"], cfg), norm(k, p["k_norm"], cfg)
     if cfg.rotary_dim:
         theta = (cfg.rope_theta_window if kind == "attn_window"
                  else cfg.rope_theta_full)
@@ -303,6 +357,26 @@ def attention_block(p, cfg: BackboneConfig, kind: str, u, positions,
                window=cfg.window if kind == "attn_window" else None,
                sink=p.get("sink"))
     return _mm(a.reshape(*lead, cfg.n_heads * cfg.v_dim), p["wo"])
+
+
+def conv_block(p, cfg: BackboneConfig, u, positions):
+    """The gated short convolution: u [..., T, D] (normed) -> [..., T,
+    D]. [B, C, X] = u W_in; z = B * X; c_t = sum over taps j of
+    K[L - 1 - j] * z_{t - j}; the result is (C * c) W_out. Depthwise,
+    causal, no bias. The neighbour t - j is read along the token axis,
+    and tap j is kept only where the event's index in its own history
+    is at least j: before a history's first event stands zero, not
+    the end of the history packed in front of it (serving) nor the
+    padding a right-aligned row starts with (training)."""
+    D, L = cfg.hidden, cfg.conv_kernel
+    bcx = _mm(u, p["w_in"])
+    z = bcx[..., :D] * bcx[..., 2 * D:]
+    kernel = p["kernel"].astype(jnp.float32)
+    c = kernel[L - 1] * z
+    for j in range(1, L):
+        c = c + jnp.where((positions >= j)[..., None],
+                          kernel[L - 1 - j] * jnp.roll(z, j, axis=-2), 0.0)
+    return _mm(bcx[..., D:2 * D] * c, p["w_out"])
 
 
 def ffn_dense(p, cfg: BackboneConfig, u):
@@ -318,10 +392,10 @@ def ffn_moe(p, cfg: BackboneConfig, u, live=None):
     flat = u.reshape(-1, cfg.hidden)
     routing = moe.route(flat, p["router"], p["bias"], top_k=cfg.top_k,
                         norm_topk_prob=cfg.norm_topk_prob,
-                        scale=cfg.routed_scale)
+                        scale=cfg.routed_scale, eps=cfg.route_eps)
     y, stats = moe.moe_apply(
         flat, routing, p["w_gate_up"], p["w_down"],
-        first=cfg.expert_first,
+        first=cfg.expert_first, n_experts=cfg.n_experts,
         live=None if live is None else live.reshape(-1))
     return y.reshape(*lead, cfg.hidden), stats
 
@@ -340,17 +414,25 @@ def forward(params, cfg: BackboneConfig, tokens, positions,
     if valid is not None and not cfg.pad_row:
         x = jnp.where(valid[..., None], x, 0.0)
     stats = []
-    for li, (attn, ffn) in enumerate(cfg.layers):
+    # one named scope a block kind, so that a profile groups by them
+    for li, (mixer, ffn) in enumerate(cfg.layers):
         lp = params[f"l{li}"]
-        x = x + attention_block(lp["attn"], cfg, attn,
-                                norm(x, lp["norm1"], cfg), positions,
-                                attend)
+        u = norm(x, lp["norm1"], cfg)
+        if mixer == "conv":
+            with jax.named_scope("mixer_conv"):
+                x = x + conv_block(lp["conv"], cfg, u, positions)
+        else:
+            with jax.named_scope("mixer_attn"):
+                x = x + attention_block(lp["attn"], cfg, mixer, u,
+                                        positions, attend)
         u = norm(x, lp["norm2"], cfg)
         if ffn == "ffn_moe":
-            y, st = ffn_moe(lp["ffn"], cfg, u, live=valid)
+            with jax.named_scope("ffn_experts"):
+                y, st = ffn_moe(lp["ffn"], cfg, u, live=valid)
             stats.append(st)
         else:
-            y = ffn_dense(lp["ffn"], cfg, u)
+            with jax.named_scope("ffn_dense"):
+                y = ffn_dense(lp["ffn"], cfg, u)
         x = x + y
     out = norm(x, params["norm_f"], cfg)
     if not stats:

@@ -15,10 +15,15 @@ selected and held are sorted by expert, each expert's group is padded
 up to whole row blocks, and one grouped product a projection runs over
 the blocks (`grouped_matmul`, a Pallas kernel: a block's expert comes
 from a prefetched table and picks the weight tile; blocks past the
-last used one load and compute nothing). The buffer holds as many
-pairs as the call has tokens (twice what uniform routing sends to a
-sixteenth of the experts); a call that routes more to this chip fills
-further buffers, so the bound is on memory, never on the answer.
+last used one load and compute nothing). One buffer holds twice the
+pairs that uniform routing sends to the held experts, and never more
+than every pair of the call (`buffer_pairs`): T pairs for T tokens
+where a sixteenth of the experts is held and 8 are selected; all k T
+pairs, exactly, where every expert is held, so that such a layer runs
+one gather, two grouped products and one scatter-add whatever its
+routing. A call that routes more to this chip than one buffer holds
+fills further buffers, so the bound is on memory, never on the
+answer.
 """
 
 from __future__ import annotations
@@ -52,9 +57,10 @@ def expert_share(index: int, count: int, n_experts: int) -> Tuple[int, int]:
 
 
 def route(u, w_router, bias, *, top_k: int, norm_topk_prob: bool = True,
-          scale: float = 1.0) -> Routing:
+          scale: float = 1.0, eps: float = 0.0) -> Routing:
     """u [T, D] float32, w_router [D, E], bias [E] (the correction term
-    of the aux-loss-free balancing, used for selection only). The
+    of the aux-loss-free balancing, used for selection only); `eps` is
+    added to the sum the selected scores are normalised by. The
     scores are float32: a selection that flips on rounding sends a
     token to another expert. Against bfloat16 weights that is two
     products on the matrix unit, u's leading and next 8 bits of
@@ -76,7 +82,8 @@ def route(u, w_router, bias, *, top_k: int, norm_topk_prob: bool = True,
     _, experts = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(s, experts, axis=1)
     if norm_topk_prob:
-        w = w / w.sum(axis=1, keepdims=True)
+        den = w.sum(axis=1, keepdims=True)
+        w = w / (den + eps if eps else den)
     return Routing(experts.astype(jnp.int32), w * scale)
 
 
@@ -164,14 +171,24 @@ def moe_block_rows(n_tokens: int) -> int:
     return 256 if n_tokens >= 2048 else max(8, min(128, n_tokens // 4))
 
 
+def buffer_pairs(n_tokens: int, top_k: int, held: int,
+                 n_experts: int) -> int:
+    """(Token, held expert) pairs one buffer of `moe_apply` takes:
+    twice what uniform routing sends to `held` of `n_experts`, at most
+    every pair of the call, at least one."""
+    every = n_tokens * top_k
+    return max(1, min(every, -(-2 * every * held // n_experts)))
+
+
 def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
-              live=None, block_rows: int = 0):
+              n_experts: int, live=None, block_rows: int = 0):
     """The held experts' part of the expert layer for u [T, D].
 
     w_gate_up [held, D, 2 F] (gate beside up), w_down [held, F, D];
-    the held experts are ids first .. first + held - 1. `live` [T] bool
-    leaves padding tokens out (they would load the experts for
-    nothing). Returns ([T, D] float32, MoeStats)."""
+    the held experts are ids first .. first + held - 1 of the
+    router's `n_experts`, which sizes a buffer (`buffer_pairs`).
+    `live` [T] bool leaves padding tokens out (they would load the
+    experts for nothing). Returns ([T, D] float32, MoeStats)."""
     T, D = u.shape
     held, F = w_down.shape[0], w_down.shape[1]
     k = routing.experts.shape[1]
@@ -191,7 +208,7 @@ def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
     ends = jnp.cumsum(counts)
     starts = ends - counts
     n_pairs = ends[-1]
-    cap = T                                # pairs one buffer takes
+    cap = buffer_pairs(T, k, held, n_experts)
     n_blocks = -(-cap // tm) + held        # every group's padding fits
     rows = n_blocks * tm
     ub = u.astype(w_gate_up.dtype)
@@ -230,7 +247,7 @@ def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
         # T, which the scatter drops
         return out.at[row_tok].add(y * row_w[:, None], mode="drop")
 
-    # at most k buffers (every pair of every token held here); one that
+    # as many buffers as hold every pair of every token; one that
     # starts past the last pair is skipped. A scan over a cond, not a
     # loop to a computed bound, so that the small training runs can
     # differentiate it.
@@ -239,8 +256,13 @@ def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
                             lambda o: one_buffer(c, o), lambda o: o,
                             out), None
 
-    out, _ = jax.lax.scan(step, jnp.zeros((T, D), jnp.float32),
-                          jnp.arange(k, dtype=jnp.int32))
+    out = jnp.zeros((T, D), jnp.float32)
+    n_buffers = -(-T * k // cap)
+    if n_buffers == 1:
+        out = one_buffer(0, out)
+    else:
+        out, _ = jax.lax.scan(step, out,
+                              jnp.arange(n_buffers, dtype=jnp.int32))
     any_here = here.any(axis=1)
     n_live = T if live is None else live.sum()
     return out, MoeStats(counts, (n_live - any_here.sum()).astype(jnp.int32))

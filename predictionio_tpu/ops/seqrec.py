@@ -48,6 +48,7 @@ import numpy as np
 
 from predictionio_tpu.obs import trace
 from predictionio_tpu.ops import backbone as bb
+from predictionio_tpu.ops import moe
 from predictionio_tpu.ops.attention import packed_attention, ring_attention
 
 
@@ -269,6 +270,12 @@ def _seq_metrics():
                 "(Token, held expert) pairs one call computed, summed "
                 "over its expert layers",
                 buckets=tuple(float(2 ** i) for i in range(6, 21))),
+            "buffers": reg.histogram(
+                "pio_moe_buffers",
+                "Buffers of (token, held expert) pairs one call's "
+                "expert layer ran (ops/moe.buffer_pairs), mean over "
+                "its expert layers",
+                buckets=(0.5, 1.0, 1.5, 2.0, 3.0, 4.0, 6.0, 8.0)),
         }
     return _SEQ_METRICS
 
@@ -397,6 +404,11 @@ class PackedEncoder:
                     metrics["unrouted"].observe(float(
                         np.asarray(stats.unrouted).mean() / n_tok))
                     metrics["pairs"].observe(float(per.sum()))
+                    cap = moe.buffer_pairs(
+                        bucket, self.cfg.top_k, self.cfg.experts_held,
+                        self.cfg.n_experts)
+                    metrics["buffers"].observe(float(
+                        np.ceil(per.sum(axis=1) / cap).mean()))
             except Exception:
                 pass  # metrics must never fail a serve call
         return np.concatenate(out).astype(np.float32)

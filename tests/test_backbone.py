@@ -54,9 +54,10 @@ def histories():
     return [rng.integers(0, CFG.vocab, n).tolist() for n in LENGTHS]
 
 
-def _encoder(params, rows=8):
-    model = SeqRecModel(params=params, n_items=CFG.vocab,
-                        backbone=bb.config_dict(CFG))
+def _encoder(params, rows=8, cfg=None):
+    cfg = cfg or CFG
+    model = SeqRecModel(params=params, n_items=cfg.vocab,
+                        backbone=bb.config_dict(cfg))
     enc = PackedEncoder(model, rows=rows)
     enc.warm()
     return enc
@@ -240,7 +241,8 @@ def test_tokens_over_experts_sum_to_t_times_k(weights, live):
     for share in range(4):
         first, held = moe.expert_share(share, 4, CFG.n_experts)
         _, stats = moe.moe_apply(u, routing, f["w_gate_up"], f["w_down"],
-                                 first=first, live=mask)
+                                 first=first, n_experts=CFG.n_experts,
+                                 live=mask)
         total += int(stats.expert_tokens.sum())
     assert total == (T if live is None else 30) * CFG.top_k
 
@@ -262,7 +264,7 @@ def test_the_four_shares_add_up_to_the_uncut_expert_layer():
         first, held = moe.expert_share(share, 4, 16)
         part, _ = moe.moe_apply(
             u, routing, p["w_gate_up"][first:first + held],
-            p["w_down"][first:first + held], first=first)
+            p["w_down"][first:first + held], first=first, n_experts=16)
         assert np.abs(np.asarray(part)).max() > 1e-4
         total += np.asarray(part)
     np.testing.assert_allclose(total, want, atol=1e-5)
@@ -278,7 +280,7 @@ def test_more_pairs_than_one_buffer_holds_are_all_computed(weights):
     routing = moe.Routing(jnp.tile(jnp.asarray([[0, 3]], jnp.int32), (T, 1)),
                           jnp.full((T, 2), 0.5, jnp.float32))
     got, stats = moe.moe_apply(u, routing, f["w_gate_up"], f["w_down"],
-                               first=0)
+                               first=0, n_experts=CFG.n_experts)
     want = 0
     for e in (0, 3):
         gu = u @ f["w_gate_up"][e]
@@ -565,3 +567,307 @@ def test_filters_the_plan_does_not_take_are_served_the_generic_way(
     want = allowed[np.argsort(-logits[allowed], kind="stable")[:4]]
     assert [int(s["item"][1:]) for s in reply["itemScores"]] \
         == want.tolist()
+
+
+# == the LFM2 family at `tiny-lfm2` ==========================================
+# hidden 64, 4 heads on 2 KV heads of 16 with q/k norm and whole-head
+# rotary, gated short convolutions of 3 taps, 8 experts top-2 ALL held,
+# a tied table; against `benchmark/lfm2_reference.py`.
+
+import lfm2_datagen                                        # noqa: E402
+import lfm2_reference as lref                              # noqa: E402
+
+from predictionio_tpu.ops.seqrec import seqrec_encode      # noqa: E402
+
+LDOC = json.loads((ROOT / "benchmark" / "configs" / "tiny-lfm2.json")
+                  .read_text())
+LCFG = bb.config_from_json(LDOC, "tiny-lfm2")
+LARCH = lref.arch(LDOC)
+# 1 and 2 events: shorter than the convolution's reach
+LLENGTHS = (40, 1, 2, 7, 3, 17)
+
+
+@pytest.fixture(scope="module")
+def lweights():
+    return (lfm2_datagen.program_params(LDOC, SEED, jnp.float32),
+            lfm2_datagen.reference_params(LDOC, SEED))
+
+
+@pytest.fixture(scope="module")
+def lhistories():
+    rng = np.random.default_rng(10)
+    return [rng.integers(0, LCFG.vocab, n).tolist() for n in LLENGTHS]
+
+
+def _lencoder(params):
+    return _encoder(params, cfg=LCFG)
+
+
+@pytest.fixture(scope="module")
+def lencoder(lweights):
+    return _lencoder(lweights[0])
+
+
+def _llogits(params, vecs):
+    return np.asarray(vecs) @ np.asarray(params["embed"], np.float32).T
+
+
+def _ref_layer(rp, i):
+    return {k: jnp.asarray(v) for k, v in rp[f"l{i}"].items()}
+
+
+def test_lfm2_tiny_has_every_block_kind_and_the_programs_pytree(lweights):
+    assert {m for m, _ in LCFG.layers} == {"conv", "attn_full"}
+    assert {f for _, f in LCFG.layers} == {"ffn_dense", "ffn_moe"}
+    assert LCFG.experts_held == LCFG.n_experts == 8 and LCFG.tied
+    assert (LCFG.qk_norm, LCFG.conv_kernel, LCFG.route_eps,
+            LCFG.rotary_dim) == (True, 3, 1e-6, LCFG.qk_dim)
+    init = bb.init_params(jax.random.PRNGKey(0), LCFG)
+    assert jax.tree_util.tree_structure(init) \
+        == jax.tree_util.tree_structure(lweights[0])
+    assert "head" not in init
+    np.testing.assert_array_equal(init["l2"]["attn"]["q_norm"]["g"], 1.0)
+    assert float(jnp.std(init["l0"]["conv"]["kernel"])) \
+        == pytest.approx(3 ** -0.5, rel=0.15)
+
+
+@pytest.mark.parametrize("T", [1, 2, 21])
+def test_conv_mixer_matches_reference(lweights, T):
+    pp, rp = lweights
+    u = jnp.asarray(np.random.default_rng(11).normal(size=(T, LCFG.hidden)),
+                    jnp.float32)
+    got = bb.conv_block(pp["l0"]["conv"], LCFG, u, jnp.arange(T))
+    with jax.default_matmul_precision("highest"):
+        want = lref.conv_mixer(LARCH, _ref_layer(rp, 0), u)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    assert np.abs(np.asarray(want)).max() > 1e-3
+
+
+def test_conv_mixer_keeps_a_tap_only_inside_its_own_history(lweights):
+    """Three histories end to end: each as if alone; and the same with
+    the taps' mask taken away reads the history in front."""
+    pp, rp = lweights
+    lens = (5, 1, 9)
+    u = jnp.asarray(np.random.default_rng(12).normal(
+        size=(sum(lens), LCFG.hidden)), jnp.float32)
+    pos = jnp.concatenate([jnp.arange(n) for n in lens])
+    got = bb.conv_block(pp["l0"]["conv"], LCFG, u, pos)
+    with jax.default_matmul_precision("highest"):
+        want = jnp.concatenate([
+            lref.conv_mixer(LARCH, _ref_layer(rp, 0), u[a:a + n])
+            for a, n in zip(np.cumsum((0,) + lens[:-1]), lens)])
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    unmasked = bb.conv_block(pp["l0"]["conv"], LCFG, u,
+                             jnp.full_like(pos, 10))
+    assert np.abs(np.asarray(unmasked - want))[5:7].max() > 1e-2
+
+
+def test_qk_normed_attention_block_matches_reference(lweights):
+    pp, rp = lweights
+    T = 21
+    u = jnp.asarray(np.random.default_rng(13).normal(size=(T, LCFG.hidden)),
+                    jnp.float32)
+
+    def attend(q, k, v, *, window, sink):
+        assert window is None and sink is None
+        pad = 32 - T
+        q, k, v = (jnp.pad(x, ((0, pad), (0, 0), (0, 0))) for x in (q, k, v))
+        s = jnp.concatenate([jnp.zeros(T, jnp.int32),
+                             jnp.ones(pad, jnp.int32)])
+        st = jnp.concatenate([jnp.zeros(T, jnp.int32),
+                              jnp.full(pad, T, jnp.int32)])
+        return packed_attention(q, k, v, s, st, max_segment=LCFG.max_history,
+                                block_q=8, block_k=8)[:T]
+
+    got = bb.attention_block(pp["l2"]["attn"], LCFG, "attn_full", u,
+                             jnp.arange(T), attend)
+    with jax.default_matmul_precision("highest"):
+        want = lref.attention(LARCH, _ref_layer(rp, 2), u)
+        bare = lref.attention(LARCH, _ref_layer(rp, 2), u,
+                              drop_qk_norm=True)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    assert np.abs(np.asarray(bare - want)).max() > 1e-3
+
+
+@pytest.mark.parametrize("one_buffer", [True, False])
+def test_every_expert_held_counts_t_times_k_and_matches_reference(
+        lweights, one_buffer):
+    """All 8 experts of 8: one buffer of all T k pairs. The same 8 held
+    of a router said to have 32: a buffer of T pairs, so the T k pairs
+    pass it and a second one runs."""
+    pp, rp = lweights
+    f = pp["l2"]["ffn"]
+    T = 50
+    u = jnp.asarray(np.random.default_rng(14).normal(size=(T, LCFG.hidden)),
+                    jnp.float32)
+    routing = moe.route(u, f["router"], f["bias"], top_k=LCFG.top_k,
+                        eps=LCFG.route_eps)
+    got, stats = moe.moe_apply(
+        u, routing, f["w_gate_up"], f["w_down"], first=0,
+        n_experts=LCFG.n_experts if one_buffer else 32)
+    assert moe.buffer_pairs(T, LCFG.top_k, 8, 8) == T * LCFG.top_k
+    assert moe.buffer_pairs(T, LCFG.top_k, 8, 32) == T
+    assert int(stats.expert_tokens.sum()) == T * LCFG.top_k
+    assert int(stats.unrouted) == 0
+    want = lref.expert_ffn(LARCH, _ref_layer(rp, 2), u)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    sel, w = lref.route(LARCH, _ref_layer(rp, 2), u)
+    np.testing.assert_array_equal(np.sort(routing.experts, axis=1),
+                                  np.sort(sel, axis=1))
+    assert float(np.asarray(w).sum(axis=1).max()) < 1.0     # the 1e-6
+
+
+def test_buffer_pairs_is_mimos_rule_and_never_more_than_every_pair():
+    # a sixteenth of 256 experts held, 8 selected: T pairs, 8 buffers
+    assert moe.buffer_pairs(8192, 8, 16, 256) == 8192
+    assert moe.buffer_pairs(64, 2, 4, 16) == 64               # tiny-mimo
+    assert moe.buffer_pairs(8192, 4, 32, 32) == 4 * 8192       # all held
+    assert moe.buffer_pairs(8192, 4, 16, 32) == 4 * 8192       # a half
+    assert moe.buffer_pairs(8192, 4, 4, 32) == 8192
+
+
+def test_lfm2_packed_stack_matches_reference_one_history_at_a_time(
+        lweights, lencoder, lhistories):
+    pp, rp = lweights
+    got = _llogits(pp, lencoder(lhistories))
+    for row, h in zip(got, lhistories):
+        np.testing.assert_allclose(row, lref.forward(LDOC, rp, h),
+                                   atol=1e-5)
+
+
+def test_lfm2_a_history_alone_and_packed_behind_others_agree(
+        lencoder, lhistories):
+    """The leak test: histories of 1 and 2 events stand right behind
+    one of 40; without the taps' mask they would read its end."""
+    packed = lencoder(lhistories)
+    for j, h in enumerate(lhistories):
+        np.testing.assert_allclose(lencoder([h])[0], packed[j], atol=1e-5)
+
+
+def test_lfm2_padded_training_layout_agrees_with_the_packed_one(
+        lweights, lencoder, lhistories):
+    pp, _ = lweights
+    S = LCFG.max_history
+    seqs = np.full((len(lhistories), S), LCFG.vocab, np.int32)
+    for r, h in enumerate(lhistories):
+        seqs[r, S - len(h):] = h
+    model = SeqRecModel(params=pp, n_items=LCFG.vocab,
+                        backbone=bb.config_dict(LCFG))
+    np.testing.assert_allclose(seqrec_encode(model, seqs),
+                               lencoder(lhistories), atol=1e-5)
+
+
+def test_lfm2_layerwise_reference_is_the_reference(lweights, lhistories):
+    _, rp = lweights
+    layerwise = lref.forward_layerwise(
+        LDOC, lfm2_datagen.layer_stream(LDOC, SEED), lhistories)
+    for row, h in zip(layerwise, lhistories):
+        np.testing.assert_allclose(row, lref.forward(LDOC, rp, h),
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("fault", sorted(lref.FAULTS))
+def test_lfm2_reference_faults_move_the_logits(lweights, lhistories, fault):
+    _, rp = lweights
+    h = lhistories[0]
+    moved = lref.forward(LDOC, rp, h, **{fault: True}) \
+        - lref.forward(LDOC, rp, h)
+    assert np.abs(moved).max() > 1e-2
+
+
+def test_lfm2_bfloat16_path_inside_its_tolerance_and_the_control_outside(
+        lhistories):
+    """As `lfm2_control.py` on the chip, at the toy size and under the
+    rehearsal cell's limits (at hidden 64 rounding alone passes the
+    cell's limits on the widest readings): the program in bfloat16 and
+    the reference with bfloat16 operands come out correct, float8
+    operands not."""
+    import reference
+    import seq_control
+    limits = json.loads((ROOT / "benchmark" / "workloads"
+                         / "rehearse-lfm2-serve.json").read_text())[
+                             "correct"]["limits"]
+    rp = lfm2_datagen.reference_params(LDOC, SEED)
+    truth = np.stack([lref.forward(LDOC, rp, h) for h in lhistories])
+    pp = lfm2_datagen.program_params(LDOC, SEED)            # bfloat16
+    program = _llogits(pp, _lencoder(pp)(lhistories))
+    with lref.operands("bf16"):
+        stated = np.stack([lref.forward(LDOC, rp, h) for h in lhistories])
+    with lref.operands("fp8"):
+        control = np.stack([lref.forward(LDOC, rp, h) for h in lhistories])
+    for got in (program, stated):
+        assert reference.verdict(seq_control.numbers(got, truth, 10),
+                                 limits)[0]
+    n = seq_control.numbers(control, truth, 10)
+    assert not reference.verdict(n, limits)[0]
+    assert n["score_err_median"] > 3 * limits["score_err_median"]
+
+
+def test_mimo_file_still_gives_todays_config_field_for_field():
+    """Written out from the parent commit's `config_from_json`; the
+    fields this family brought read their defaults."""
+    cfg = bb.load_config(str(CONFIGS / "mimo-v2.5-ep16-7l.json"))
+    assert bb.config_dict(cfg) == {
+        "name": "mimo-v2.5-ep16-7l", "hidden": 4096, "vocab": 19072,
+        "layers": (("attn_full", "ffn_dense"), ("attn_full", "ffn_moe"))
+        + (("attn_window", "ffn_moe"),) * 5,
+        "n_heads": 64, "kv_heads_full": 4, "kv_heads_window": 8,
+        "qk_dim": 192, "v_dim": 128, "norm": "rms", "eps": 1e-05,
+        "act": "silu", "dense_width": 16384, "window": 128,
+        "sink_window": True, "sink_full": False, "rotary_dim": 64,
+        "rope_theta_full": 10000000.0, "rope_theta_window": 10000.0,
+        "value_scale": 0.707, "positions": 0, "embed_scale": 1.0,
+        "tied": False, "pad_row": False, "expert_width": 2048,
+        "n_experts": 256, "top_k": 8, "norm_topk_prob": True,
+        "routed_scale": 1.0, "expert_first": 0, "experts_held": 16,
+        "qk_norm": False, "conv_kernel": 0, "route_eps": 0.0,
+        "max_history": 2048, "max_batch_tokens": 8192,
+        "token_buckets": (512, 1024, 2048, 4096, 8192)}
+
+
+def test_lfm2_published_widths_and_the_cut():
+    doc = json.loads((CONFIGS / "lfm2-8b-a1b-pp2-12l.json").read_text())
+    cfg = bb.load_config(str(CONFIGS / "lfm2-8b-a1b-pp2-12l.json"))
+    assert (cfg.hidden, cfg.n_heads, cfg.kv_heads_full, cfg.qk_dim,
+            cfg.v_dim, cfg.rotary_dim, cfg.conv_kernel, cfg.qk_norm) == (
+                2048, 32, 8, 64, 64, 64, 3, True)
+    assert (cfg.n_experts, cfg.experts_held, cfg.top_k, cfg.expert_width,
+            cfg.dense_width, cfg.vocab, cfg.tied) == (
+                32, 32, 4, 1792, 7168, 65536, True)
+    conv, attn = ("conv", "ffn_moe"), ("attn_full", "ffn_moe")
+    assert cfg.layers == (("conv", "ffn_dense"),) * 2 + (
+        attn, conv, conv, conv, attn, conv, conv, conv, attn, conv)
+    assert bb.n_params(cfg) == 3_928_728_256
+    assert len(doc["layer_types"]) == doc["published"][
+        "num_hidden_layers"] == 24
+    assert set(doc["reduced"]) == {"num_hidden_layers", "n_users"}
+    assert bb.config_of(json.loads(json.dumps(bb.config_dict(cfg)))) == cfg
+
+
+@pytest.mark.parametrize("bad", ["sliding_attention", "mamba"])
+def test_an_unknown_layer_type_is_refused(bad):
+    doc = dict(LDOC, layer_types=["conv", bad] + LDOC["layer_types"][2:])
+    with pytest.raises(ValueError, match=bad):
+        bb.config_from_json(doc, "bad")
+
+
+def test_seqrec_train_runs_at_tiny_lfm2():
+    rng = np.random.default_rng(0)
+    n_users, n_items = 64, 40
+    us = np.repeat(np.arange(n_users), 9)
+    starts = rng.integers(0, n_items, n_users)
+    its = (np.repeat(starts, 9) + np.tile(np.arange(9), n_users)) % n_items
+    ts = np.tile(np.arange(9), n_users)
+    seqs, targets = build_sequences(us, its, ts, n_items=n_items, seq_len=8)
+    losses = []
+    m = seqrec_train(seqs, targets, n_items=n_items, seq_len=8,
+                     batch_size=32, epochs=6, lr=3e-3, seed=0,
+                     backbone=str(CONFIGS / "tiny-lfm2.json"), losses=losses)
+    assert len(losses) == 12 and np.isfinite(losses).all()
+    assert np.mean(losses[-3:]) < np.mean(losses[:3])
+    assert m.config.vocab == n_items and m.item_emb.shape == (n_items, 64)
+    init = bb.init_params(jax.random.PRNGKey(0), m.config)
+    assert np.abs(m.params["l0"]["conv"]["kernel"]
+                  - np.asarray(init["l0"]["conv"]["kernel"])).max() > 0
+    np.testing.assert_array_equal(m.params["l2"]["ffn"]["bias"],
+                                  init["l2"]["ffn"]["bias"])

@@ -85,6 +85,48 @@ def test_grouped_matmul_compiles_at_width(one_chip, as_tpu, k_dim, n_dim):
     assert _compiled_kernels(compiled) == 1
 
 
+def test_packed_attention_compiles_at_lfm2s_width(one_chip, as_tpu):
+    """LFM2-8B-A1B: 32 query heads on 8 KV heads (group 4), q, k and v
+    of 64, no window, no sink, 8,192 tokens."""
+    from predictionio_tpu.ops.attention import (
+        packed_attention, packed_block_sizes)
+    assert packed_block_sizes(8192, 4) == (512, 256)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, seg, start: packed_attention(
+            q, k, v, seg, start, max_segment=2048)).lower(
+        sds((8192, 32, 64), jnp.bfloat16), sds((8192, 8, 64), jnp.bfloat16),
+        sds((8192, 8, 64), jnp.bfloat16),
+        sds((8192,), jnp.int32), sds((8192,), jnp.int32)).compile()
+    assert _compiled_kernels(compiled) == 1
+
+
+@pytest.mark.parametrize("k_dim,n_dim", [(2048, 3584), (1792, 2048)])
+def test_grouped_matmul_compiles_at_lfm2s_width(one_chip, as_tpu, k_dim,
+                                                n_dim):
+    """All 32 experts of width 1,792 (gate beside up: 3,584), one
+    buffer of every pair of an 8,192-token call in 256-row blocks."""
+    from predictionio_tpu.ops.moe import (
+        buffer_pairs, grouped_matmul, moe_block_rows)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tm = moe_block_rows(8192)
+    rows = buffer_pairs(8192, 4, 32, 32) + 32 * tm
+    assert (tm, rows) == (256, 40960)
+    compiled = jax.jit(
+        lambda x, w, e, n: grouped_matmul(x, w, e, n, tm, 512,
+                                          jnp.float32)).lower(
+        sds((rows, k_dim), jnp.bfloat16),
+        sds((32, k_dim, n_dim), jnp.bfloat16),
+        sds((rows // tm,), jnp.int32), sds((), jnp.int32)).compile()
+    assert _compiled_kernels(compiled) == 1
+
+
 def _moves_of(compiled, cells: int) -> list:
     """The compiled program's `copy` and `transpose` instructions whose
     result holds at least `cells` elements: a catalog that the call
@@ -184,3 +226,16 @@ def test_fused_topk_compiles_over_the_heads_rows(one_chip, monkeypatch,
     compiled = _compiled_fused_bucket(one_chip, monkeypatch, 19072, 4096,
                                       bucket)
     _assert_reads_the_catalog_where_it_lies(compiled, 19072, 4096)
+
+
+@pytest.mark.parametrize("bucket", [1, 64])
+def test_fused_topk_compiles_over_the_whole_lfm2_vocabulary(
+        one_chip, monkeypatch, bucket):
+    """65,536 rows of 2,048 (the tied table, float32 for the plan):
+    512-row tiles of whole lane groups, read where they lie."""
+    from predictionio_tpu.ops import fused_topk
+    assert fused_topk._tile_items(65536, 10, 2048) == (512, 512)
+    assert not fused_topk._items_on_lanes(2048)
+    compiled = _compiled_fused_bucket(one_chip, monkeypatch, 65536, 2048,
+                                      bucket)
+    _assert_reads_the_catalog_where_it_lies(compiled, 65536, 2048)
