@@ -19,11 +19,23 @@ last used one load and compute nothing). One buffer holds twice the
 pairs that uniform routing sends to the held experts, and never more
 than every pair of the call (`buffer_pairs`): T pairs for T tokens
 where a sixteenth of the experts is held and 8 are selected; all k T
-pairs, exactly, where every expert is held, so that such a layer runs
-one gather, two grouped products and one scatter-add whatever its
-routing. A call that routes more to this chip than one buffer holds
-fills further buffers, so the bound is on memory, never on the
-answer.
+pairs, exactly, where every expert is held. A call that routes more
+to this chip than one buffer holds fills further buffers, so the bound
+is on memory, never on the answer.
+
+A buffer is one gather of the tokens' rows into sorted order, two
+grouped products, and the way back, which is one of two
+(`gather_combine`, the buffer rule's own integer). Where one buffer
+holds every pair of the call (every expert held, or any share of at
+least half), each (token, choice) pair has exactly one row of the
+down product's result and its index is known when the buffer is laid
+out: a token's result is its k rows, gathered and weighted in pair
+order, `out[t] = sum_j w[t, j] * y[row_of[t, j]]`, float32, no
+atomics. Where a buffer holds fewer pairs than the call may bring
+(a sixteenth of the experts), the rows are weighted and
+scatter-added onto their tokens, buffer after buffer: most tokens
+have no row in a given buffer, and a token-side gather would read
+k T rows to find them.
 """
 
 from __future__ import annotations
@@ -180,6 +192,30 @@ def buffer_pairs(n_tokens: int, top_k: int, held: int,
     return max(1, min(every, -(-2 * every * held // n_experts)))
 
 
+def gather_combine(n_tokens: int, top_k: int, held: int,
+                   n_experts: int) -> bool:
+    """Whether `moe_apply` combines the experts' rows by a gather on
+    the token side: where one buffer holds every pair of the call, so
+    that each pair has exactly one row. Otherwise it scatter-adds."""
+    return buffer_pairs(n_tokens, top_k, held, n_experts) \
+        == n_tokens * top_k
+
+
+def _combine_rows(y, row_of, here, weights):
+    """out[t] = sum over j of weights[t, j] * y[row_of[t, j]], over
+    the pairs that are `here`; y [rows, D] float32, the others [T, k].
+    A pair that is not here carries an index past the buffer, and rows
+    past the used blocks were never written: masked, not multiplied
+    by a zero weight."""
+    last = y.shape[0] - 1
+    out = 0.0
+    for j in range(row_of.shape[1]):
+        rows_j = y[jnp.minimum(row_of[:, j], last)]
+        out = out + jnp.where(here[:, j, None], rows_j, 0.0) \
+            * weights[:, j, None]
+    return out
+
+
 def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
               n_experts: int, live=None, block_rows: int = 0):
     """The held experts' part of the expert layer for u [T, D].
@@ -188,7 +224,10 @@ def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
     the held experts are ids first .. first + held - 1 of the
     router's `n_experts`, which sizes a buffer (`buffer_pairs`).
     `live` [T] bool leaves padding tokens out (they would load the
-    experts for nothing). Returns ([T, D] float32, MoeStats)."""
+    experts for nothing). The rows come back to their tokens by a
+    gather where one buffer holds every pair, by a scatter-add a
+    buffer where not (`gather_combine`). Returns ([T, D] float32,
+    MoeStats)."""
     T, D = u.shape
     held, F = w_down.shape[0], w_down.shape[1]
     k = routing.experts.shape[1]
@@ -203,7 +242,6 @@ def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
     order = jnp.argsort(e_flat, stable=True)
     e_sorted = e_flat[order]
     tok_sorted = (order // k).astype(jnp.int32)
-    w_sorted = routing.weights.reshape(-1)[order]
     counts = jnp.bincount(e_flat, length=held + 1)[:held].astype(jnp.int32)
     ends = jnp.cumsum(counts)
     starts = ends - counts
@@ -214,7 +252,11 @@ def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
     ub = u.astype(w_gate_up.dtype)
     u_ext = jnp.concatenate([ub, jnp.zeros((1, D), ub.dtype)])
 
-    def one_buffer(c, out):
+    def products(c):
+        """Buffer `c`: the down product's rows [rows, D] float32 (those
+        past the used blocks never written), each row's token (T where
+        it has none) and each sorted pair's row (`rows` where the pair
+        is not here)."""
         lo = c * cap
         # this buffer's slice of each expert's group, padded to blocks
         g_lo = jnp.clip(starts, lo, lo + cap)
@@ -225,13 +267,10 @@ def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
         pos = lo + jnp.arange(cap, dtype=jnp.int32)
         e = jax.lax.dynamic_slice(e_sorted, (lo,), (cap,))
         tok = jax.lax.dynamic_slice(tok_sorted, (lo,), (cap,))
-        wt = jax.lax.dynamic_slice(w_sorted, (lo,), (cap,))
         ec = jnp.minimum(e, held - 1)
         dest = jnp.where(e < held, g_off[ec] + pos - g_lo[ec], rows)
         row_tok = jnp.full((rows,), T, jnp.int32).at[dest].set(
             tok, mode="drop")
-        row_w = jnp.zeros((rows,), jnp.float32).at[dest].set(
-            wt, mode="drop")
         # block b is of the first expert whose padded group ends past it
         block_expert = jnp.minimum(
             (g_end[None, :] // tm <= jnp.arange(n_blocks)[:, None]).sum(1),
@@ -243,26 +282,39 @@ def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
              * gu[:, F:].astype(jnp.float32)).astype(x.dtype)
         y = grouped_matmul(h, w_down, block_expert, n_used, tm, 512,
                            jnp.float32)
-        # rows past the used blocks were never written: they carry row
-        # T, which the scatter drops
-        return out.at[row_tok].add(y * row_w[:, None], mode="drop")
+        return y, row_tok, dest
 
-    # as many buffers as hold every pair of every token; one that
-    # starts past the last pair is skipped. A scan over a cond, not a
-    # loop to a computed bound, so that the small training runs can
-    # differentiate it.
-    def step(out, c):
-        return jax.lax.cond(c * cap < n_pairs,
-                            lambda o: one_buffer(c, o), lambda o: o,
-                            out), None
-
-    out = jnp.zeros((T, D), jnp.float32)
-    n_buffers = -(-T * k // cap)
-    if n_buffers == 1:
-        out = one_buffer(0, out)
+    if gather_combine(T, k, held, n_experts):
+        # the one buffer holds every pair: `dest`, carried back from
+        # sorted order to pair order, is each pair's row
+        y, _, dest = products(0)
+        row_of = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            dest, unique_indices=True).reshape(T, k)
+        out = _combine_rows(y, row_of, here, routing.weights)
     else:
-        out, _ = jax.lax.scan(step, out,
-                              jnp.arange(n_buffers, dtype=jnp.int32))
+        w_sorted = routing.weights.reshape(-1)[order]
+
+        def scatter_buffer(c, out):
+            y, row_tok, dest = products(c)
+            wt = jax.lax.dynamic_slice(w_sorted, (c * cap,), (cap,))
+            row_w = jnp.zeros((rows,), jnp.float32).at[dest].set(
+                wt, mode="drop")
+            # rows past the used blocks were never written: they carry
+            # row T, which the scatter drops
+            return out.at[row_tok].add(y * row_w[:, None], mode="drop")
+
+        # as many buffers as hold every pair of every token; one that
+        # starts past the last pair is skipped. A scan over a cond, not
+        # a loop to a computed bound, so that the small training runs
+        # can differentiate it.
+        def step(out, c):
+            return jax.lax.cond(c * cap < n_pairs,
+                                lambda o: scatter_buffer(c, o),
+                                lambda o: o, out), None
+
+        out, _ = jax.lax.scan(step, jnp.zeros((T, D), jnp.float32),
+                              jnp.arange(-(-T * k // cap),
+                                         dtype=jnp.int32))
     any_here = here.any(axis=1)
     n_live = T if live is None else live.sum()
     return out, MoeStats(counts, (n_live - any_here.sum()).astype(jnp.int32))

@@ -276,6 +276,12 @@ def _seq_metrics():
                 "expert layer ran (ops/moe.buffer_pairs), mean over "
                 "its expert layers",
                 buckets=(0.5, 1.0, 1.5, 2.0, 3.0, 4.0, 6.0, 8.0)),
+            "gather": reg.histogram(
+                "pio_moe_gather_combine_share",
+                "Share of one call's expert layers that combined the "
+                "experts' rows by a gather on the token side and not "
+                "by a scatter-add (ops/moe.gather_combine)",
+                buckets=share),
         }
     return _SEQ_METRICS
 
@@ -409,6 +415,10 @@ class PackedEncoder:
                         self.cfg.n_experts)
                     metrics["buffers"].observe(float(
                         np.ceil(per.sum(axis=1) / cap).mean()))
+                    # one rule for every expert layer of the stack
+                    metrics["gather"].observe(float(moe.gather_combine(
+                        bucket, self.cfg.top_k, self.cfg.experts_held,
+                        self.cfg.n_experts)))
             except Exception:
                 pass  # metrics must never fail a serve call
         return np.concatenate(out).astype(np.float32)

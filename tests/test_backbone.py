@@ -487,7 +487,8 @@ def test_queries_through_the_server_compile_nothing_and_tile_the_cycle(
                            "pio_seq_history_events",
                            "pio_moe_expert_tokens_max_over_mean",
                            "pio_moe_unrouted_share",
-                           "pio_moe_expert_pairs")}
+                           "pio_moe_expert_pairs",
+                           "pio_moe_gather_combine_share")}
     with compile_watch() as w:
         replies = _hammer(srv.port, len(hists))
     _drained(srv)
@@ -689,32 +690,91 @@ def test_qk_normed_attention_block_matches_reference(lweights):
     assert np.abs(np.asarray(bare - want)).max() > 1e-3
 
 
+def _unwritten_rows_are_nan(monkeypatch):
+    """On the chip `grouped_matmul` never writes the rows past the used
+    blocks, and they hold whatever lay there: here they hold NaN."""
+    real = moe.grouped_matmul
+
+    def poisoned(x, w, block_expert, n_used, block_rows, *rest):
+        y = real(x, w, block_expert, n_used, block_rows, *rest)
+        block = jnp.arange(x.shape[0]) // block_rows
+        return jnp.where((block < n_used)[:, None], y, jnp.nan)
+
+    monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+
+
 @pytest.mark.parametrize("one_buffer", [True, False])
+@pytest.mark.parametrize("live", [None, "some"])
+@pytest.mark.parametrize("routed", ["by_the_router", "to_two_experts"])
 def test_every_expert_held_counts_t_times_k_and_matches_reference(
-        lweights, one_buffer):
-    """All 8 experts of 8: one buffer of all T k pairs. The same 8 held
-    of a router said to have 32: a buffer of T pairs, so the T k pairs
-    pass it and a second one runs."""
+        lweights, monkeypatch, one_buffer, live, routed):
+    """All 8 experts of 8: one buffer of all T k pairs, combined by the
+    gather. The same 8 held of a router said to have 32: a buffer of T
+    pairs, so the T k pairs pass it, a second one runs and the rows
+    are scatter-added. With padding tokens, whose pairs have no row,
+    and with every token sent to two experts, which leaves whole
+    blocks of the buffer unused and unwritten."""
+    _unwritten_rows_are_nan(monkeypatch)
     pp, rp = lweights
     f = pp["l2"]["ffn"]
-    T = 50
+    T, n_live = 50, 50 if live is None else 41
     u = jnp.asarray(np.random.default_rng(14).normal(size=(T, LCFG.hidden)),
                     jnp.float32)
+    mask = None if live is None else jnp.arange(T) < n_live
     routing = moe.route(u, f["router"], f["bias"], top_k=LCFG.top_k,
                         eps=LCFG.route_eps)
-    got, stats = moe.moe_apply(
-        u, routing, f["w_gate_up"], f["w_down"], first=0,
-        n_experts=LCFG.n_experts if one_buffer else 32)
+    if routed == "to_two_experts":
+        routing = moe.Routing(
+            jnp.tile(jnp.asarray([[5, 2]], jnp.int32), (T, 1)),
+            routing.weights)
+    n_experts = LCFG.n_experts if one_buffer else 32
+    got, stats = moe.moe_apply(u, routing, f["w_gate_up"], f["w_down"],
+                               first=0, n_experts=n_experts, live=mask)
     assert moe.buffer_pairs(T, LCFG.top_k, 8, 8) == T * LCFG.top_k
     assert moe.buffer_pairs(T, LCFG.top_k, 8, 32) == T
-    assert int(stats.expert_tokens.sum()) == T * LCFG.top_k
+    assert moe.gather_combine(T, LCFG.top_k, 8, n_experts) == one_buffer
+    assert int(stats.expert_tokens.sum()) == n_live * LCFG.top_k
     assert int(stats.unrouted) == 0
-    want = lref.expert_ffn(LARCH, _ref_layer(rp, 2), u)
+    if routed == "to_two_experts":
+        assert sorted(np.flatnonzero(stats.expert_tokens)) == [2, 5]
+        # 10 of the 17 blocks of 12 rows used
+        assert moe.moe_block_rows(T) == 12
+    F = LCFG.expert_width
+    want = np.zeros((T, LCFG.hidden), np.float32)
+    for j in range(LCFG.top_k):
+        for e in np.unique(routing.experts[:, j]):
+            gu = u @ f["w_gate_up"][e]
+            y = (jax.nn.silu(gu[:, :F]) * gu[:, F:]) @ f["w_down"][e]
+            want += np.where(routing.experts[:, j, None] == e,
+                             routing.weights[:, j, None] * y, 0.0)
+    want[n_live:] = 0.0
+    assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(got, want, atol=1e-5)
-    sel, w = lref.route(LARCH, _ref_layer(rp, 2), u)
-    np.testing.assert_array_equal(np.sort(routing.experts, axis=1),
-                                  np.sort(sel, axis=1))
-    assert float(np.asarray(w).sum(axis=1).max()) < 1.0     # the 1e-6
+    np.testing.assert_array_equal(np.asarray(got)[n_live:], 0.0)
+    if routed == "by_the_router":
+        ref_out = lref.expert_ffn(LARCH, _ref_layer(rp, 2), u)
+        np.testing.assert_allclose(got[:n_live], ref_out[:n_live],
+                                   atol=1e-5)
+        sel, w = lref.route(LARCH, _ref_layer(rp, 2), u)
+        np.testing.assert_array_equal(np.sort(routing.experts, axis=1),
+                                      np.sort(sel, axis=1))
+        assert float(np.asarray(w).sum(axis=1).max()) < 1.0     # the 1e-6
+
+
+@pytest.mark.parametrize("held,n_experts,top_k,gather", [
+    (32, 32, 4, True),       # lfm2-8b-a1b-pp2-12l: every expert
+    (16, 32, 4, True),       # a half: buffer_pairs(T, 4, 16, 32) == 4 T
+    (8, 32, 4, False),       # a quarter: 2 T pairs a buffer
+    (16, 256, 8, False),     # mimo-v2.5-ep16-7l: a sixteenth
+    (8, 8, 2, True),         # tiny-lfm2
+    (4, 16, 2, False),       # tiny-mimo
+])
+def test_the_gather_combine_is_chosen_by_the_buffer_rule(
+        held, n_experts, top_k, gather):
+    for T in (64, 8192):
+        assert moe.gather_combine(T, top_k, held, n_experts) == gather
+        assert gather == (moe.buffer_pairs(T, top_k, held, n_experts)
+                          == T * top_k)
 
 
 def test_buffer_pairs_is_mimos_rule_and_never_more_than_every_pair():
@@ -724,6 +784,39 @@ def test_buffer_pairs_is_mimos_rule_and_never_more_than_every_pair():
     assert moe.buffer_pairs(8192, 4, 32, 32) == 4 * 8192       # all held
     assert moe.buffer_pairs(8192, 4, 16, 32) == 4 * 8192       # a half
     assert moe.buffer_pairs(8192, 4, 4, 32) == 8192
+
+
+@pytest.mark.parametrize("which,share,pct", [("tiny-lfm2", 1.0, 100.0),
+                                             ("tiny-mimo", 0.0, 0.0)])
+def test_the_packed_encoder_says_which_combine_a_call_ran(
+        encoder, lencoder, histories, lhistories, which, share, pct):
+    """`pio_moe_gather_combine_share`, once a call, and the per-layer
+    metric that reads it for both sequence cells."""
+    from predictionio_tpu.obs import get_registry
+    import readers
+    enc, hs = {"tiny-lfm2": (lencoder, lhistories),
+               "tiny-mimo": (encoder, histories)}[which]
+    child = get_registry().histogram("pio_moe_gather_combine_share").labels()
+    calls = get_registry().histogram("pio_seq_call_tokens").labels()
+    n0, s0, c0 = child.count, child.sum, calls.count
+    enc(hs)
+    assert child.count - n0 == calls.count - c0 >= 1
+    assert child.sum - s0 == share * (child.count - n0)
+    bench_dir = ROOT / "benchmark"
+    metric = json.loads((bench_dir / "layer_metrics"
+                         / "seq_moe_gather_combine_pct.json").read_text())
+    assert metric["reader"] == "readers.hist_mean"
+    facts = {"hist": {"pio_moe_gather_combine_share": {
+        "count": child.count - n0, "sum": child.sum - s0}}}
+    assert readers.hist_mean(facts, **metric["args"]) == pct
+    assert readers.hist_mean({"hist": {}}, **metric["args"]) is None
+    entry = [m for m in json.loads((ROOT / "BENCHMARK.json").read_text())
+             ["per_layer"] if m["name"] == "seq_moe_gather_combine_pct"]
+    assert entry == [{
+        "name": "seq_moe_gather_combine_pct", "unit": "%",
+        "better": "higher", "source": "program_counter",
+        "layer": "expert layer", "moves": "serve_qps",
+        "workloads": ["lfm2-hist-c32", "mimo25-hist-c32"]}]
 
 
 def test_lfm2_packed_stack_matches_reference_one_history_at_a_time(

@@ -127,6 +127,36 @@ def test_grouped_matmul_compiles_at_lfm2s_width(one_chip, as_tpu, k_dim,
     assert _compiled_kernels(compiled) == 1
 
 
+@pytest.mark.parametrize("width,ffn,held,n_experts,top_k,scatters", [
+    (2048, 1792, 32, 32, 4, 0),      # lfm2-8b-a1b-pp2-12l: the gather
+    (4096, 2048, 16, 256, 8, 1),     # mimo-v2.5-ep16-7l: the scatter-add
+])
+def test_expert_layer_combines_by_gather_where_a_buffer_holds_every_pair(
+        one_chip, as_tpu, width, ffn, held, n_experts, top_k, scatters):
+    """`moe_apply` at 8,192 tokens: two grouped products, and a float32
+    scatter of whole rows onto the tokens only where a buffer holds
+    fewer pairs than the call may bring."""
+    from predictionio_tpu.ops import moe
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    T = 8192
+    assert moe.gather_combine(T, top_k, held, n_experts) == (not scatters)
+    compiled = jax.jit(
+        lambda u, e, w, wgu, wd, live: moe.moe_apply(
+            u, moe.Routing(e, w), wgu, wd, first=0, n_experts=n_experts,
+            live=live)).lower(
+        sds((T, width), jnp.float32), sds((T, top_k), jnp.int32),
+        sds((T, top_k), jnp.float32),
+        sds((held, width, 2 * ffn), jnp.bfloat16),
+        sds((held, ffn, width), jnp.bfloat16), sds((T,), jnp.bool_)).compile()
+    assert _compiled_kernels(compiled) == 2
+    row_scatters = re.findall(
+        rf"= f32\[{T},{width}\]\S* scatter\(", compiled.as_text())
+    assert len(row_scatters) == scatters
+
+
 def _moves_of(compiled, cells: int) -> list:
     """The compiled program's `copy` and `transpose` instructions whose
     result holds at least `cells` elements: a catalog that the call
