@@ -178,12 +178,15 @@ def _tile_items(n_rows: int, k: int, rank: int) -> tuple:
     items, the tile is a whole number of sub-blocks, and a catalog
     smaller than the tile is one grid step of its own sub-blocks. A
     tile is double-buffered in VMEM, so one of wide rows (a sequence
-    model's head: rank 4,096) is cut to `_TILE_BYTES`; at rank 64 the
-    cut is far above the default tile and changes nothing."""
+    model's head: rank 4,096) is cut to the whole lane groups that
+    fit `_TILE_BYTES` (rank 2,688: 384 items; the 512 above them ran a
+    bucket of 32 out of fast memory); at rank 64 the cut is far above
+    the default tile and changes nothing."""
     tile = int(os.environ.get("PIO_FUSED_TILE_ITEMS", "0") or 0)
     if tile <= 0:
         tile = DEFAULT_TILE_ITEMS
-    tile = min(tile, max(_LANES, _TILE_BYTES // (4 * rank)))
+    tile = min(tile, max(_LANES,
+                         _TILE_BYTES // (4 * rank) // _LANES * _LANES))
     tile = _round_up(max(tile, k), _LANES)
     sub = min(tile, _round_up(max(_SUB_ITEMS, k), _LANES))
     return min(_round_up(tile, sub), _round_up(n_rows, sub)), sub

@@ -23,6 +23,9 @@ pairs, exactly, where every expert is held. A call that routes more
 to this chip than one buffer holds fills further buffers, so the bound
 is on memory, never on the answer.
 
+An expert is a SwiGLU (gate beside up in one matrix) or, where the
+matrix is F wide and not 2 F, two matrices with relu^2 between them.
+
 A buffer is one gather of the tokens' rows into sorted order, two
 grouped products, and the way back, which is one of two
 (`gather_combine`, the buffer rule's own integer). Where one buffer
@@ -99,26 +102,47 @@ def route(u, w_router, bias, *, top_k: int, norm_topk_prob: bool = True,
     return Routing(experts.astype(jnp.int32), w * scale)
 
 
-def _gmm_kernel(expert_ref, used_ref, x_ref, w_ref, o_ref):
+def _gmm_kernel(expert_ref, used_ref, x_ref, w_ref, o_ref, *, w_rows: int):
     del expert_ref
 
     @pl.when(pl.program_id(1) < used_ref[0])
     def _():
         o_ref[...] = jax.lax.dot_general(
-            x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+            x_ref[...], w_ref[...], (((1,), (w_rows,)), ((), ())),
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def col_block(n_cols: int, at_most: int) -> int:
+    """Columns of one weight tile: the widest whole number of lane
+    groups (128) that divides `n_cols` and is at most `at_most`; every
+    column where there is none (1,856 = 14.5 lane groups)."""
+    fits = [d for d in range(128, min(at_most, n_cols) + 1, 128)
+            if n_cols % d == 0]
+    return fits[-1] if fits else n_cols
+
+
+def columns_first(n_cols: int) -> bool:
+    """Whether `grouped_matmul` is handed w [E, K, N] as its transpose
+    [E, N, K]: where N is no whole number of lane groups the compiler
+    keeps the array with K on the lanes, so the transpose is the same
+    bytes, and a kernel that asked for (K, N) tiles would have every
+    call copy all the weights first (2.0 ms an expert layer at 64 x
+    2,688 x 1,856: my chip run, PR 36). As `fused_topk._items_on_lanes`
+    for a catalog of narrow rows."""
+    return n_cols % 128 != 0
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def grouped_matmul(x, w, block_expert, n_used, block_rows: int,
-                   block_cols: int = 512, out_dtype=None):
+                   block_cols: int = 512, out_dtype=None,
+                   w_t: bool = False):
     """x [R, K] in row blocks of `block_rows`, block b all of expert
-    `block_expert[b]`; w [E, K, N]. Returns [R, N]: rows of the first
-    `n_used` blocks are x @ w[expert], rows of the others are not
-    written."""
+    `block_expert[b]`; w [E, K, N], or with `w_t` its transpose [E, N,
+    K]. Returns [R, N]: rows of the first `n_used` blocks are x @
+    w[expert], rows of the others are not written."""
     R, K = x.shape
-    N = w.shape[2]
-    tn = min(block_cols, N)
+    N = w.shape[1 if w_t else 2]
+    tn = col_block(N, block_cols)
     n_blocks = R // block_rows
 
     def row_block(b, used_ref):
@@ -129,15 +153,18 @@ def grouped_matmul(x, w, block_expert, n_used, block_rows: int,
         in_specs=[
             pl.BlockSpec((block_rows, K),
                          lambda n, b, e, u: (row_block(b, u), 0)),
-            pl.BlockSpec((None, K, tn),
-                         lambda n, b, e, u: (e[row_block(b, u)], 0, n)),
+            (pl.BlockSpec((None, tn, K),
+                          lambda n, b, e, u: (e[row_block(b, u)], n, 0))
+             if w_t else
+             pl.BlockSpec((None, K, tn),
+                          lambda n, b, e, u: (e[row_block(b, u)], 0, n))),
         ],
         # a skipped step keeps the last used block's index, so that
         # block is written once, whole, when the sweep ends
         out_specs=pl.BlockSpec((block_rows, tn),
                                lambda n, b, e, u: (row_block(b, u), n)))
     return pl.pallas_call(
-        _gmm_kernel, grid_spec=grid,
+        partial(_gmm_kernel, w_rows=int(w_t)), grid_spec=grid,
         out_shape=jax.ShapeDtypeStruct((R, N), out_dtype or x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -148,13 +175,13 @@ def grouped_matmul(x, w, block_expert, n_used, block_rows: int,
 
 
 def _gmm_fwd(x, w, block_expert, n_used, block_rows, block_cols,
-             out_dtype):
+             out_dtype, w_t):
     return (grouped_matmul(x, w, block_expert, n_used, block_rows,
-                           block_cols, out_dtype),
+                           block_cols, out_dtype, w_t),
             (x, w, block_expert, n_used))
 
 
-def _gmm_bwd(block_rows, block_cols, out_dtype, res, dy):
+def _gmm_bwd(block_rows, block_cols, out_dtype, w_t, res, dy):
     """For the template's small training runs: dx is the same grouped
     product against the transposed weights; dw is formed block by
     block in plain einsums (every block's [K, N] at once, which no
@@ -162,17 +189,18 @@ def _gmm_bwd(block_rows, block_cols, out_dtype, res, dy):
     x, w, block_expert, n_used = res
     used = jnp.arange(x.shape[0] // block_rows) < n_used
     dy = jnp.where(jnp.repeat(used, block_rows)[:, None], dy, 0)
-    dx = grouped_matmul(dy.astype(x.dtype), w.swapaxes(1, 2),
-                        block_expert, n_used, block_rows,
-                        min(block_cols, x.shape[1]), x.dtype)
+    dx = grouped_matmul(dy.astype(x.dtype), w, block_expert, n_used,
+                        block_rows, block_cols, x.dtype, not w_t)
     xb = jnp.where(jnp.repeat(used, block_rows)[:, None], x, 0).reshape(
         -1, block_rows, x.shape[1])
     per_block = jnp.einsum("brk,brn->bkn", xb.astype(jnp.float32),
                            dy.reshape(-1, block_rows, dy.shape[1])
                            .astype(jnp.float32))
     dw = jax.ops.segment_sum(per_block, block_expert,
-                             num_segments=w.shape[0]).astype(w.dtype)
-    return dx, dw, None, None
+                             num_segments=w.shape[0])
+    if w_t:
+        dw = dw.swapaxes(1, 2)
+    return dx, dw.astype(w.dtype), None, None
 
 
 grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
@@ -216,12 +244,13 @@ def _combine_rows(y, row_of, here, weights):
     return out
 
 
-def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
+def moe_apply(u, routing: Routing, w_up, w_down, *, first: int,
               n_experts: int, live=None, block_rows: int = 0):
     """The held experts' part of the expert layer for u [T, D].
 
-    w_gate_up [held, D, 2 F] (gate beside up), w_down [held, F, D];
-    the held experts are ids first .. first + held - 1 of the
+    w_up [held, D, 2 F] (gate beside up: a SwiGLU, silu(g) * u) or
+    [held, D, F] (two matrices an expert: relu(u)^2), w_down [held, F,
+    D]; the held experts are ids first .. first + held - 1 of the
     router's `n_experts`, which sizes a buffer (`buffer_pairs`).
     `live` [T] bool leaves padding tokens out (they would load the
     experts for nothing). The rows come back to their tokens by a
@@ -249,7 +278,7 @@ def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
     cap = buffer_pairs(T, k, held, n_experts)
     n_blocks = -(-cap // tm) + held        # every group's padding fits
     rows = n_blocks * tm
-    ub = u.astype(w_gate_up.dtype)
+    ub = u.astype(w_up.dtype)
     u_ext = jnp.concatenate([ub, jnp.zeros((1, D), ub.dtype)])
 
     def products(c):
@@ -277,9 +306,15 @@ def moe_apply(u, routing: Routing, w_gate_up, w_down, *, first: int,
             held - 1).astype(jnp.int32)
         n_used = g_end[-1] // tm
         x = u_ext[row_tok]
-        gu = grouped_matmul(x, w_gate_up, block_expert, n_used, tm)
-        h = (jax.nn.silu(gu[:, :F].astype(jnp.float32))
-             * gu[:, F:].astype(jnp.float32)).astype(x.dtype)
+        up_t = columns_first(w_up.shape[2])
+        gu = grouped_matmul(x, w_up.swapaxes(1, 2) if up_t else w_up,
+                            block_expert, n_used, tm, 512, None, up_t)
+        if w_up.shape[2] == 2 * F:
+            h = (jax.nn.silu(gu[:, :F].astype(jnp.float32))
+                 * gu[:, F:].astype(jnp.float32)).astype(x.dtype)
+        else:
+            h = jnp.square(jax.nn.relu(gu.astype(jnp.float32))).astype(
+                x.dtype)
         y = grouped_matmul(h, w_down, block_expert, n_used, tm, 512,
                            jnp.float32)
         return y, row_tok, dest

@@ -175,7 +175,7 @@ def seqrec_train(sequences: np.ndarray, targets: np.ndarray, *,
             seqs, tgts = batch
             value, g = jax.value_and_grad(loss)(params, seqs, tgts)
             for layer in range(len(cfg.layers)):
-                ffn = g[f"l{layer}"]["ffn"]
+                ffn = g[f"l{layer}"].get("ffn", {})
                 if "bias" in ffn:       # the correction bias is no weight
                     ffn["bias"] = jnp.zeros_like(ffn["bias"])
             updates, opt_state = opt.update(g, opt_state, params)
@@ -282,6 +282,12 @@ def _seq_metrics():
                 "experts' rows by a gather on the token side and not "
                 "by a scatter-add (ops/moe.gather_combine)",
                 buckets=share),
+            "ssm_reset": reg.histogram(
+                "pio_seq_ssm_reset_chunk_share",
+                "Share of one call's chunks of the state-space scan "
+                "(ssm_chunk tokens each) that hold a history's first "
+                "event, where the carried state is cut and what lies "
+                "before the event is masked", buckets=share),
         }
     return _SEQ_METRICS
 
@@ -401,6 +407,11 @@ class PackedEncoder:
                 metrics["pad"].observe(1.0 - n_tok / bucket)
                 for ln in lens:
                     metrics["events"].observe(float(ln))
+                if self.cfg.ssm_chunk:      # from the pack's layout
+                    chunk = self.cfg.ssm_chunk
+                    firsts = np.cumsum(lens) - lens
+                    metrics["ssm_reset"].observe(
+                        len(np.unique(firsts // chunk)) * chunk / bucket)
                 if stats is not None:
                     per = np.asarray(stats.expert_tokens, np.float64)
                     mean = per.mean(axis=1)

@@ -816,7 +816,8 @@ def test_the_packed_encoder_says_which_combine_a_call_ran(
         "name": "seq_moe_gather_combine_pct", "unit": "%",
         "better": "higher", "source": "program_counter",
         "layer": "expert layer", "moves": "serve_qps",
-        "workloads": ["lfm2-hist-c32", "mimo25-hist-c32"]}]
+        "workloads": ["lfm2-hist-c32", "mimo25-hist-c32",
+                      "nemotron-tt-hist-c32"]}]
 
 
 def test_lfm2_packed_stack_matches_reference_one_history_at_a_time(
@@ -914,6 +915,37 @@ def test_mimo_file_still_gives_todays_config_field_for_field():
         "n_experts": 256, "top_k": 8, "norm_topk_prob": True,
         "routed_scale": 1.0, "expert_first": 0, "experts_held": 16,
         "qk_norm": False, "conv_kernel": 0, "route_eps": 0.0,
+        **SSM_DEFAULTS,
+        "max_history": 2048, "max_batch_tokens": 8192,
+        "token_buckets": (512, 1024, 2048, 4096, 8192)}
+
+
+# the fields the third family brought, as the first two read them
+SSM_DEFAULTS = {"conv_bias": False, "ssm_heads": 0, "ssm_head_dim": 0,
+                "ssm_groups": 0, "ssm_state": 0, "ssm_chunk": 0,
+                "shared_width": 0}
+
+
+def test_lfm2_file_still_gives_todays_config_field_for_field():
+    """Written out from the parent commit's `config_from_json` (PR 34),
+    before its chains of keys became `_FAMILIES`."""
+    cfg = bb.load_config(str(CONFIGS / "lfm2-8b-a1b-pp2-12l.json"))
+    conv, attn = ("conv", "ffn_moe"), ("attn_full", "ffn_moe")
+    assert bb.config_dict(cfg) == {
+        "name": "lfm2-8b-a1b-pp2-12l", "hidden": 2048, "vocab": 65536,
+        "layers": (("conv", "ffn_dense"),) * 2 + (
+            attn, conv, conv, conv, attn, conv, conv, conv, attn, conv),
+        "n_heads": 32, "kv_heads_full": 8, "kv_heads_window": 8,
+        "qk_dim": 64, "v_dim": 64, "norm": "rms", "eps": 1e-05,
+        "act": "silu", "dense_width": 7168, "window": 0,
+        "sink_window": False, "sink_full": False, "rotary_dim": 64,
+        "rope_theta_full": 1000000.0, "rope_theta_window": 1000000.0,
+        "value_scale": 1.0, "positions": 0, "embed_scale": 1.0,
+        "tied": True, "pad_row": False, "expert_width": 1792,
+        "n_experts": 32, "top_k": 4, "norm_topk_prob": True,
+        "routed_scale": 1.0, "expert_first": 0, "experts_held": 32,
+        "qk_norm": True, "conv_kernel": 3, "route_eps": 1e-06,
+        **SSM_DEFAULTS,
         "max_history": 2048, "max_batch_tokens": 8192,
         "token_buckets": (512, 1024, 2048, 4096, 8192)}
 
@@ -964,3 +996,53 @@ def test_seqrec_train_runs_at_tiny_lfm2():
                   - np.asarray(init["l0"]["conv"]["kernel"])).max() > 0
     np.testing.assert_array_equal(m.params["l2"]["ffn"]["bias"],
                                   init["l2"]["ffn"]["bias"])
+
+
+# -- the third family's keys (its blocks: tests/test_backbone_ssm.py) --------
+
+NDOC = json.loads((ROOT / "benchmark" / "configs" / "tiny-nemotron.json")
+                  .read_text())
+
+
+def test_nemotron_published_widths_and_the_cut():
+    path = CONFIGS / "nemotron-tt-30b-a3b-ep2-13l.json"
+    doc = json.loads(path.read_text())
+    cfg = bb.load_config(str(path))
+    assert (cfg.hidden, cfg.n_heads, cfg.kv_heads_full, cfg.qk_dim,
+            cfg.v_dim, cfg.rotary_dim, cfg.positions, cfg.qk_norm) == (
+                2688, 32, 2, 128, 128, 0, 0, False)
+    assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state,
+            cfg.ssm_chunk, cfg.conv_kernel, cfg.conv_bias) == (
+                64, 64, 8, 128, 128, 4, True)
+    assert (cfg.n_experts, cfg.experts_held, cfg.expert_first, cfg.top_k,
+            cfg.expert_width, cfg.shared_width, cfg.act, cfg.routed_scale,
+            cfg.route_eps, cfg.vocab, cfg.tied, cfg.eps) == (
+                128, 64, 0, 6, 1856, 3712, "relu2", 2.5, 1e-20, 65536,
+                False, 1e-5)
+    assert "".join({"ssm": "M", "ffn_moe": "E", "attn_full": "*"}[b]
+                   for (b,) in cfg.layers) == "MEMEM*EMEMEM*" \
+        == doc["hybrid_override_pattern"][:13]
+    assert bb.n_params(cfg) == 3_926_018_560
+    assert len(doc["hybrid_override_pattern"]) == doc["published"][
+        "num_hidden_layers"] == 52
+    assert (doc["published"]["n_routed_experts"],
+            doc["published"]["vocab_size"]) == (128, 131072)
+    assert set(doc["reduced"]) == {"num_hidden_layers", "n_routed_experts",
+                                   "vocab_size", "n_users"}
+    assert all(b % cfg.ssm_chunk == 0 for b in cfg.token_buckets)
+    assert bb.config_of(json.loads(json.dumps(bb.config_dict(cfg)))) == cfg
+
+
+@pytest.mark.parametrize("bad", ["-", "X"])
+def test_a_pattern_letter_outside_m_e_star_is_refused(bad):
+    doc = dict(NDOC, hybrid_override_pattern="ME" + bad + "*EM*E")
+    with pytest.raises(ValueError, match="hybrid_override_pattern"):
+        bb.config_from_json(doc, "bad")
+
+
+def test_a_file_with_two_families_patterns_is_refused():
+    with pytest.raises(ValueError, match="one layer pattern"):
+        bb.config_from_json(dict(NDOC, layer_types=["conv"] * 8), "bad")
+    with pytest.raises(ValueError, match="one layer pattern"):
+        bb.config_from_json({k: v for k, v in NDOC.items()
+                             if k != "hybrid_override_pattern"}, "bad")

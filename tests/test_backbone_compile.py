@@ -157,6 +157,81 @@ def test_expert_layer_combines_by_gather_where_a_buffer_holds_every_pair(
     assert len(row_scatters) == scatters
 
 
+@pytest.mark.parametrize("tokens", [512, 8192])
+def test_ssm_chunk_scan_compiles_at_nemotrons_width(one_chip, as_tpu,
+                                                    tokens):
+    """Nemotron-H's Mamba-2 mixer: 64 heads of 64 in 8 groups, state
+    128, chunks of 128; bfloat16 operands, float32 steps and result.
+    The kernel slices a group's 8 heads at 64-lane offsets and carries
+    their states [8, 128, 64] in fast memory."""
+    from predictionio_tpu.ops import ssm
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda x, dt, a, b, c, first: ssm.chunk_scan(
+            x, dt, a, b, c, first, 128)).lower(
+        sds((tokens, 64, 64), jnp.bfloat16), sds((tokens, 64), jnp.float32),
+        sds((64,), jnp.float32), sds((tokens, 8, 128), jnp.bfloat16),
+        sds((tokens, 8, 128), jnp.bfloat16),
+        sds((tokens,), jnp.bool_)).compile()
+    assert _compiled_kernels(compiled) == 1
+
+
+def test_packed_attention_compiles_at_nemotrons_width(one_chip, as_tpu):
+    """32 query heads on 2 KV heads (a group of 16) of 128, no window,
+    no sink, 8,192 tokens: 128 query tokens a block."""
+    from predictionio_tpu.ops.attention import (
+        packed_attention, packed_block_sizes)
+    assert packed_block_sizes(8192, 16) == (128, 256)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, seg, start: packed_attention(
+            q, k, v, seg, start, max_segment=2048)).lower(
+        sds((8192, 32, 128), jnp.bfloat16), sds((8192, 2, 128), jnp.bfloat16),
+        sds((8192, 2, 128), jnp.bfloat16),
+        sds((8192,), jnp.int32), sds((8192,), jnp.int32)).compile()
+    assert _compiled_kernels(compiled) == 1
+
+
+def test_half_the_squared_relu_experts_combine_by_gather(one_chip, as_tpu):
+    """`moe_apply` at 8,192 tokens with 64 of 128 two-matrix experts of
+    width 1,856 over a hidden size of 2,688, top 6: one buffer of every
+    pair, two grouped products (the up product's 1,856 columns are 14.5
+    lane groups and go as one tile; the down product's 2,688 in tiles
+    of 384), no scatter of rows and no copy of the weights."""
+    from predictionio_tpu.ops import moe
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    T, D, F, held, n_experts, top_k = 8192, 2688, 1856, 64, 128, 6
+    assert moe.gather_combine(T, top_k, held, n_experts)
+    assert moe.buffer_pairs(T, top_k, held, n_experts) == T * top_k
+    assert (moe.col_block(F, 512), moe.col_block(D, 512),
+            moe.col_block(3584, 512), moe.col_block(64, 512)) == (
+                F, 384, 512, 64)
+    compiled = jax.jit(
+        lambda u, e, w, w_up, w_down, live: moe.moe_apply(
+            u, moe.Routing(e, w), w_up, w_down, first=0,
+            n_experts=n_experts, live=live)).lower(
+        sds((T, D), jnp.float32), sds((T, top_k), jnp.int32),
+        sds((T, top_k), jnp.float32), sds((held, D, F), jnp.bfloat16),
+        sds((held, F, D), jnp.bfloat16), sds((T,), jnp.bool_)).compile()
+    assert _compiled_kernels(compiled) == 2
+    assert not re.findall(rf"= f32\[{T},{D}\]\S* scatter\(",
+                          compiled.as_text())
+    # neither product re-lays its weights: the compiler keeps w_up
+    # [64, 2688, 1856] with the 2,688 on the lanes, and the kernel is
+    # handed its transpose (`moe.columns_first`)
+    assert moe.columns_first(F) and not moe.columns_first(D)
+    assert _moves_of(compiled, held * D * F) == []
+
+
 def _moves_of(compiled, cells: int) -> list:
     """The compiled program's `copy` and `transpose` instructions whose
     result holds at least `cells` elements: a catalog that the call
@@ -269,3 +344,18 @@ def test_fused_topk_compiles_over_the_whole_lfm2_vocabulary(
     compiled = _compiled_fused_bucket(one_chip, monkeypatch, 65536, 2048,
                                       bucket)
     _assert_reads_the_catalog_where_it_lies(compiled, 65536, 2048)
+
+
+@pytest.mark.parametrize("bucket", [1, 32, 64])
+def test_fused_topk_compiles_over_nemotrons_held_rows(one_chip, monkeypatch,
+                                                      bucket):
+    """65,536 rows of 2,688 (21 lane groups, no power of two; the head,
+    float32 for the plan), read where they lie, in tiles of 384: whole
+    lane groups inside the tile's bytes (512 rows ran the bucket of 32,
+    and no other, out of fast memory on the chip: my chip run, PR 36)."""
+    from predictionio_tpu.ops import fused_topk
+    assert fused_topk._tile_items(65536, 10, 2688) == (384, 384)
+    assert not fused_topk._items_on_lanes(2688)
+    compiled = _compiled_fused_bucket(one_chip, monkeypatch, 65536, 2688,
+                                      bucket)
+    _assert_reads_the_catalog_where_it_lies(compiled, 65536, 2688)
