@@ -19,7 +19,7 @@ from __future__ import annotations
 import threading
 import time
 from bisect import bisect_left
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 # latency-oriented defaults, seconds (Prometheus client defaults)
 DEFAULT_BUCKETS: Tuple[float, ...] = (
@@ -284,6 +284,7 @@ class MetricsRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._families: Dict[str, _Family] = {}
+        self._collectors: List[Callable[[], object]] = []
 
     def _get_or_create(self, cls, name: str, help: str, **kwargs) -> _Family:
         with self._lock:
@@ -315,7 +316,20 @@ class MetricsRegistry:
         return self._get_or_create(Histogram, name, help,
                                    labelnames=labels, buckets=buckets)
 
+    def add_collector(self, fn: Callable[[], object]) -> None:
+        """Run `fn()` before every read of the whole registry (render,
+        snapshot, a scrape): what it folds in is then in the read. It
+        runs on the reader's thread with no lock of this registry held,
+        so it may observe into any family; a reader must not hold a
+        family lock when it reads."""
+        with self._lock:
+            self._collectors.append(fn)
+
     def _families_snapshot(self) -> List[_Family]:
+        with self._lock:
+            collectors = list(self._collectors)
+        for fn in collectors:
+            fn()
         with self._lock:
             return list(self._families.values())
 

@@ -25,10 +25,16 @@ node, so memory stays O(budget) under pathological stack churn while
 hot paths (allocated early, sampled often) keep full depth.
 
 Alongside the sampler, this module owns the cheap runtime gauges:
-GC pauses via `gc.callbacks` (`pio_gc_pause_seconds{generation}`),
-host RSS/CPU/threads from `/proc/self`, and per-device memory from
-`jax.Device.memory_stats()` — all sampled on the tsdb scrape tick,
-not per-request.
+GC pauses via `gc.callbacks` (`pio_gc_pause_seconds{generation}`,
+folded into the registry by every read of it, and a `pio:gc.gen<n>`
+span on the profiler's clock around each collection), host
+RSS/CPU/threads from `/proc/self`, and per-device memory from
+`jax.Device.memory_stats()` — sampled on the tsdb scrape tick, not
+per-request.
+
+The observers' own cost: each tick of the sampler, the tsdb scraper and
+the watchdog runs inside a `pio:obs.<loop>` span (`obs_tick`) and
+observes its wall time into `pio_obs_tick_seconds{loop}`.
 """
 
 from __future__ import annotations
@@ -38,8 +44,11 @@ import os
 import sys
 import threading
 import time
-from typing import Dict, Iterable, List, Optional, Tuple
+from collections import deque
+from contextlib import contextmanager
+from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
+from predictionio_tpu.obs import trace
 from predictionio_tpu.obs.logs import get_logger
 from predictionio_tpu.obs.metrics import MetricsRegistry, get_registry
 from predictionio_tpu.utils.device import backend_initialized, live_devices
@@ -100,6 +109,35 @@ def format_thread_stack(ident: int, limit: int = 40) -> str:
     return " < ".join(parts)
 
 
+OBS_TICK_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                    0.025, 0.05, 0.1, 0.25, 1.0)
+
+
+def obs_tick_seconds(metrics: MetricsRegistry, loop: str):
+    """`loop`'s child of pio_obs_tick_seconds, resolved once by the
+    observer that owns the loop."""
+    return metrics.histogram(
+        "pio_obs_tick_seconds",
+        "Wall time of one tick of an observer loop (sampler, scraper, "
+        "watchdog): what watching the process costs it",
+        labels=("loop",), buckets=OBS_TICK_BUCKETS).labels(loop=loop)
+
+
+@contextmanager
+def obs_tick(child, loop: str) -> Iterator[None]:
+    """One tick of the observer `loop`: a `pio:obs.<loop>` span on the
+    profiler's clock, and its wall time into `child` (from
+    `obs_tick_seconds`)."""
+    ann = trace.span_open("pio:obs." + loop)
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        dt = time.perf_counter() - t0
+        trace.span_close(ann)
+        child.observe(dt)
+
+
 def _envf(name: str, default: float) -> float:
     try:
         return float(os.environ.get(name, "") or default)
@@ -124,7 +162,8 @@ class SamplingProfiler:
     Directly instantiable for tests; the process-global instance
     (one sampler sees every thread, so per-server instances would
     multiply the overhead for identical data) comes from
-    `ensure_started()`.
+    `ensure_started()`. Its ticks are observed into the process-default
+    registry, as the process is what it samples.
     """
 
     def __init__(self, hz: Optional[float] = None,
@@ -147,6 +186,7 @@ class SamplingProfiler:
         self._samples = 0            # thread-samples folded
         self._ticks = 0              # sampler wakeups
         self._started_at = 0.0
+        self._tick_seconds = obs_tick_seconds(get_registry(), "sampler")
 
     # -- lifecycle -----------------------------------------------------------
     @property
@@ -188,6 +228,10 @@ class SamplingProfiler:
         """Fold one sample of every live thread's stack; returns the
         number of threads folded. Public so tests can drive the fold
         deterministically without a live sampler thread."""
+        with obs_tick(self._tick_seconds, "sampler"):
+            return self._sample(skip_ident)
+
+    def _sample(self, skip_ident: Optional[int]) -> int:
         names = {t.ident: t.name for t in threading.enumerate()}
         frames = sys._current_frames()
         folded = 0
@@ -339,7 +383,11 @@ _gc_lock = threading.Lock()
 # id(registry) -> pauses the hook has seen and nobody has folded yet,
 # as (generation, seconds)
 _gc_pending: Dict[int, "deque"] = {}
-_gc_start_ns = 0
+# the collection in progress: its start (ns) and its span. CPython runs
+# collections (and hence callbacks) under a per-interpreter guard, so
+# one slot suffices
+_gc_open: List = [0, None]
+_GC_SPANS = {0: "pio:gc.gen0", 1: "pio:gc.gen1", 2: "pio:gc.gen2"}
 
 
 def _gc_histogram(metrics: MetricsRegistry):
@@ -350,38 +398,50 @@ def _gc_histogram(metrics: MetricsRegistry):
         labels=("generation",))
 
 
-def install_gc_callbacks(metrics: Optional[MetricsRegistry] = None) -> bool:
-    """Install a `gc.callbacks` hook timing every collection for
-    `pio_gc_pause_seconds{generation}`. Idempotent per registry (one
-    hook feeds one registry; a test registry gets its own). Returns
-    True on install, False for already-installed.
-
-    The hook only appends to a bounded deque; `flush_gc_pauses` folds
-    the pauses into the histogram from the tsdb tick and `/metrics`. A
-    collection runs inside whichever thread happened to allocate — one
+def _on_gc(phase: str, info: Dict) -> None:
+    """The one `gc.callbacks` hook: a `pio:gc.gen<n>` span around the
+    collection on the collecting thread, and its pause queued for every
+    hooked registry. It takes no lock and touches no registry family: a
+    collection runs inside whichever thread happened to allocate, one
     that may be holding this very family's (non-reentrant) lock, e.g.
-    halfway through rendering it — so a hook that observed directly
+    halfway through rendering it, and a hook that observed directly
     deadlocked that thread against itself and wedged every later
     `/metrics` behind it."""
-    from collections import deque
+    if phase == "start":
+        _gc_open[1] = trace.span_open(
+            _GC_SPANS.get(info.get("generation"), "pio:gc"))
+        _gc_open[0] = time.perf_counter_ns()
+    elif phase == "stop" and _gc_open[0]:
+        dt = (time.perf_counter_ns() - _gc_open[0]) / 1e9
+        ann, _gc_open[1] = _gc_open[1], None
+        trace.span_close(ann)
+        pause = (str(info.get("generation", "?")), dt)
+        # a tuple is built in C with the GIL held: no install can
+        # change the dict under the loop
+        for pending in tuple(_gc_pending.values()):
+            pending.append(pause)
+
+
+def install_gc_callbacks(metrics: Optional[MetricsRegistry] = None) -> bool:
+    """Time every collection for `pio_gc_pause_seconds{generation}` in
+    `metrics`. Idempotent per registry (a test registry gets its own
+    queue); one hook serves them all. Returns True on install, False
+    for already-installed.
+
+    The hook only appends to a bounded deque; `flush_gc_pauses` folds
+    the pauses into the histogram, as a collector of the registry
+    (`MetricsRegistry.add_collector`), so every read of the registry
+    (`/metrics`, a snapshot, the tsdb tick, the benchmark's window
+    delta) holds every pause that ended before it."""
     metrics = metrics if metrics is not None else get_registry()
     _gc_histogram(metrics)      # the family exists before the first pause
     with _gc_lock:
         if id(metrics) in _gc_pending:
             return False
-        pending = _gc_pending[id(metrics)] = deque(maxlen=65536)
-
-    def _on_gc(phase: str, info: Dict) -> None:
-        # CPython runs collections (and hence callbacks) under a
-        # per-interpreter guard, so one start slot suffices
-        global _gc_start_ns
-        if phase == "start":
-            _gc_start_ns = time.perf_counter_ns()
-        elif phase == "stop" and _gc_start_ns:
-            dt = (time.perf_counter_ns() - _gc_start_ns) / 1e9
-            pending.append((str(info.get("generation", "?")), dt))
-
-    gc.callbacks.append(_on_gc)
+        _gc_pending[id(metrics)] = deque(maxlen=65536)
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+    metrics.add_collector(lambda: flush_gc_pauses(metrics))
     return True
 
 
@@ -406,14 +466,12 @@ def flush_gc_pauses(metrics: Optional[MetricsRegistry] = None) -> int:
 
 # -- host /proc sampler -------------------------------------------------------
 class HostSampler:
-    """RSS / CPU seconds / thread count from `/proc/self`, whether this
-    process holds a JAX backend, and the queued GC pauses — set on the
-    tsdb tick and on every `/metrics`. CPU is a monotone counter
-    advanced by delta."""
+    """RSS / CPU seconds / thread count from `/proc/self` and whether
+    this process holds a JAX backend — set on the tsdb tick and on
+    every `/metrics`. CPU is a monotone counter advanced by delta."""
 
     def __init__(self, metrics: Optional[MetricsRegistry] = None):
-        m = self._metrics = (metrics if metrics is not None
-                             else get_registry())
+        m = metrics if metrics is not None else get_registry()
         self._rss = m.gauge("pio_host_rss_bytes",
                             "Resident set size of this process")
         self._threads = m.gauge("pio_host_threads",
@@ -431,7 +489,6 @@ class HostSampler:
 
     def sample(self) -> None:
         self._backend.set(1.0 if backend_initialized() else 0.0)
-        flush_gc_pauses(self._metrics)
         try:
             with open("/proc/self/statm", "rb") as fh:
                 self._rss.set(int(fh.read().split()[1]) * self._page)

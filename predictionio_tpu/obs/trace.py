@@ -33,9 +33,14 @@ instrument of that path: inside a cycle it stamps the interval into the
 record (from which `pio_serve_stage_seconds{stage=...}` is observed at
 the cycle's end, recorder on or off), and on any thread it enters a
 `jax.profiler.TraceAnnotation("pio:batch.<name>")`, so the interval
-lies in the `/host:CPU` plane of whatever profile is being taken. With
-the recorder enabled every cycle lands in the ring as `kind="batch"`
-and its members point at it by `batch_id`.
+lies in the `/host:CPU` plane of whatever profile is being taken. The
+record reads the drainer thread's own CPU time (`time.thread_time()`)
+at the same instants as its wall clock and charges it by the same rule,
+into `pio_serve_stage_cpu_seconds{stage=...}`: a stage's wall time less
+its CPU time is what the thread waited (the interpreter lock, a lock or
+condition, a socket, the device, the OS). With the recorder enabled
+every cycle lands in the ring as `kind="batch"` and its members point
+at it by `batch_id`.
 """
 
 from __future__ import annotations
@@ -122,6 +127,11 @@ STAGE_SECONDS_HELP = (
     "lookup, pack, launch, fetch, unpack, serve, encode, wake tile the "
     "cycle. Sums of others: predict = history..unpack, cycle = all of "
     "them, host = cycle - fetch - seq_fetch")
+STAGE_CPU_SECONDS_HELP = (
+    "The drainer thread's own CPU time (time.thread_time) in each stage "
+    "of a batch cycle, charged as pio_serve_stage_seconds charges wall "
+    "time; wall less CPU is time the thread waited: the interpreter "
+    "lock, a lock or condition, a socket, the device, the OS")
 
 
 class PendingTrace:
@@ -161,20 +171,25 @@ class BatchTrace:
     `PendingTrace`: slots and scalars, nothing built until the cycle
     ends. `start[i]` is the first start of stage i, `dur[i]` the sum of
     its intervals (a batch past the biggest bucket packs, launches and
-    fetches more than once). `hist` maps a stage's name (and `cycle`,
-    `host`) to its child of pio_serve_stage_seconds, so code below the
-    server never looks up a registry. A `solo` record is one
+    fetches more than once), `cpu[i]` the thread's CPU time in them,
+    read at the same instants. `hist` maps a stage's name (and `cycle`,
+    `host`) to its child of pio_serve_stage_seconds and `cpu_hist` to
+    its child of pio_serve_stage_cpu_seconds, so code below the server
+    never looks up a registry. A `solo` record is one
     `predict_batch` outside the drainer (no batcher, canary, batch
     predict): it observes the stages it passed and is neither a cycle
     nor kept."""
 
-    __slots__ = ("start", "dur", "t_begin", "t_last", "last", "closed",
-                 "batch_id", "rows", "bucket", "path", "hist", "solo")
+    __slots__ = ("start", "dur", "cpu", "t_begin", "t_last", "c_begin",
+                 "c_last", "last", "closed", "batch_id", "rows", "bucket",
+                 "path", "hist", "cpu_hist", "solo")
 
-    def __init__(self, hist=None, solo: bool = False):
+    def __init__(self, hist=None, solo: bool = False, cpu_hist=None):
         self.start = [0.0] * len(STAGES)
         self.dur = [0.0] * len(STAGES)
+        self.cpu = [0.0] * len(STAGES)
         self.t_begin = self.t_last = time.perf_counter()
+        self.c_begin = self.c_last = time.thread_time()
         self.last = -1           # the stage that closed last
         self.closed = 0          # how many closed so far
         self.batch_id = 0
@@ -183,6 +198,7 @@ class BatchTrace:
         self.path = ""           # host|device|sharded|fused, as the
         #                          call that dispatched set it
         self.hist = hist
+        self.cpu_hist = cpu_hist
         self.solo = solo
 
     def cycle_s(self) -> float:
@@ -191,6 +207,13 @@ class BatchTrace:
     def host_s(self) -> float:
         return (self.t_last - self.t_begin - self.dur[_IX_FETCH]
                 - self.dur[_IX_SEQ_FETCH])
+
+    def cycle_cpu_s(self) -> float:
+        return self.c_last - self.c_begin
+
+    def host_cpu_s(self) -> float:
+        return (self.c_last - self.c_begin - self.cpu[_IX_FETCH]
+                - self.cpu[_IX_SEQ_FETCH])
 
 
 # -- X-PIO-Trace codec (signed-header compatible with X-PIO-App) -------------
@@ -453,7 +476,8 @@ class TraceRecorder:
         t0 = bt.t_begin
         spans = [{"name": STAGES[i],
                   "start_ms": round((bt.start[i] - t0) * 1000.0, 3),
-                  "dur_ms": round(bt.dur[i] * 1000.0, 3)}
+                  "dur_ms": round(bt.dur[i] * 1000.0, 3),
+                  "cpu_ms": round(bt.cpu[i] * 1000.0, 3)}
                  for i in range(len(STAGES)) if bt.start[i] > 0.0]
         entry = {
             "trace_id": _new_trace_id(),
@@ -466,6 +490,7 @@ class TraceRecorder:
             "dispatch": bt.path,
             "duration_ms": round(bt.cycle_s() * 1000.0, 3),
             "host_ms": round(bt.host_s() * 1000.0, 3),
+            "host_cpu_ms": round(bt.host_cpu_s() * 1000.0, 3),
             "keep": "batch",
             "ts": time.time(),
             "batch_id": bt.batch_id,
@@ -728,11 +753,30 @@ def _annotation():
     return _ANNOTATION
 
 
+def span_open(name: str):
+    """Enter a `name` span on the profiler's clock (a TraceAnnotation,
+    on this thread's line of `/host:CPU`), or nothing where this
+    process has not loaded jax; hand the result to `span_close`. Takes
+    no lock and touches no registry, so a `gc.callbacks` hook may call
+    it."""
+    cls = _annotation()
+    if cls is None:
+        return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
+
+
+def span_close(ann) -> None:
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
 class _Stage:
     """One interval of `stage()`: a context manager, and the handle of
     the `stage_open` / `stage_close` pair."""
 
-    __slots__ = ("name", "ix", "bt", "t0", "mark", "ann")
+    __slots__ = ("name", "ix", "bt", "t0", "c0", "mark", "ann")
 
     def __init__(self, name: str):
         self.name = name
@@ -745,27 +789,28 @@ class _Stage:
         if bt is not None and self.ix >= 0:
             self.bt = bt
             self.t0 = bt.t_last
+            self.c0 = bt.c_last
             self.mark = bt.closed
             if bt.start[self.ix] == 0.0:
                 bt.start[self.ix] = bt.t_last
-        cls = _annotation()
-        if cls is not None:
-            self.ann = cls("pio:batch." + self.name)
-            self.ann.__enter__()
+        self.ann = span_open("pio:batch." + self.name)
         return self
 
     def __exit__(self, *exc):
-        if self.ann is not None:
-            self.ann.__exit__(*exc)
+        span_close(self.ann)
         bt = self.bt
         if bt is not None:
             now = time.perf_counter()
+            cnow = time.thread_time()
             if bt.closed != self.mark:
                 # stages closed inside this one: it is their parent, and
                 # what it did after the last of them is that one's
                 bt.dur[bt.last] += now - bt.t_last
+                bt.cpu[bt.last] += cnow - bt.c_last
             bt.dur[self.ix] += now - self.t0
+            bt.cpu[self.ix] += cnow - self.c0
             bt.t_last = now
+            bt.c_last = cnow
             bt.last = self.ix
             bt.closed += 1
         return False
@@ -791,11 +836,13 @@ def stage_close(handle: _Stage) -> None:
     handle.__exit__(None, None, None)
 
 
-def batch_begin(hist=None, solo: bool = False) -> BatchTrace:
+def batch_begin(hist=None, solo: bool = False,
+                cpu_hist=None) -> BatchTrace:
     """Open this thread's cycle record, now: a drainer calls it when
     its turn to form comes and its window opens, so stages tile a cycle
-    and the cycles of two drainers overlap."""
-    bt = BatchTrace(hist, solo)
+    and the cycles of two drainers overlap. `hist` and `cpu_hist` are
+    `stage_children` of the wall and the CPU family."""
+    bt = BatchTrace(hist, solo, cpu_hist)
     _TLS.batch = bt
     return bt
 
@@ -812,17 +859,21 @@ def batch_drop() -> None:
 
 def batch_end(bt: BatchTrace) -> float:
     """The cycle is over: observe every stage it passed, `cycle` and
-    `host` into the record's histogram children, and with the recorder
-    on put it in the ring. Returns the instant the cycle ended."""
+    `host` into the record's histogram children, wall and CPU, and with
+    the recorder on put it in the ring. Returns the instant the cycle
+    ended."""
     _TLS.batch = None
-    hist = bt.hist
-    if hist is not None:
+    for hist, vals, cycle, host in (
+            (bt.hist, bt.dur, bt.cycle_s, bt.host_s),
+            (bt.cpu_hist, bt.cpu, bt.cycle_cpu_s, bt.host_cpu_s)):
+        if hist is None:
+            continue
         for i in range(len(STAGES)):
             if bt.start[i] > 0.0:
-                hist[STAGES[i]].observe(bt.dur[i])
+                hist[STAGES[i]].observe(vals[i])
         if not bt.solo:
-            hist["cycle"].observe(bt.cycle_s())
-            hist["host"].observe(bt.host_s())
+            hist["cycle"].observe(cycle())
+            hist["host"].observe(host())
     if not bt.solo:
         rec = _REC
         if rec is not None and rec.enabled:
@@ -841,8 +892,9 @@ def note_dispatch(path: str, bucket: int = 0) -> None:
 
 
 def stage_children(family) -> Dict[str, Any]:
-    """Every cycle stage's child of a pio_serve_stage_seconds family,
-    resolved once (the drainer then observes without a labels() call)."""
+    """Every cycle stage's child of a pio_serve_stage_seconds (or
+    pio_serve_stage_cpu_seconds) family, resolved once (the drainer
+    then observes without a labels() call)."""
     return {name: family.labels(stage=name)
             for name in STAGES + ("cycle", "host")}
 

@@ -35,6 +35,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 from predictionio_tpu.obs.logs import get_logger
 from predictionio_tpu.obs.metrics import MetricsRegistry
+from predictionio_tpu.obs.profiler import obs_tick, obs_tick_seconds
 
 _log = get_logger("tsdb")
 
@@ -229,6 +230,7 @@ class Scraper:
         self.interval_s = (_envf("PIO_TSDB_INTERVAL_S", DEFAULT_INTERVAL_S)
                            if interval_s is None else interval_s)
         self.collectors: List[Callable[[], None]] = list(collectors)
+        self._tick_seconds = obs_tick_seconds(registry, "scraper")
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._beat = None
@@ -242,14 +244,16 @@ class Scraper:
         """One scrape cycle: collectors first (they freshen gauges the
         snapshot then captures), then the registry fold. Public so
         tests and the fleet router can force a tick."""
-        for fn in self.collectors:
-            try:
-                fn()
-            except Exception as e:    # a broken collector must not
-                _log.warning("tsdb_collector_failed",   # stop the scrape
-                             collector=getattr(fn, "__name__", "?"),
-                             error=f"{type(e).__name__}: {e}")
-        self.tsdb.record_snapshot(self.registry.snapshot(), now)
+        with obs_tick(self._tick_seconds, "scraper"):
+            for fn in self.collectors:
+                try:
+                    fn()
+                except Exception as e:    # a broken collector must not
+                    _log.warning(                 # stop the scrape
+                        "tsdb_collector_failed",
+                        collector=getattr(fn, "__name__", "?"),
+                        error=f"{type(e).__name__}: {e}")
+            self.tsdb.record_snapshot(self.registry.snapshot(), now)
 
     def _run(self) -> None:
         self._beat.guard(self._run_loop)

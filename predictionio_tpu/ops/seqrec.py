@@ -250,11 +250,6 @@ def _seq_metrics():
                 "pio_seq_pad_share",
                 "Padding tokens over the token bucket, per call of the "
                 "packed stack", buckets=share),
-            "events": reg.histogram(
-                "pio_seq_history_events",
-                "Events of one query's history as encoded (after the "
-                "cut to max_history)",
-                buckets=tuple(float(2 ** i) for i in range(0, 13))),
             "load": reg.histogram(
                 "pio_moe_expert_tokens_max_over_mean",
                 "Tokens of the busiest held expert over the held "
@@ -410,8 +405,6 @@ class PackedEncoder:
             try:
                 metrics["tokens"].observe(n_tok)
                 metrics["pad"].observe(1.0 - n_tok / bucket)
-                for ln in lens:
-                    metrics["events"].observe(float(ln))
                 if self.cfg.ssm_chunk:      # from the pack's layout
                     chunk = self.cfg.ssm_chunk
                     firsts = np.cumsum(lens) - lens

@@ -39,6 +39,7 @@ import time
 from typing import Callable, Dict, List, Optional
 
 from predictionio_tpu.obs import get_logger, get_registry
+from predictionio_tpu.obs.profiler import obs_tick, obs_tick_seconds
 from predictionio_tpu.resilience.faults import faults
 
 _log = get_logger(__name__)
@@ -205,6 +206,7 @@ class Watchdog:
         self._restarts = reg.counter(
             "pio_thread_restarts_total",
             "Loop threads respawned by the watchdog", labels=("role",))
+        self._tick_seconds = obs_tick_seconds(reg, "watchdog")
 
     # -- registration -------------------------------------------------------
     def register(self, role: str, budget_s: float = 0.0,
@@ -274,6 +276,10 @@ class Watchdog:
     def sweep(self) -> None:
         """One pass over all beats: export ages, detect stalls/deaths,
         run due restarts. Public so tests drive it synchronously."""
+        with obs_tick(self._tick_seconds, "watchdog"):
+            self._sweep()
+
+    def _sweep(self) -> None:
         now = time.monotonic()
         alive = {t.ident for t in threading.enumerate()}
         with self._lock:

@@ -104,9 +104,13 @@ class _ServeInstruments:
         self.stage = metrics.histogram(
             "pio_serve_stage_seconds", trace.STAGE_SECONDS_HELP,
             labels=("stage",))
+        self.stage_cpu = metrics.histogram(
+            "pio_serve_stage_cpu_seconds", trace.STAGE_CPU_SECONDS_HELP,
+            labels=("stage",))
         # a batch cycle's stages, resolved once: the cycle's record
         # (obs/trace.BatchTrace) carries them down to ops/
         self.cycle_stages = trace.stage_children(self.stage)
+        self.cycle_cpu = trace.stage_children(self.stage_cpu)
         self.algo = metrics.histogram(
             "pio_serve_algo_predict_seconds",
             "Per-algorithm batch_predict wall time", labels=("algo",))
@@ -437,7 +441,8 @@ class _Deployment:
         predict work overlaps; ordering and the isolation contract are
         unchanged (results land positionally)."""
         obs = self.obs
-        solo = (trace.batch_begin(obs.cycle_stages, solo=True)
+        solo = (trace.batch_begin(obs.cycle_stages, solo=True,
+                                  cpu_hist=obs.cycle_cpu)
                 if trace.current_batch() is None else None)
         try:
             return self._predict_batch(queries)
@@ -819,7 +824,7 @@ class _MicroBatcher:
         # it opens with the cycle's window, so stages tile a cycle and
         # the two drainers' cycles overlap; a drainer's wait for its
         # turn belongs to no cycle, as an empty window belongs to none
-        stages = self.obs.cycle_stages
+        stages, cpu = self.obs.cycle_stages, self.obs.cycle_cpu
         forming = False
         flying = 0                   # rows of this drainer's taken batch
         try:
@@ -830,7 +835,7 @@ class _MicroBatcher:
                         self._turn.wait(self.submit_timeout_s)
                         wd_beat.tick()
                     self._forming = forming = True
-                    bt = trace.batch_begin(stages)
+                    bt = trace.batch_begin(stages, cpu_hist=cpu)
                     h = trace.stage_open("window")
                     # wait out the window — but a full batch forming
                     # mid-window notifies the condition and ships NOW

@@ -484,7 +484,6 @@ def test_queries_through_the_server_compile_nothing_and_tile_the_cycle(
     srv, hists, rp = served
     before = {name: get_registry().histogram(name).labels().count
               for name in ("pio_seq_call_tokens", "pio_seq_pad_share",
-                           "pio_seq_history_events",
                            "pio_moe_expert_tokens_max_over_mean",
                            "pio_moe_unrouted_share",
                            "pio_moe_expert_pairs",

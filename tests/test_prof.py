@@ -177,7 +177,8 @@ class TestGCPauseHook:
         server hung from ~40 s after start."""
         reg = MetricsRegistry()
         install_gc_callbacks(reg)
-        hook = gc.callbacks[-1]
+        hook = prof_mod._on_gc
+        assert hook in gc.callbacks
         fam = reg.histogram("pio_gc_pause_seconds", "", labels=(
             "generation",), buckets=(0.0001, 0.0005, 0.001, 0.005, 0.01,
                                      0.05, 0.1, 0.5))
@@ -195,6 +196,129 @@ class TestGCPauseHook:
         assert done.wait(5), "gc hook blocked on the family lock"
         assert prof_mod.flush_gc_pauses(reg) == 1
         assert reg.render().count("pio_gc_pause_seconds_count") == 1
+
+    def test_a_collection_in_a_cycle_is_in_the_next_read(
+            self, clean_gc_hooks):
+        """The benchmark's window delta reads the registry through
+        `_families_snapshot` and never calls /metrics: every pause that
+        ended before a read is in it."""
+        from predictionio_tpu.obs import trace
+        reg = MetricsRegistry()
+        install_gc_callbacks(reg)
+
+        def gen2():
+            for fam in reg._families_snapshot():
+                if fam.name == "pio_gc_pause_seconds":
+                    return {key: (c.count, c.sum)
+                            for key, c in fam._items()}.get(("2",), (0, 0))
+
+        before = gen2()
+        bt = trace.batch_begin()
+        with trace.stage("unpack"):
+            gc.collect()
+        trace.batch_end(bt)
+        count, total = gen2()
+        assert count == before[0] + 1 and total > before[1]
+        assert prof_mod.flush_gc_pauses(reg) == 0      # nothing left
+
+    def test_collect_under_the_family_lock_then_read(self, clean_gc_hooks):
+        """PR 21's case with a real collection: the thread that holds
+        the gc family's lock collects; the hook takes no lock, and the
+        fold runs at the next read, on a thread that holds none."""
+        reg = MetricsRegistry()
+        install_gc_callbacks(reg)
+        fam = prof_mod._gc_histogram(reg)
+        done = threading.Event()
+
+        def collect_under_the_lock():
+            with fam._lock:
+                gc.collect()
+            done.set()
+
+        t = threading.Thread(target=collect_under_the_lock, daemon=True,
+                             name="pio-test-gc")
+        t.start()
+        assert done.wait(10), "a collection blocked on the family lock"
+        assert 'pio_gc_pause_seconds_count{generation="2"}' in reg.render()
+
+
+class _StandIn:
+    """A stand-in for jax.profiler.TraceAnnotation: records every enter
+    and exit, by thread."""
+
+    events = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _StandIn.events.append(("enter", self.name, threading.get_ident()))
+        return self
+
+    def __exit__(self, *exc):
+        _StandIn.events.append(("exit", self.name, threading.get_ident()))
+        return False
+
+
+def _pairs(events, prefix, thread=None):
+    """The spans named `prefix...`, checked to open and close in pairs,
+    nested, on each thread; returns the names of those `thread` (any
+    where None) closed, in closing order: the live loops of other
+    tests may tick beside this one's."""
+    stacks, closed = {}, []
+    for kind, name, ident in events:
+        if not name.startswith(prefix):
+            continue
+        stack = stacks.setdefault(ident, [])
+        if kind == "enter":
+            stack.append(name)
+        else:
+            assert stack and stack[-1] == name, (name, stack)
+            stack.pop()
+            if thread in (None, ident):
+                closed.append(name)
+    assert not any(stacks.values()), stacks
+    return closed
+
+
+class TestObserverSpans:
+    @pytest.fixture()
+    def stand_in(self, monkeypatch):
+        from predictionio_tpu.obs import trace
+        _StandIn.events = []
+        monkeypatch.setattr(trace, "_ANNOTATION", _StandIn)
+        return _StandIn
+
+    def test_gc_spans_open_and_close_in_pairs(self, stand_in,
+                                              clean_gc_hooks):
+        install_gc_callbacks(MetricsRegistry())
+        gc.collect()
+        gc.collect(0)
+        closed = _pairs(stand_in.events, "pio:gc.")
+        assert "pio:gc.gen2" in closed and "pio:gc.gen0" in closed
+
+    def test_each_observer_tick_is_a_span_and_an_observation(
+            self, stand_in):
+        from predictionio_tpu.obs import get_registry
+        from predictionio_tpu.resilience.watchdog import Watchdog
+        default = get_registry()
+        reg = MetricsRegistry()
+
+        def ticks(metrics, loop):
+            return prof_mod.obs_tick_seconds(metrics, loop).count
+
+        before = (ticks(default, "sampler"), ticks(reg, "scraper"),
+                  ticks(default, "watchdog"))
+        SamplingProfiler(hz=0).sample_once()
+        Scraper(TSDB(points=4), reg, interval_s=0).tick()
+        Watchdog(stall_s=1.0, interval_s=1.0).sweep()
+        assert (ticks(default, "sampler"), ticks(reg, "scraper"),
+                ticks(default, "watchdog")) == tuple(b + 1 for b in before)
+        closed = _pairs(stand_in.events, "pio:obs.",
+                        thread=threading.get_ident())
+        assert sorted(closed) == ["pio:obs.sampler", "pio:obs.scraper",
+                                  "pio:obs.watchdog"]
+        assert prof_mod.obs_tick_seconds(reg, "scraper").sum > 0
 
 
 # -- tsdb ring --------------------------------------------------------------

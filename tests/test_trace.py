@@ -530,9 +530,12 @@ class TestBatchCycle:
             status, text = call(srv.port, "GET", "/metrics")
             assert status == 200
             for name in _STAGES + ("cycle", "host"):
-                line = next(ln for ln in text.splitlines() if ln.startswith(
-                    f'pio_serve_stage_seconds_count{{stage="{name}"}}'))
-                assert float(line.split()[-1]) >= 1, name
+                for fam in ("pio_serve_stage_seconds",
+                            "pio_serve_stage_cpu_seconds"):
+                    line = next(
+                        ln for ln in text.splitlines() if ln.startswith(
+                            f'{fam}_count{{stage="{name}"}}'))
+                    assert float(line.split()[-1]) >= 1, (fam, name)
             obs = srv._serve_obs
             # both routes, every request: fast path and generic
             assert obs.worker_wait.labels().count == sent
@@ -643,6 +646,10 @@ class TestBatchCycle:
                 == pytest.approx(b["duration_ms"], abs=0.02)
             assert b["host_ms"] == pytest.approx(
                 b["duration_ms"] - spans["fetch"]["dur_ms"], abs=0.01)
+            # the thread's CPU clock, read where the wall clock is
+            assert 0.0 <= b["host_cpu_ms"] <= b["host_ms"] + 0.05
+            assert all(0.0 <= s["cpu_ms"] <= s["dur_ms"] + 0.05
+                       for s in b["spans"])
             status, body = call(
                 srv.port, "GET",
                 f"/traces.json?batch_id={member['batch_id']}")
@@ -774,6 +781,97 @@ class TestBatchCycle:
         t.join()
         trace.batch_drop()
         assert seen == [None]
+
+
+def _wall_and_cpu():
+    m = MetricsRegistry()
+    wall = trace.stage_children(m.histogram(
+        "pio_serve_stage_seconds", trace.STAGE_SECONDS_HELP,
+        labels=("stage",)))
+    cpu = trace.stage_children(m.histogram(
+        "pio_serve_stage_cpu_seconds", trace.STAGE_CPU_SECONDS_HELP,
+        labels=("stage",)))
+    return wall, cpu
+
+
+def _spin(seconds):
+    t_end = time.thread_time() + seconds
+    while time.thread_time() < t_end:
+        pass
+
+
+class TestCycleCpu:
+    """The drainer thread's CPU time beside the wall time of every
+    stage, read at the same instants and charged by the same rule."""
+
+    def test_a_spinning_stage_is_cpu_and_a_sleeping_one_is_not(self):
+        # best of three: a spin that the OS preempts on a loaded
+        # machine reads wall above CPU, and that is the OS's
+        for _ in range(3):
+            wall, cpu = _wall_and_cpu()
+            bt = trace.batch_begin(wall, cpu_hist=cpu)
+            with trace.stage("pack"):
+                _spin(0.05)
+            with trace.stage("launch"):
+                time.sleep(0.05)
+            trace.batch_end(bt)
+            spin_w, spin_c = wall["pack"].sum, cpu["pack"].sum
+            if spin_c >= 0.9 * spin_w:
+                break
+        assert 0.9 * spin_w <= spin_c <= spin_w + 1e-3
+        assert wall["launch"].sum >= 0.05
+        assert cpu["launch"].sum < 0.1 * wall["launch"].sum
+        assert cpu["cycle"].count == cpu["host"].count == 1
+
+    def test_parent_and_children_charged_as_wall_time_is(self):
+        wall, cpu = _wall_and_cpu()
+        bt = trace.batch_begin(wall, cpu_hist=cpu)
+        with trace.stage("window"):
+            time.sleep(0.01)
+        with trace.stage("predict"):
+            _spin(0.005)                 # glue before the first child
+            with trace.stage("lookup"):
+                _spin(0.01)
+            with trace.stage("fetch"):
+                time.sleep(0.01)
+            _spin(0.01)                  # the tail: the last child's
+        with trace.stage("wake"):
+            _spin(0.002)
+        trace.batch_end(bt)
+        ix = trace.STAGES.index
+        children = ("lookup", "fetch")
+        for vals in (bt.dur, bt.cpu):
+            assert vals[ix("predict")] == pytest.approx(
+                sum(vals[ix(n)] for n in children), abs=1e-9)
+        # the tail after `fetch` is fetch's, in CPU as in wall time
+        assert bt.cpu[ix("fetch")] >= 0.009
+        # the leaves tile the cycle, and host leaves fetch out
+        leaves = ("window", "lookup", "fetch", "wake")
+        assert bt.cycle_cpu_s() == pytest.approx(
+            sum(bt.cpu[ix(n)] for n in leaves), abs=1e-9)
+        assert bt.host_cpu_s() == pytest.approx(
+            bt.cycle_cpu_s() - bt.cpu[ix("fetch")], abs=1e-9)
+        for name in ("window", "predict", "lookup", "fetch", "wake",
+                     "cycle", "host"):
+            assert cpu[name].count == wall[name].count == 1, name
+            assert cpu[name].sum <= wall[name].sum + 1e-4, name
+        assert cpu["window"].sum < 0.5 * wall["window"].sum
+        assert cpu["pack"].count == 0
+
+    def test_a_solo_record_observes_the_stages_it_passed(self):
+        wall, cpu = _wall_and_cpu()
+        bt = trace.batch_begin(wall, solo=True, cpu_hist=cpu)
+        with trace.stage("pack"):
+            _spin(0.002)
+        trace.batch_end(bt)
+        assert cpu["pack"].count == wall["pack"].count == 1
+        assert cpu["cycle"].count == cpu["host"].count == 0
+        # a record without the CPU family observes wall time alone
+        bt = trace.batch_begin(wall)
+        with trace.stage("pack"):
+            pass
+        trace.batch_end(bt)
+        assert wall["pack"].count == 2 and cpu["pack"].count == 1
 
 
 def test_obs_imports_without_jax():
