@@ -22,7 +22,9 @@ import secrets
 import re
 from dataclasses import dataclass, field, replace
 from datetime import datetime
-from typing import Any, Dict, Iterator, List, Mapping, Optional, Sequence
+from typing import (
+    Any, Callable, Dict, Iterator, List, Mapping, Optional, Sequence,
+)
 
 from predictionio_tpu.data.aggregate import aggregate_properties
 from predictionio_tpu.data.event import Event, PropertyMap, utcnow
@@ -663,33 +665,48 @@ class EventStore(abc.ABC):
         return aggregate_properties_single(events)
 
 
-def match_event(e: Event, *,
-                start_time: Optional[datetime] = None,
-                until_time: Optional[datetime] = None,
-                entity_type: Optional[str] = None,
-                entity_id: Optional[str] = None,
-                event_names: Optional[Sequence[str]] = None,
-                target_entity_type: object = _UNSET,
-                target_entity_id: object = _UNSET,
-                properties: Optional[Dict[str, object]] = None) -> bool:
-    """Shared in-memory filter predicate implementing `find` semantics."""
-    if properties and not match_properties(e, properties):
-        return False
-    if start_time is not None and e.event_time < _aware(start_time):
-        return False
-    if until_time is not None and e.event_time >= _aware(until_time):
-        return False
-    if entity_type is not None and e.entity_type != entity_type:
-        return False
-    if entity_id is not None and e.entity_id != entity_id:
-        return False
-    if event_names is not None and e.event not in set(event_names):
-        return False
-    if target_entity_type is not _UNSET and e.target_entity_type != target_entity_type:
-        return False
-    if target_entity_id is not _UNSET and e.target_entity_id != target_entity_id:
-        return False
-    return True
+def event_filter(*,
+                 start_time: Optional[datetime] = None,
+                 until_time: Optional[datetime] = None,
+                 entity_type: Optional[str] = None,
+                 entity_id: Optional[str] = None,
+                 event_names: Optional[Sequence[str]] = None,
+                 target_entity_type: object = _UNSET,
+                 target_entity_id: object = _UNSET,
+                 properties: Optional[Dict[str, object]] = None
+                 ) -> Callable[[Event], bool]:
+    """`find`'s filter semantics as one in-memory predicate, built once a
+    read: it tests only the filters the call gives, and the event names
+    as a set made here."""
+    tests: List[Callable[[Event], bool]] = []
+    if start_time is not None:
+        lo = _aware(start_time)
+        tests.append(lambda e: e.event_time >= lo)
+    if until_time is not None:
+        hi = _aware(until_time)
+        tests.append(lambda e: e.event_time < hi)
+    if entity_type is not None:
+        tests.append(lambda e: e.entity_type == entity_type)
+    if entity_id is not None:
+        tests.append(lambda e: e.entity_id == entity_id)
+    if event_names is not None:
+        names = frozenset(event_names)
+        tests.append(lambda e: e.event in names)
+    if target_entity_type is not _UNSET:
+        tests.append(lambda e: e.target_entity_type == target_entity_type)
+    if target_entity_id is not _UNSET:
+        tests.append(lambda e: e.target_entity_id == target_entity_id)
+    if properties:
+        tests.append(lambda e: match_properties(e, properties))
+    if len(tests) == 1:
+        return tests[0]
+
+    def match(e: Event) -> bool:
+        for test in tests:
+            if not test(e):
+                return False
+        return True
+    return match
 
 
 def _aware(t: datetime) -> datetime:

@@ -184,15 +184,15 @@ class EvlogEvents(base.EventStore):
              properties=None,
              limit: Optional[int] = None,
              reversed: bool = False) -> Iterator[Event]:
-        events = [
-            e for e in self._replay(app_id, channel_id).values()
-            if base.match_event(
-                e, start_time=start_time, until_time=until_time,
-                entity_type=entity_type, entity_id=entity_id,
-                event_names=event_names,
-                target_entity_type=target_entity_type,
-                target_entity_id=target_entity_id,
-                properties=properties)]
+        match = base.event_filter(
+            start_time=start_time, until_time=until_time,
+            entity_type=entity_type, entity_id=entity_id,
+            event_names=event_names,
+            target_entity_type=target_entity_type,
+            target_entity_id=target_entity_id,
+            properties=properties)
+        events = [e for e in self._replay(app_id, channel_id).values()
+                  if match(e)]
         events.sort(key=lambda e: e.event_time, reverse=reversed)
         if limit is not None and limit > 0:
             events = events[:limit]

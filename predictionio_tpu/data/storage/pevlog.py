@@ -1089,6 +1089,11 @@ class PevlogEvents(base.EventStore):
         start_us = _us(start_time) if start_time is not None else None
         until_us = _us(until_time) if until_time is not None else None
         dead = self._tombstones(part)
+        match = base.event_filter(
+            start_time=start_time, until_time=until_time,
+            entity_type=entity_type, entity_id=entity_id,
+            event_names=event_names, target_entity_type=target_entity_type,
+            target_entity_id=target_entity_id, properties=properties)
         events: List[Event] = []
         for seg in self._segments(part):
             if not self._segment_survives(
@@ -1102,15 +1107,7 @@ class PevlogEvents(base.EventStore):
                 continue
             self.c.stats["segments_scanned"] += 1
             for e in self._replay_segment(seg).values():
-                if not self._live(e, dead):
-                    continue
-                if base.match_event(
-                        e, start_time=start_time, until_time=until_time,
-                        entity_type=entity_type, entity_id=entity_id,
-                        event_names=event_names,
-                        target_entity_type=target_entity_type,
-                        target_entity_id=target_entity_id,
-                        properties=properties):
+                if self._live(e, dead) and match(e):
                     events.append(e)
         events.sort(key=lambda e: e.event_time, reverse=reversed)
         if limit is not None and limit > 0:
@@ -1347,8 +1344,8 @@ class PevlogEvents(base.EventStore):
     def _event_block(self, table: Dict[str, Event], dead, filters,
                      spec, require_target: bool) -> tuple:
         """Event-object fallback block for one replayed segment."""
-        evs = [e for e in table.values()
-               if self._live(e, dead) and base.match_event(e, **filters)]
+        match = base.event_filter(**filters)
+        evs = [e for e in table.values() if self._live(e, dead) and match(e)]
         return columns.block_from_events(evs, spec, require_target)
 
     # -- prepared-data cache support -----------------------------------------
@@ -1394,6 +1391,8 @@ class PevlogEvents(base.EventStore):
         start_us = _us(start_time) if start_time is not None else None
         until_us = _us(until_time) if until_time is not None else None
         dead = self._tombstones(part)
+        in_window = base.event_filter(start_time=start_time,
+                                      until_time=until_time)
         rows: List[tuple] = []   # (tus, seq, name, entity_id, props|None)
         seq = 0
         for seg in self._segments(part):
@@ -1416,9 +1415,7 @@ class PevlogEvents(base.EventStore):
                     if e.event not in name_set \
                             or e.entity_type != entity_type \
                             or not self._live(e, dead) \
-                            or not base.match_event(
-                                e, start_time=start_time,
-                                until_time=until_time):
+                            or not in_window(e):
                         continue
                     rows.append((columns._event_us(e), seq, e.event,
                                  e.entity_id, e.properties._fields))

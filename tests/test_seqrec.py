@@ -215,3 +215,126 @@ class TestEngineTemplate:
         res = algo.predict(model, sr.Query(user="u3", num=5))
         assert len(res.itemScores) == 5, \
             "history emptied by unknown-item burst"
+
+    @staticmethod
+    def _served(n_users=40):
+        """A small trained model, and each user's events written newest
+        first, so the store has to order them."""
+        from datetime import datetime, timedelta, timezone
+        from predictionio_tpu.data.event import DataMap, Event
+        from predictionio_tpu.ingest import BiMap
+        from predictionio_tpu.models import seqrec as sr
+
+        u, i, t, n_items = _markov_events(n_users=n_users, n_items=20,
+                                          seed=4)
+        seqs, targets = build_sequences(u, i, t, n_items=n_items,
+                                        seq_len=8)
+        net = seqrec_train(seqs, targets, n_items=n_items, seq_len=8,
+                           dim=16, n_heads=2, n_layers=1,
+                           batch_size=len(seqs), epochs=1, seed=0)
+        model = sr.SeqRecServingModel(
+            net, BiMap({f"u{n}": n for n in range(n_users)}),
+            BiMap({f"i{n}": n for n in range(n_items)}))
+        t0 = datetime(2024, 1, 1, tzinfo=timezone.utc)
+        batch = [Event(event="view", entity_type="user",
+                       entity_id=f"u{uu}", target_entity_type="item",
+                       target_entity_id=f"i{ii}", properties=DataMap({}),
+                       event_time=t0 + timedelta(minutes=int(tt)))
+                 for uu, ii, tt in zip(u[::-1], i[::-1], t[::-1])]
+        return model, batch
+
+    @staticmethod
+    def _algo(reg):
+        from predictionio_tpu.core import RuntimeContext
+        from predictionio_tpu.models import seqrec as sr
+        algo = sr.SeqRecAlgorithm(sr.SeqRecParams(
+            app_name="seqapp", seq_len=8, dim=16, n_heads=2, n_layers=1))
+        algo.with_serving_context(RuntimeContext(registry=reg))
+        return algo
+
+    def test_history_read_counts_the_reads_the_store_answers_in_order(
+            self, registry, tmp_path, monkeypatch):
+        """`pio_mem_find_in_order`, once a read: every history read of a
+        cycle on MEM walks the user's events kept in time order (1 each),
+        a read that names no user scans (0), and SQLITE, which has no
+        such way, counts nothing; the same histories, so the same
+        replies. Only this thread's observations are compared: the
+        histogram is the process's, and other threads may read MEM."""
+        import threading
+
+        from predictionio_tpu.data.storage import App, StorageRegistry
+        from predictionio_tpu.data.storage import memory
+        from predictionio_tpu.models import seqrec as sr
+        from predictionio_tpu.obs import get_registry
+
+        hist = memory._in_order()
+        assert hist is get_registry().histogram("pio_mem_find_in_order")
+        me, seen = threading.get_ident(), []
+
+        class Mine:
+            def observe(self, value):
+                if threading.get_ident() == me:
+                    seen.append(value)
+                hist.observe(value)
+        monkeypatch.setattr(memory, "_in_order", Mine)
+
+        model, batch = self._served()
+        sqlite = StorageRegistry({
+            "PIO_STORAGE_SOURCES_SQLITE_TYPE": "SQLITE",
+            "PIO_STORAGE_SOURCES_SQLITE_PATH": str(tmp_path / "pio.db"),
+            "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "SQLITE",
+            "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "SQLITE",
+            "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "SQLITE"})
+        queries = [(k, sr.Query(user=f"u{k}", num=3)) for k in range(6)]
+        queries.append((6, sr.Query(user="nobody", num=3)))
+        replies = {}
+        for name, reg in (("mem", registry), ("sqlite", sqlite)):
+            app_id = reg.get_meta_data_apps().insert(App(0, "seqapp"))
+            events = reg.get_events()
+            events.init(app_id)
+            events.insert_batch(batch, app_id)
+            del seen[:]
+            out = self._algo(reg).batch_predict(model, queries)
+            list(events.find(app_id, entity_type="user"))
+            assert seen == ([1.0] * 7 + [0.0] if name == "mem" else [])
+            replies[name] = sorted(
+                (k, [(s.item, s.score) for s in r.itemScores])
+                for k, r in out)
+        assert replies["mem"] == replies["sqlite"]
+        assert [len(r) for _, r in replies["mem"]] == [3] * 6 + [0]
+
+    def test_failing_history_reads_trip_the_storage_breaker(self):
+        """Counting the ordered reads makes no call through the storage
+        wrapper: history reads whose `find` fails open the event
+        source's breaker after its threshold, and the next cycle fails
+        fast."""
+        from predictionio_tpu.data.storage import App, StorageRegistry
+        from predictionio_tpu.models import seqrec as sr
+        from predictionio_tpu.resilience import CircuitOpenError, faults
+
+        model, batch = self._served(n_users=8)
+        reg = StorageRegistry({
+            "PIO_STORAGE_SOURCES_META_TYPE": "MEM",
+            "PIO_STORAGE_SOURCES_EV_TYPE": "MEM",
+            "PIO_STORAGE_SOURCES_EV_RETRY_ATTEMPTS": "1",
+            "PIO_STORAGE_SOURCES_EV_BREAKER_THRESHOLD": "2",
+            "PIO_STORAGE_SOURCES_EV_BREAKER_RECOVERY_S": "60",
+            "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "META",
+            "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "EV",
+            "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "META"})
+        app_id = reg.get_meta_data_apps().insert(App(0, "seqapp"))
+        reg.get_events().init(app_id)
+        reg.get_events().insert_batch(batch, app_id)
+        algo = self._algo(reg)
+        queries = [(k, sr.Query(user=f"u{k}", num=3)) for k in range(3)]
+        assert len(algo.batch_predict(model, queries)) == 3
+        faults().arm("storage.EV.Events.find", error=OSError)
+        try:
+            for _ in range(2):
+                with pytest.raises(OSError):
+                    algo.batch_predict(model, queries)
+            assert reg.breaker_states()["EV"] == "open"
+            with pytest.raises(CircuitOpenError):
+                algo.batch_predict(model, queries)
+        finally:
+            faults().clear()

@@ -20,6 +20,7 @@ from predictionio_tpu.data.storage import (
     AccessKey, App, Channel, EngineInstance, Model, StorageRegistry,
     StorageWriteError,
 )
+from predictionio_tpu.data.storage.base import _UNSET
 
 T0 = datetime(2020, 1, 1, tzinfo=timezone.utc)
 
@@ -352,19 +353,46 @@ class TestMetaDAOs:
 
 # -- the MEM store's index by entity ------------------------------------------
 
+def _matches(e, start_time=None, until_time=None, entity_type=None,
+             entity_id=None, event_names=None, target_entity_type=_UNSET,
+             target_entity_id=_UNSET, properties=None):
+    """`find`'s filter rules, written out one event at a time."""
+    def aware(t):
+        return t if t.tzinfo else t.replace(tzinfo=timezone.utc)
+    return (entity_type in (None, e.entity_type)
+            and entity_id in (None, e.entity_id)
+            and (start_time is None or e.event_time >= aware(start_time))
+            and (until_time is None or e.event_time < aware(until_time))
+            and (event_names is None or e.event in event_names)
+            and target_entity_type in (_UNSET, e.target_entity_type)
+            and target_entity_id in (_UNSET, e.target_entity_id)
+            and all(k in e.properties and e.properties[k] == v
+                    for k, v in (properties or {}).items()))
+
+
 def _scan(events_dao, app_id, **kw):
     """What `find` returned before the index: every event of the app,
     filtered and sorted."""
-    from predictionio_tpu.data.storage.base import match_event
     rev = kw.pop("reversed", False)
     limit = kw.pop("limit", None)
     found = [e for e in events_dao._table(app_id, None).values()
-             if match_event(e, entity_type=kw.get("entity_type"),
-                            entity_id=kw.get("entity_id"),
-                            event_names=kw.get("event_names"))]
+             if _matches(e, **kw)]
     found.sort(key=lambda e: (e.event_time_millis, e.event_id or ""),
                reverse=rev)
-    return found[:limit] if limit else found
+    return found[:limit] if limit is not None and limit > 0 else found
+
+
+def _at(eid, when, event_id, event="view", target=("item", "x"),
+        props=None):
+    return Event(event=event, entity_type="user", entity_id=eid,
+                 target_entity_type=target[0] if target else None,
+                 target_entity_id=target[1] if target else None,
+                 properties=DataMap(props or {}), event_time=when,
+                 event_id=event_id)
+
+
+MS = T0 + timedelta(minutes=7)          # a whole millisecond
+US = timedelta(microseconds=1)
 
 
 @pytest.mark.parametrize("query", [
@@ -375,6 +403,32 @@ def _scan(events_dao, app_id, **kw):
     dict(entity_type="item", entity_id="u3"),
     dict(entity_type="user"),
     dict(),
+    # time bounds, one of them inside a millisecond
+    dict(entity_type="user", entity_id="u3",
+         start_time=T0 + timedelta(minutes=10),
+         until_time=T0 + timedelta(minutes=30)),
+    dict(entity_type="user", entity_id="u3", start_time=MS + 500 * US,
+         until_time=MS + 1100 * US, reversed=True),
+    # target filters: none, a type, an id
+    dict(entity_type="user", entity_id="u3", target_entity_type=None),
+    dict(entity_type="user", entity_id="u3", target_entity_type="item",
+         target_entity_id="j5"),
+    dict(entity_type="user", entity_id="u3", target_entity_id="x"),
+    dict(entity_type="user", entity_id="u3", properties={"color": "red"},
+         reversed=True),
+    # the batch's entity and the entity deleted whole and written again
+    dict(entity_type="user", entity_id="u9"),
+    dict(entity_type="user", entity_id="u9", reversed=True,
+         event_names=["view"]),
+    dict(entity_type="user", entity_id="u8", reversed=True),
+    dict(entity_type="user", entity_id="u7"),
+    # limits, with and without `reversed`; 0 and below read everything
+    dict(entity_type="user", entity_id="u3", limit=7),
+    dict(entity_type="user", entity_id="u3", limit=7, reversed=True,
+         event_names=["view"]),
+    dict(entity_type="user", entity_id="u3", limit=0, reversed=True),
+    dict(entity_type="user", entity_id="u3", limit=-1),
+    dict(entity_type="user", event_names=["view"], limit=5, reversed=True),
 ])
 def test_mem_find_by_entity_reads_the_index_and_equals_the_scan(query):
     import random
@@ -398,6 +452,40 @@ def test_mem_find_by_entity_reads_the_index_and_equals_the_scan(query):
     for n in range(40):                            # and inserts after them
         dao.insert(ev(eid="u3", t=rng.randrange(50),
                       target=("item", f"j{n}")), 1)
+    # two events in one millisecond, the later one first by id; times
+    # under a millisecond apart on both sides of its edge
+    dao.insert(_at("u3", MS + 200 * US, "zz-same-ms"), 1)
+    dao.insert(_at("u3", MS + 700 * US, "aa-same-ms"), 1)
+    dao.insert(_at("u3", MS + 1100 * US, "a-next-ms"), 1)
+    dao.insert(_at("u3", MS + 999 * US, "b-same-ms", target=None), 1)
+    # the same at the end of an entity's list, where an insert appends
+    dao.insert(_at("u7", MS + 200 * US, "zz-last-ms"), 1)
+    dao.insert(_at("u7", MS + 700 * US, "aa-last-ms"), 1)
+    # a batch out of time order over two entities, with properties
+    dao.insert_batch([
+        _at(eid, T0 + timedelta(minutes=m), f"batch{eid}{m}",
+            event=("buy" if m % 3 else "view"), target=None if m == 4 else
+            ("item", "x"), props={"color": "red" if m % 2 else "blue"})
+        for eid, m in [("u9", 9), ("u3", 3), ("u9", 2), ("u9", 12),
+                       ("u3", 44), ("u9", 2 * 60), ("u9", 4), ("u3", 17)]],
+        1)
+    # naive times, read as UTC, after and between aware ones
+    for m in (200, 201):
+        dao.insert(_at("u9", (T0 + timedelta(minutes=m)).replace(
+            tzinfo=None), f"u9-naive-{m}"), 1)
+    dao.insert(_at("u9", T0 + timedelta(minutes=202), "u9-aware-202"), 1)
+    dao.insert(_at("u9", (T0 + timedelta(minutes=7)).replace(tzinfo=None),
+                   "u9-naive-7"), 1)
+    # an entity deleted whole, then written again under the same ids
+    u8 = [_at("u8", T0 + timedelta(minutes=m), f"u8e{m}")
+          for m in (5, 1, 3)]
+    for e in u8:
+        dao.insert(e, 1)
+    for e in u8:
+        assert dao.delete(e.event_id, 1)
+    assert ("user", "u8") not in dao.c.events_by_entity[(1, None)]
+    dao.insert_batch(u8[1:], 1)
+    dao.insert(u8[0], 1)
     # a second DAO over the same client sees the same index
     for d in (dao, reg.get_events()):
         got = list(d.find(1, **query))
@@ -410,3 +498,41 @@ def test_mem_find_by_entity_reads_the_index_and_equals_the_scan(query):
     assert all(v for v in by.values())
     dao.remove(1)
     assert list(dao.find(1, entity_type="user", entity_id="u3")) == []
+
+
+def test_mem_entity_read_computes_no_sort_key(monkeypatch):
+    """The order is kept as events are written, with keys computed only
+    for an event out of time; a read that names the entity computes no
+    key, where a read that names none sorts."""
+    reg = StorageRegistry({
+        "PIO_STORAGE_SOURCES_MEM_TYPE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "MEM"})
+    dao = reg.get_events()
+    dao.init(1)
+    millis = Event.event_time_millis
+    keys = []
+    monkeypatch.setattr(Event, "event_time_millis", property(
+        lambda e: keys.append(e.event_id) or millis.fget(e)))
+    for n in range(30):                # in time, then one out of it
+        dao.insert(ev(eid="u1", t=n, target=("item", f"i{n}")), 1)
+    # in time, with ids in order: appended, and no key computed
+    assert keys == []
+    dao.insert_batch([ev(eid="u1", t=t, event="buy") for t in (40, 41)], 1)
+    assert keys == []
+    dao.insert(ev(eid="u1", t=-1), 1)
+    assert keys                        # out of time: the keys, to bisect
+    del keys[:]
+    reads = [dict(), dict(reversed=True, limit=5),
+             dict(event_names=["view"], start_time=T0, reversed=True)]
+    got = [list(dao.find(1, entity_type="user", entity_id="u1", **kw))
+           for kw in reads]
+    assert keys == []
+    assert len(got[0]) == 33 and got[1] == got[0][::-1][:5]
+    assert [e.event_time for e in got[0]] == sorted(
+        e.event_time for e in got[0])
+    list(dao.find(1, entity_type="user"))
+    assert len(keys) == 33             # the scan still sorts
+    for kw, g in zip(reads, got):
+        assert g == _scan(dao, 1, entity_type="user", entity_id="u1", **kw)
