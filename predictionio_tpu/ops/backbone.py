@@ -3,10 +3,10 @@
 A backbone is a `BackboneConfig`: widths, a list of layers, the norm,
 the positions, and, where experts are spread over chips, the share
 held here. A layer is a tuple of block kinds, each block `x + Block(
-Norm(x))`: a mixer (`attn_full`, `attn_window`, `conv`, `ssm`) followed
-by a feed-forward (`ffn_dense`, `ffn_moe`), or one block alone. The
-item catalog is the vocabulary and a user's event history the context.
-Two families of configuration are built here:
+Norm(x))`: a mixer (`attn_full`, `attn_window`, `conv`, `ssm`,
+`retention`) followed by a feed-forward (`ffn_dense`, `ffn_moe`), or
+one block alone. The item catalog is the vocabulary and a user's event
+history the context. Two families of configuration are built here:
 
   - `sasrec_config`: the template's own small block (LayerNorm, full
     attention with as many KV heads as heads, a ReLU feed-forward of
@@ -17,28 +17,33 @@ Two families of configuration are built here:
     different KV head counts, a learned sink a head in window layers,
     rotary position on part or all of the head, RMSNorm of each head's
     q and k, attention with no position at all, gated
-    short-convolution mixers, Mamba-2 state-space mixers, RMSNorm, a
-    dense SwiGLU, sigmoid-routed experts that are SwiGLUs or two-matrix
-    squared-ReLU, and a shared expert), cut as the file says:
-    `layer_ids` picks layers of the published patterns, the experts'
-    count and `expert_share` say which experts live here, `vocab_size`
-    how many rows of the vocabulary. Three families' keys are read
-    into the one `BackboneConfig`; the key that carries the layer
-    pattern tells the family, and `_FAMILIES` holds each one's names.
+    short-convolution mixers, Mamba-2 state-space mixers, degree-2
+    power-retention mixers, RMSNorm, a dense SwiGLU, sigmoid-routed
+    experts that are SwiGLUs or two-matrix squared-ReLU, and a shared
+    expert), cut as the file says: `layer_ids` picks layers of the
+    published patterns, the experts' count and `expert_share` say which
+    experts live here, `vocab_size` how many rows of the vocabulary.
+    Four families' keys are read into the one `BackboneConfig`; the
+    key that carries the layer pattern tells the family, and a file
+    with none names it by `model_type` (`brumby`: every layer a
+    `retention` mixer and a dense SwiGLU); `_FAMILIES` holds each
+    one's names.
 
 `init_params` / `forward` are the one stack for all of them. The
 parameters are one pytree keyed by layer (`l0`, `l1`, ...): a block's
 norm under `norm1` (the layer's first block) or `norm2`, a mixer under
-`attn`, `conv` or `ssm`, a feed-forward under `ffn`. Attention itself
+`attn`, `conv`, `ssm` or `retention`, a feed-forward under `ffn`.
+Attention itself
 is handed in (`attend`): training runs padded batches through
 `ops.attention.ring_attention`, serving runs packed histories through
-`ops.attention.packed_attention`. The convolution and state-space
-mixers read along the token axis, and `positions` (each event's index
-in its own history, which both layouts hand to `forward`) says where a
-history begins: a tap is kept only where the neighbour is of the same
-history (`conv_block`, `ssm_block`), and the state is zero before an
-event of index 0 (`ssm_block`). Everything else is position-wise and
-does not know which layout it runs in.
+`ops.attention.packed_attention`. The convolution, state-space and
+retention mixers read along the token axis, and `positions` (each
+event's index in its own history, which both layouts hand to
+`forward`) says where a history begins: a tap is kept only where the
+neighbour is of the same history (`conv_block`, `ssm_block`), and the
+state is zero before an event of index 0 (`ssm_block`,
+`retention_block`). Everything else is position-wise and does not know
+which layout it runs in.
 """
 
 from __future__ import annotations
@@ -54,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.ops import moe, ssm
+from predictionio_tpu.ops import moe, retention, ssm
 
 
 @dataclass(frozen=True)
@@ -100,6 +105,8 @@ class BackboneConfig:
     ssm_state: int = 0               # of this many dimensions,
     ssm_chunk: int = 0               # scanned in chunks of this length
     shared_width: int = 0            # the expert layer's shared expert
+    retention_chunk: int = 0         # a `retention` mixer's chunk,
+    retention_eps: float = 0.0       # and its normaliser's epsilon
     # serving: the longest history read, the tokens of one call, and
     # the padded sizes a call is compiled for
     max_history: int = 0
@@ -154,11 +161,20 @@ _FAMILIES: Dict[str, Dict[str, Any]] = {
         "experts": "n_routed_experts", "act": "mlp_hidden_act",
         "rotary": None, "conv_kernel": "conv_kernel",
         "conv_bias": "use_conv_bias"},
+    "brumby": {                         # every layer power retention and
+        "eps": "rms_norm_eps",          # a dense SwiGLU: no pattern key,
+        "experts": None, "act": "hidden_act",   # its model_type names it
+        "rotary": "partial_rotary_factor", "conv_kernel": None,
+        "conv_bias": None},
 }
 
 
 def _family(doc: Dict[str, Any]) -> Dict[str, Any]:
-    found = [k for k in _FAMILIES if k in doc]
+    """The family whose pattern key the file carries, or, for a stack
+    of one layer kind that has no pattern key, whose name is the file's
+    `model_type`."""
+    found = [k for k in _FAMILIES
+             if k in doc or doc.get("model_type") == k]
     if len(found) != 1:
         raise ValueError(f"a configuration carries one layer pattern of "
                          f"{sorted(_FAMILIES)}; this one has {found}")
@@ -168,6 +184,8 @@ def _family(doc: Dict[str, Any]) -> Dict[str, Any]:
 def _layers_from_json(doc: Dict[str, Any], ids) -> Tuple:
     """The blocks of the layers `ids`, from whichever family's pattern
     keys the file has."""
+    if doc.get("model_type") == "brumby":
+        return tuple(("retention", "ffn_dense") for _ in ids)
     if "hybrid_override_pattern" in doc:
         pattern = doc["hybrid_override_pattern"]
         unknown = sorted(set(pattern) - set(_PATTERN_BLOCKS))
@@ -216,9 +234,11 @@ def config_from_json(doc: Dict[str, Any], name: str = "") -> BackboneConfig:
     conv_bias = bool(of("conv_bias", False))
     if conv_bias and "conv" in kinds:
         raise ValueError("the convolution mixer here has no bias")
+    serving = doc.get("assumed") or {}
+    if "retention" in kinds and not serving.get("retention_chunk"):
+        raise ValueError("a retention mixer needs assumed.retention_chunk")
     share = doc.get("expert_share") or {"index": 0, "count": 1}
     held = int(of("experts", 0))
-    serving = doc.get("assumed") or {}
     heads = int(doc["num_attention_heads"])
     qk = int(doc.get("head_dim") or int(doc["hidden_size"]) // heads)
     rotary = (0.0 if names["rotary"] is None
@@ -260,6 +280,8 @@ def config_from_json(doc: Dict[str, Any], name: str = "") -> BackboneConfig:
         ssm_chunk=int(doc.get("chunk_size") or 0),
         shared_width=int(doc.get("moe_shared_expert_intermediate_size")
                          or 0),
+        retention_chunk=int(serving.get("retention_chunk", 0)),
+        retention_eps=float(serving.get("retention_eps", 0.0)),
         max_history=int(serving.get("max_history", 0)),
         max_batch_tokens=int(serving.get("max_batch_tokens", 0)),
         token_buckets=tuple(int(b) for b in
@@ -289,7 +311,8 @@ def config_of(doc: Dict[str, Any]) -> BackboneConfig:
 
 # where a block's parameters stand in its layer's dict
 _PARAM_KEY = {"attn_full": "attn", "attn_window": "attn", "conv": "conv",
-              "ssm": "ssm", "ffn_dense": "ffn", "ffn_moe": "ffn"}
+              "ssm": "ssm", "retention": "retention", "ffn_dense": "ffn",
+              "ffn_moe": "ffn"}
 
 
 def _block_shapes(cfg: BackboneConfig, kind: str) -> Dict[str, Any]:
@@ -331,6 +354,9 @@ def _block_shapes(cfg: BackboneConfig, kind: str) -> Dict[str, Any]:
     if cfg.qk_norm:     # one gain vector, shared by the heads
         m["q_norm"] = {"g": (cfg.qk_dim,)}
         m["k_norm"] = {"g": (cfg.qk_dim,)}
+    if kind == "retention":     # one gate a KV head
+        m["w_gate"] = (D, hkv)
+        m["b_gate"] = (hkv,)
     if (cfg.sink_window if kind == "attn_window" else cfg.sink_full):
         m["sink"] = (H,)
     return m
@@ -374,7 +400,11 @@ def init_params(key, cfg: BackboneConfig, dtype=jnp.float32):
     mixer's rates are A = -exp(a_log) with exp(a_log) uniform in
     [1, 16], its step sizes start at softplus(dt_bias) log-uniform in
     [0.001, 0.1], and its skip d is 1 (the family's published
-    initialisation)."""
+    initialisation). A retention mixer's gate bias puts each KV head's
+    half-life at zero input log-uniform in [64, 4096] tokens, and its
+    gate matrix is N(0, 1 / (4 fan_in)): the input moves the gate
+    without setting it (a trained model's gates are not published, and
+    a short memory would leave the carried state unread)."""
     flat, tree = jax.tree_util.tree_flatten_with_path(
         param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
     keys = iter(jax.random.split(key, len(flat)))
@@ -402,12 +432,26 @@ def init_params(key, cfg: BackboneConfig, dtype=jnp.float32):
         if leaf == "conv_bias":
             return (jax.random.normal(k, shape, jnp.float32)
                     / np.sqrt(3.0 * cfg.conv_kernel))
+        if leaf == "b_gate":
+            return gate_bias(k, shape)
+        if leaf == "w_gate":
+            return (jax.random.normal(k, shape, jnp.float32)
+                    / np.sqrt(4.0 * shape[-2])).astype(dtype)
         fan = cfg.hidden if leaf in ("embed", "head") else shape[-2]
         return (jax.random.normal(k, shape, jnp.float32)
                 / np.sqrt(fan)).astype(dtype)
 
     return jax.tree_util.tree_unflatten(
         tree, [draw(path, shape) for path, shape in flat])
+
+
+def gate_bias(key, shape):
+    """A retention gate's bias: sigmoid(b) = 2^(-1 / h), the half-life
+    h log-uniform in [64, 4096] tokens."""
+    half_life = jnp.exp(jax.random.uniform(
+        key, shape, jnp.float32, math.log(64.0), math.log(4096.0)))
+    g = jnp.exp2(-1.0 / half_life)
+    return jnp.log(g) - jnp.log1p(-g)
 
 
 # -- the blocks ---------------------------------------------------------------
@@ -496,6 +540,13 @@ def conv_block(p, cfg: BackboneConfig, u, positions):
     return _mm(bcx[..., D:2 * D] * c, p["w_out"])
 
 
+def _whole(v, chunk: int, fill=0):
+    """v [T, ...] padded along its first axis to whole chunks: each slot
+    added is a history of one event, and no one is served from it."""
+    return jnp.pad(v, ((0, -v.shape[0] % chunk),) + ((0, 0),) * (v.ndim - 1),
+                   constant_values=fill)
+
+
 def ssm_block(p, cfg: BackboneConfig, u, positions):
     """The Mamba-2 mixer: u [..., T, D] (normed) -> [..., T, D].
     [z | xBC | dt] = u W_in; xBC goes through a depthwise causal
@@ -533,14 +584,10 @@ def ssm_block(p, cfg: BackboneConfig, u, positions):
     step = jax.nn.softplus(dt.reshape(-1, H) + p["dt_bias"])
     T, Q = x.shape[0], cfg.ssm_chunk
 
-    def whole(v, fill=0):
-        """Whole chunks: each slot added is a history of one event."""
-        return jnp.pad(v, ((0, -T % Q),) + ((0, 0),) * (v.ndim - 1),
-                       constant_values=fill)
-
-    y = ssm.chunk_scan(whole(xbc.astype(w_in.dtype)), whole(step),
+    y = ssm.chunk_scan(_whole(xbc.astype(w_in.dtype), Q), _whole(step, Q),
                        -jnp.exp(p["a_log"]),
-                       whole(positions.reshape(-1) == 0, True), Q, G, N)[:T]
+                       _whole(positions.reshape(-1) == 0, Q, True),
+                       Q, G, N)[:T]
     y = (y + jnp.repeat(p["d"], P) * x).reshape(*lead, inner) \
         * jax.nn.silu(z)
     # the norm over each group's columns, as column slices: a [..., G,
@@ -550,6 +597,42 @@ def ssm_block(p, cfg: BackboneConfig, u, positions):
         [v * jax.lax.rsqrt((v * v).mean(-1, keepdims=True) + cfg.eps)
          for v in (y[..., i * w:(i + 1) * w] for i in range(G))], -1)
     return _mm(y * p["norm"]["g"], p["w_out"])
+
+
+def retention_block(p, cfg: BackboneConfig, u, positions, live=None):
+    """The degree-2 power-retention mixer: u [..., T, D] (normed) ->
+    [..., T, D]. q = u Wq [H, P], k = u Wk and v = u Wv [Hkv, P]; q and
+    k RMSNormed over each head's P dimensions and rotated as
+    `attention_block`'s; a gate a KV head, log g = logsigmoid(u W_gate
+    + b_gate). y_{t,i} = sum_s w_ts v_s / (sum_s w_ts + eps) over the
+    events s <= t of t's own history, w_ts = exp(G_t - G_s) (q_{t,i} .
+    k_s / sqrt(P))^2 with G the sum of log g (`ops/retention.py`, in
+    chunks of `retention_chunk` along the token axis, the state zero
+    before an event whose index in its own history is 0); the result is
+    y Wo. `live` [..., T] bool marks the tokens served (None: all)."""
+    H, P = cfg.n_heads, cfg.qk_dim
+    hkv, lead = cfg.kv_heads_full, u.shape[:-1]
+    q = _mm(u, p["wq"]).reshape(*lead, H, P)
+    k = _mm(u, p["wk"]).reshape(*lead, hkv, P)
+    v = _mm(u, p["wv"])
+    if cfg.qk_norm:
+        q, k = norm(q, p["q_norm"], cfg), norm(k, p["k_norm"], cfg)
+    if cfg.rotary_dim:
+        q = rotary(q, positions, dim=cfg.rotary_dim,
+                   theta=cfg.rope_theta_full)
+        k = rotary(k, positions, dim=cfg.rotary_dim,
+                   theta=cfg.rope_theta_full)
+    log_g = jax.nn.log_sigmoid(_mm(u, p["w_gate"]) + p["b_gate"])
+    dt, T, Q = p["wq"].dtype, math.prod(lead), cfg.retention_chunk
+    y = retention.power_retention(
+        _whole((q * P ** -0.5).reshape(T, H * P).astype(dt), Q),
+        _whole(k.reshape(T, hkv * P).astype(dt), Q),
+        _whole(v.reshape(T, hkv * P).astype(dt), Q),
+        _whole(log_g.reshape(T, hkv), Q),
+        _whole(positions.reshape(T) == 0, Q, True),
+        None if live is None else _whole(live.reshape(T), Q, False),
+        Q, cfg.retention_eps)[:T]
+    return _mm(y.reshape(*lead, H * P), p["wo"])
 
 
 def ffn_dense(p, cfg: BackboneConfig, u):
@@ -608,6 +691,9 @@ def forward(params, cfg: BackboneConfig, tokens, positions,
             elif kind == "ssm":
                 with jax.named_scope("mixer_ssm"):
                     y = ssm_block(bp, cfg, u, positions)
+            elif kind == "retention":
+                with jax.named_scope("mixer_retention"):
+                    y = retention_block(bp, cfg, u, positions, live=valid)
             elif kind == "ffn_moe":
                 with jax.named_scope("ffn_experts"):
                     y, st = ffn_moe(bp, cfg, u, live=valid)

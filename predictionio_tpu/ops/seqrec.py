@@ -288,6 +288,12 @@ def _seq_metrics():
                 "Share of one call's state-space scans that read x, B "
                 "and C and wrote y where the mixer lays them, with no "
                 "group-major copy (ops/ssm.in_place)", buckets=share),
+            "retention_carry": reg.histogram(
+                "pio_seq_retention_carry_chunk_share",
+                "Share of one call's chunks of the retention kernel "
+                "(retention_chunk tokens each) that read the state "
+                "carried from the chunk before: those whose first token "
+                "continues a history that is served", buckets=share),
         }
     return _SEQ_METRICS
 
@@ -415,6 +421,13 @@ class PackedEncoder:
                         self.cfg.ssm_heads // self.cfg.ssm_groups
                         * self.cfg.ssm_head_dim, self.cfg.ssm_state,
                         self.cfg.ssm_groups)))
+                if self.cfg.retention_chunk:    # the kernel's own rule
+                    chunk = self.cfg.retention_chunk
+                    starts = np.arange(0, n_tok, chunk)
+                    reads = len(starts) - np.isin(
+                        starts, np.cumsum(lens) - lens).sum()
+                    metrics["retention_carry"].observe(
+                        reads * chunk / bucket)
                 if stats is not None:
                     per = np.asarray(stats.expert_tokens, np.float64)
                     mean = per.mean(axis=1)

@@ -71,6 +71,28 @@ def scan_steps(x, dt, a, b, c, first):
     return jax.lax.scan(step, S0, (x, dt, b, c, first))[1]
 
 
+def chunk_sums(x, first, chunk: int):
+    """x [T, H] float32, first [T] bool -> (L, cut), each [T / chunk,
+    chunk, H]: L the sum of x along the token axis from the chunk's
+    first token, or from the last first event at or before the token
+    where one stands in the chunk, up to the token itself; `cut`
+    whether such a first event stands. What stands in front of a first
+    event is in no bit of L behind it. The decays of a chunked scan
+    over packed histories are differences of L (`chunk_scan`, and
+    `ops/retention.power_retention`)."""
+    T, H = x.shape
+    nc = T // chunk
+
+    def onward(left, right):
+        (l_sum, l_cut), (r_sum, r_cut) = left, right
+        return jnp.where(r_cut, r_sum, l_sum + r_sum), l_cut | r_cut
+
+    return jax.lax.associative_scan(
+        onward, (x.reshape(nc, chunk, H),
+                 jnp.broadcast_to(first.reshape(nc, chunk, 1),
+                                  (nc, chunk, H))), axis=1)
+
+
 def in_place(width: int, state: int, groups: int) -> bool:
     """Whether `chunk_scan` reads x, B and C where the convolution
     leaves them, as (chunk, group) blocks of its token-major slab, and
@@ -146,17 +168,7 @@ def chunk_scan(xbc, dt, a, first, chunk: int, groups: int, state: int):
     f32 = jnp.float32
     dt = dt.astype(f32)
     seg = jnp.cumsum(first.astype(jnp.int32)).reshape(nc, Q)
-
-    def onward(left, right):
-        """A sum that starts again at a history's first event: what
-        stands in front of the event is in no bit of L behind it."""
-        (l_sum, l_cut), (r_sum, r_cut) = left, right
-        return jnp.where(r_cut, r_sum, l_sum + r_sum), l_cut | r_cut
-
-    L, cut = jax.lax.associative_scan(
-        onward, ((dt * a.astype(f32)).reshape(nc, Q, H),
-                 jnp.broadcast_to(first.reshape(nc, Q, 1), (nc, Q, H))),
-        axis=1)
+    L, cut = chunk_sums(dt * a.astype(f32), first, Q)
     # a token whose history began before its chunk (no first event up
     # to it: `cut` is false) reads the carried state; one of the last
     # token's history feeds the closing state

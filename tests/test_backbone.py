@@ -914,7 +914,7 @@ def test_mimo_file_still_gives_todays_config_field_for_field():
         "n_experts": 256, "top_k": 8, "norm_topk_prob": True,
         "routed_scale": 1.0, "expert_first": 0, "experts_held": 16,
         "qk_norm": False, "conv_kernel": 0, "route_eps": 0.0,
-        **SSM_DEFAULTS,
+        **SSM_DEFAULTS, **RETENTION_DEFAULTS,
         "max_history": 2048, "max_batch_tokens": 8192,
         "token_buckets": (512, 1024, 2048, 4096, 8192)}
 
@@ -923,6 +923,8 @@ def test_mimo_file_still_gives_todays_config_field_for_field():
 SSM_DEFAULTS = {"conv_bias": False, "ssm_heads": 0, "ssm_head_dim": 0,
                 "ssm_groups": 0, "ssm_state": 0, "ssm_chunk": 0,
                 "shared_width": 0}
+# the fields the fourth family brought, as the first three read them
+RETENTION_DEFAULTS = {"retention_chunk": 0, "retention_eps": 0.0}
 
 
 def test_lfm2_file_still_gives_todays_config_field_for_field():
@@ -944,7 +946,7 @@ def test_lfm2_file_still_gives_todays_config_field_for_field():
         "n_experts": 32, "top_k": 4, "norm_topk_prob": True,
         "routed_scale": 1.0, "expert_first": 0, "experts_held": 32,
         "qk_norm": True, "conv_kernel": 3, "route_eps": 1e-06,
-        **SSM_DEFAULTS,
+        **SSM_DEFAULTS, **RETENTION_DEFAULTS,
         "max_history": 2048, "max_batch_tokens": 8192,
         "token_buckets": (512, 1024, 2048, 4096, 8192)}
 

@@ -400,3 +400,67 @@ def test_fused_topk_compiles_over_nemotrons_held_rows(one_chip, monkeypatch,
     compiled = _compiled_fused_bucket(one_chip, monkeypatch, 65536, 2688,
                                       bucket)
     _assert_reads_the_catalog_where_it_lies(compiled, 65536, 2688)
+
+
+@pytest.mark.parametrize("tokens", [512, 8192])
+def test_power_retention_compiles_at_brumbys_width(one_chip, as_tpu,
+                                                   tokens):
+    """Brumby-14B-Base's retention: 40 query heads on 8 KV heads of 128,
+    chunks of 128; bfloat16 operands, float32 gates. The kernel carries
+    a KV head's state [8,320, 128] and its normaliser [128, 128] in
+    float32 in fast memory and forms the degree-2 embedding there."""
+    from predictionio_tpu.ops import retention
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, g, first, live: retention.power_retention(
+            q, k, v, g, first, live, 128, 1e-6)).lower(
+        sds((tokens, 40 * 128), jnp.bfloat16),
+        sds((tokens, 8 * 128), jnp.bfloat16),
+        sds((tokens, 8 * 128), jnp.bfloat16),
+        sds((tokens, 8), jnp.float32), sds((tokens,), jnp.bool_),
+        sds((tokens,), jnp.bool_)).compile()
+    assert _compiled_kernels(compiled) == 1
+    # the state never leaves the kernel: no [8,256 or 8,320, ...] array
+    assert not re.search(r"\w+\[(?:\d+,)*(?:8256|8320)[,\]]",
+                         compiled.as_text())
+
+
+def test_the_retention_mixer_compiles_whole_at_brumbys_width(one_chip,
+                                                            as_tpu):
+    """`retention_block` at Brumby's widths and 8,192 tokens: the five
+    products, the q/k norms, the rotary, the gate and one kernel."""
+    import json
+    from pathlib import Path
+    from predictionio_tpu.ops import backbone as bb
+    doc = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                      / "configs" / "brumby-14b-pp5-8l.json").read_text())
+    cfg = bb.config_from_json(doc, "brumby")
+    shapes = bb.param_shapes(cfg)["l0"]["retention"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = jax.tree_util.tree_map(
+        lambda s: sds(s, jnp.bfloat16 if len(s) == 2 else jnp.float32),
+        shapes, is_leaf=lambda s: isinstance(s, tuple))
+    compiled = jax.jit(
+        lambda p, u, pos, live: bb.retention_block(p, cfg, u, pos, live)
+    ).lower(p, sds((8192, cfg.hidden), jnp.float32),
+            sds((8192,), jnp.int32), sds((8192,), jnp.bool_)).compile()
+    assert _compiled_kernels(compiled) == 1
+
+
+@pytest.mark.parametrize("bucket", [1, 32, 64])
+def test_fused_topk_compiles_over_brumbys_vocabulary(one_chip, monkeypatch,
+                                                     bucket):
+    """151,936 rows of 5,120 (the whole untied head, float32 for the
+    plan: 3.1 GB), read where they lie, in tiles of 128 rows."""
+    from predictionio_tpu.ops import fused_topk
+    assert fused_topk._tile_items(151936, 10, 5120) == (128, 128)
+    assert not fused_topk._items_on_lanes(5120)
+    compiled = _compiled_fused_bucket(one_chip, monkeypatch, 151936, 5120,
+                                      bucket)
+    _assert_reads_the_catalog_where_it_lies(compiled, 151936, 5120)
