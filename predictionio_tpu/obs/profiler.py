@@ -24,6 +24,30 @@ node budget is spent, deeper frames fold into the deepest allocated
 node, so memory stays O(budget) under pathological stack churn while
 hot paths (allocated early, sampled often) keep full depth.
 
+A tick keys and looks up only what changed since the last one. The
+sampler keeps, per thread, the code objects of the last tick's chain,
+their `id`s and the path they folded to. It follows each thread's
+chain to the outermost frame collecting the code objects; where they
+are the last tick's, height by height, the thread is parked and takes
+its last path whole: nothing is keyed, built or looked up. Any other
+chain is keyed from a memo ("module:function" built once per code
+object) and its path looked up. This is exact: a frame's key is a
+function of its code object alone, and the code objects are held, so
+an `id` names the same one from tick to tick. No frame object is held:
+one held past its return would keep its locals, a call's device arrays
+among them, until the sampler let it go on its own tick (at a cost
+above the walk it saves) or, at exit, during finalization.
+
+Samples are counted per distinct path (role and stack): a path's trie
+leaf is found on its first sight, by the same walk and budget rule as
+ever, and a sample after that costs one count on the path and one on
+its leaf. The per-frame self and cumulative counts of `/profile.json`
+are derived from the path counts when it is read. The path table is
+bounded by the trie's budget, `max_nodes` paths; a path first seen past
+it is counted per frame on every sample, as before. Once a tick the
+share of the tick's frames in chains unchanged since the last tick,
+whose keys were not looked up anew, goes into `pio_prof_fold_reused`.
+
 Alongside the sampler, this module owns the cheap runtime gauges:
 GC pauses via `gc.callbacks` (`pio_gc_pause_seconds{generation}`,
 folded into the registry by every read of it, and a `pio:gc.gen<n>`
@@ -46,6 +70,7 @@ import threading
 import time
 from collections import deque
 from contextlib import contextmanager
+from types import CodeType, FrameType
 from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
 from predictionio_tpu.obs import trace
@@ -156,6 +181,56 @@ class _Node:
         self.ended = 0
 
 
+# frame keys remembered by code object before the memo starts afresh
+_KEYS_MAX = 1 << 14
+
+
+class _Path:
+    """One distinct (role, stack) the sampler has folded: the trie node
+    its samples end at (None where the budget left its role no root),
+    whether the budget cut it short, and its sample count where the
+    path table holds it (None where it is counted per frame)."""
+
+    __slots__ = ("stack", "leaf", "truncated", "samples")
+
+    def __init__(self, stack: Tuple[str, ...], leaf: Optional[_Node],
+                 truncated: bool, samples: Optional[int]):
+        self.stack = stack
+        self.leaf = leaf
+        self.truncated = truncated
+        self.samples = samples
+
+
+class _Held:
+    """What the sampler keeps of one thread from one tick to the next:
+    the code objects of its chain (innermost first) and their `id`s,
+    the thread name its role came from, and the path the chain folded
+    to. No frame is kept: a frame held past its return would keep its
+    locals, a call's device arrays among them, until the sampler let
+    them go."""
+
+    __slots__ = ("codes", "ids", "name", "role", "path")
+
+    def __init__(self, codes: List[CodeType], ids: List[int], name: str,
+                 role: str, path: _Path):
+        self.codes = codes
+        self.ids = ids
+        self.name = name
+        self.role = role
+        self.path = path
+
+
+def _count_frames(self_counts: Dict[str, int], cum_counts: Dict[str, int],
+                  stack: Tuple[str, ...], n: int) -> None:
+    """`n` samples of `stack` into the per-frame counts: its innermost
+    frame's self count, and the cumulative count of each distinct frame
+    on it."""
+    innermost = stack[-1]
+    self_counts[innermost] = self_counts.get(innermost, 0) + n
+    for key in set(stack):
+        cum_counts[key] = cum_counts.get(key, 0) + n
+
+
 class SamplingProfiler:
     """Bounded folded-stack sampler over `sys._current_frames()`.
 
@@ -180,13 +255,25 @@ class SamplingProfiler:
         self._roots: Dict[str, _Node] = {}
         self._nodes = 0              # allocated trie nodes across roles
         self._truncated = 0          # samples folded at the node budget
+        # (role, stack) -> its path, at most max_nodes of them
+        self._paths: Dict[Tuple[str, Tuple[str, ...]], _Path] = {}
+        # per-frame counts of the paths past the table's bound
         self._self_counts: Dict[str, int] = {}   # innermost frame
         self._cum_counts: Dict[str, int] = {}    # anywhere on stack
+        self._held: Dict[int, _Held] = {}        # thread ident -> last tick
+        self._keys: Dict[int, str] = {}          # id(code) -> frame key
+        self._codes: List[CodeType] = []         # what _keys is keyed by
         self._role_samples: Dict[str, int] = {}
         self._samples = 0            # thread-samples folded
         self._ticks = 0              # sampler wakeups
         self._started_at = 0.0
-        self._tick_seconds = obs_tick_seconds(get_registry(), "sampler")
+        registry = get_registry()
+        self._tick_seconds = obs_tick_seconds(registry, "sampler")
+        self._reused = registry.histogram(
+            "pio_prof_fold_reused",
+            "Share of a sampler tick's frames in chains unchanged since "
+            "the previous tick (the same code objects), not keyed anew",
+            buckets=(0.25, 0.5, 0.75, 0.9, 0.95, 0.99, 1.0)).labels()
 
     # -- lifecycle -----------------------------------------------------------
     @property
@@ -229,73 +316,142 @@ class SamplingProfiler:
         number of threads folded. Public so tests can drive the fold
         deterministically without a live sampler thread."""
         with obs_tick(self._tick_seconds, "sampler"):
-            return self._sample(skip_ident)
+            names = {t.ident: t.name for t in threading.enumerate()}
+            return self._sample(sys._current_frames(), names, skip_ident)
 
-    def _sample(self, skip_ident: Optional[int]) -> int:
-        names = {t.ident: t.name for t in threading.enumerate()}
-        frames = sys._current_frames()
-        folded = 0
+    def _sample(self, frames: Dict[int, FrameType], names: Dict[int, str],
+                skip_ident: Optional[int]) -> int:
+        """Fold one tick of `frames` (thread ident -> innermost frame, as
+        `sys._current_frames()` gives them), each thread's role from
+        `names` (ident -> thread name); a chain whose code objects are
+        the last tick's takes its path whole (see the module
+        docstring)."""
+        me = threading.get_ident()
+        folded = keyed = reused = 0
         with self._lock:
             self._ticks += 1
+            before, held = self._held, {}
+            role_samples = self._role_samples
             for ident, frame in frames.items():
                 if ident == skip_ident:
                     continue
-                role = role_of(names.get(ident, ""))
-                stack: List[str] = []
-                f = frame
-                while f is not None:
-                    code = f.f_code
-                    mod = code.co_filename.rsplit("/", 1)[-1]
-                    stack.append(f"{mod}:{code.co_name}")
-                    f = f.f_back
-                stack.reverse()        # outermost first, flamegraph order
-                self._fold_locked(role, stack)
                 folded += 1
+                name = names.get(ident, "")
+                codes: List[CodeType] = []
+                f: Optional[FrameType] = frame
+                while f is not None:
+                    codes.append(f.f_code)
+                    f = f.f_back
+                ids = list(map(id, codes))
+                h = before.get(ident)
+                if h is None or h.ids != ids or h.name != name:
+                    h = self._refold_locked(h, codes, ids, name)
+                    keyed += len(ids)
+                else:                    # parked: the same chain
+                    reused += len(ids)
+                if ident != me:
+                    held[ident] = h
+                role_samples[h.role] = role_samples.get(h.role, 0) + 1
+                path = h.path
+                if path.truncated:
+                    self._truncated += 1
+                if path.leaf is not None:
+                    path.leaf.ended += 1
+                    if path.samples is None:
+                        _count_frames(self._self_counts, self._cum_counts,
+                                      path.stack, 1)
+                    else:
+                        path.samples += 1
+            self._held = held
             self._samples += folded
+        total = keyed + reused
+        self._reused.observe(reused / total if total else 0.0)
         return folded
 
-    def _fold_locked(self, role: str, stack: List[str]) -> None:
-        self._role_samples[role] = self._role_samples.get(role, 0) + 1
-        if not stack:
-            return
+    def _refold_locked(self, prev: Optional[_Held], codes: List[CodeType],
+                       ids: List[int], name: str) -> _Held:
+        """The path of the chain whose code objects are `codes`
+        (innermost first), each frame's key from the memo."""
+        if prev is not None and prev.name == name:
+            role = prev.role
+        else:
+            role = role_of(name)
+        keys = self._keys
+        stack = [keys.get(i) or self._key(c) for i, c in zip(ids, codes)]
+        stack.reverse()                  # outermost first, flamegraph order
+        return _Held(codes, ids, name, role,
+                     self._path_locked(role, tuple(stack)))
+
+    def _key(self, code: CodeType) -> str:
+        """A frame's "module:function", built once per code object. The
+        memo is keyed by `id`: a code object's own hash walks its
+        constants, nested code included, on every call; the memo holds
+        the code objects, so an `id` stays theirs."""
+        key = self._keys.get(id(code))
+        if key is None:
+            if len(self._keys) >= _KEYS_MAX:
+                self._keys.clear()
+                self._codes.clear()
+            mod = code.co_filename.rsplit("/", 1)[-1]
+            key = self._keys[id(code)] = f"{mod}:{code.co_name}"
+            self._codes.append(code)
+        return key
+
+    def _path_locked(self, role: str, stack: Tuple[str, ...]) -> _Path:
+        """The path of (role, stack), its trie leaf found (and the trie
+        grown, within the budget) on the path's first sight."""
+        path = self._paths.get((role, stack))
+        if path is None:
+            tabled = len(self._paths) < self.max_nodes
+            path = _Path(stack, *self._leaf_locked(role, stack),
+                         samples=0 if tabled else None)
+            if tabled:
+                self._paths[(role, stack)] = path
+        return path
+
+    def _leaf_locked(self, role: str, stack: Tuple[str, ...]
+                     ) -> Tuple[Optional[_Node], bool]:
+        """Walk the trie along `stack` under `role`'s root, allocating
+        nodes while the budget lasts: the node a sample of it ends at
+        (None where the role has no root and the budget is spent) and
+        whether the budget cut the walk short. Once the budget is spent
+        no node is ever allocated again (until `reset`), so a path's
+        leaf is the same on every later sight."""
         node = self._roots.get(role)
         if node is None:
             if self._nodes >= self.max_nodes:   # budget covers roots too
-                self._truncated += 1
-                return
+                return None, True
             node = self._roots[role] = _Node()
             self._nodes += 1
-        truncated = False
         for key in stack:
             child = node.children.get(key)
             if child is None:
                 if self._nodes >= self.max_nodes:
-                    truncated = True
-                    break
+                    return node, True
                 child = node.children[key] = _Node()
                 self._nodes += 1
             node = child
-        if truncated:
-            self._truncated += 1
-        node.ended += 1
-        innermost = stack[-1]
-        self._self_counts[innermost] = self._self_counts.get(
-            innermost, 0) + 1
-        for key in set(stack):
-            self._cum_counts[key] = self._cum_counts.get(key, 0) + 1
+        return node, False
 
     # -- export --------------------------------------------------------------
     def snapshot_json(self, top: int = 30) -> Dict:
-        """Shape served at /profile.json: role shares + top frames."""
+        """Shape served at /profile.json: role shares + top frames (ties
+        in frame order)."""
         with self._lock:
             samples = self._samples
             roles = dict(self._role_samples)
-            self_top = sorted(self._self_counts.items(),
-                              key=lambda kv: -kv[1])[:top]
-            cum_top = sorted(self._cum_counts.items(),
-                             key=lambda kv: -kv[1])[:top]
+            self_counts = dict(self._self_counts)
+            cum_counts = dict(self._cum_counts)
+            for path in self._paths.values():
+                if path.samples and path.leaf is not None:
+                    _count_frames(self_counts, cum_counts, path.stack,
+                                  path.samples)
             nodes, truncated = self._nodes, self._truncated
             ticks = self._ticks
+        self_top = sorted(self_counts.items(),
+                          key=lambda kv: (-kv[1], kv[0]))[:top]
+        cum_top = sorted(cum_counts.items(),
+                         key=lambda kv: (-kv[1], kv[0]))[:top]
         denom = float(samples) or 1.0
 
         def _frames(pairs: Iterable[Tuple[str, int]]) -> List[Dict]:
@@ -338,8 +494,10 @@ class SamplingProfiler:
             self._roots.clear()
             self._nodes = 0
             self._truncated = 0
+            self._paths.clear()
             self._self_counts.clear()
             self._cum_counts.clear()
+            self._held = {}
             self._role_samples.clear()
             self._samples = 0
             self._ticks = 0

@@ -1,6 +1,8 @@
 """Continuous-observatory tests: the always-on sampling profiler
 (frame trie bounds, thread-role attribution, /profile.json +
-collapsed-stack export), the GC-pause hook, the in-process tsdb ring
+collapsed-stack export, and its fold, which reuses the paths of the
+chains unchanged since the last tick, against a walk of every frame),
+the GC-pause hook, the in-process tsdb ring
 (bounds, delta decode, counter-rate math, /tsdb.json?since=
 filtering), fleet metrics federation with a member down, the
 dashboard sparkline panels, and the `pio-tpu top` terminal view.
@@ -8,9 +10,14 @@ dashboard sparkline panels, and the `pio-tpu top` terminal view.
 
 import gc
 import json
+import os
+import subprocess
+import sys
+import textwrap
 import threading
 import time
 import urllib.request
+import weakref
 
 import numpy as np
 import pytest
@@ -155,6 +162,430 @@ class TestSamplingProfiler:
         snap = prof.snapshot_json()
         assert snap["samples"] == 0 and snap["trie"]["nodes"] == 0
         assert prof.collapsed() == ""
+
+
+# -- the fold against a walk of every frame ----------------------------------
+
+class _EagerFold:
+    """The reference: every frame of every thread walked on every tick,
+    and the per-frame counts kept per sample, with the trie's budget
+    rule; what the sampler's fold must equal."""
+
+    def __init__(self, max_nodes):
+        self.max_nodes = max_nodes
+        self.reset()
+
+    def reset(self):
+        self.roots, self.nodes, self.truncated = {}, 0, 0
+        self.self_counts, self.cum_counts, self.role_samples = {}, {}, {}
+        self.samples = self.ticks = 0
+
+    def tick(self, frames, names):
+        self.ticks += 1
+        for ident, frame in frames.items():
+            stack, f = [], frame
+            while f is not None:
+                code = f.f_code
+                mod = code.co_filename.rsplit("/", 1)[-1]
+                stack.append(f"{mod}:{code.co_name}")
+                f = f.f_back
+            stack.reverse()
+            self._fold(role_of(names.get(ident, "")), stack)
+            self.samples += 1
+
+    def _fold(self, role, stack):
+        self.role_samples[role] = self.role_samples.get(role, 0) + 1
+        node = self.roots.get(role)
+        if node is None:
+            if self.nodes >= self.max_nodes:
+                self.truncated += 1
+                return
+            node = self.roots[role] = prof_mod._Node()
+            self.nodes += 1
+        for key in stack:
+            child = node.children.get(key)
+            if child is None:
+                if self.nodes >= self.max_nodes:
+                    self.truncated += 1
+                    break
+                child = node.children[key] = prof_mod._Node()
+                self.nodes += 1
+            node = child
+        node.ended += 1
+        self.self_counts[stack[-1]] = self.self_counts.get(stack[-1], 0) + 1
+        for key in set(stack):
+            self.cum_counts[key] = self.cum_counts.get(key, 0) + 1
+
+    def snapshot(self, top=30):
+        denom = float(self.samples) or 1.0
+
+        def frames(counts):
+            pairs = sorted(counts.items(), key=lambda kv: (-kv[1], kv[0]))
+            return [{"frame": k, "samples": v, "share": round(v / denom, 4)}
+                    for k, v in pairs[:top]]
+
+        return {
+            "ticks": self.ticks, "samples": self.samples,
+            "roles": {r: {"samples": n, "share": round(n / denom, 4)}
+                      for r, n in sorted(self.role_samples.items(),
+                                         key=lambda kv: -kv[1])},
+            "top_self": frames(self.self_counts),
+            "top_cumulative": frames(self.cum_counts),
+            "trie": {"nodes": self.nodes, "max_nodes": self.max_nodes,
+                     "truncated_samples": self.truncated}}
+
+    def collapsed(self):
+        lines = []
+        for role in sorted(self.roots):
+            stack = [(self.roots[role], role)]
+            while stack:
+                node, path = stack.pop()
+                if node.ended:
+                    lines.append(f"{path} {node.ended}")
+                for key in sorted(node.children):
+                    stack.append((node.children[key], f"{path};{key}"))
+        return "".join(line + "\n" for line in lines)
+
+
+def _trie(node):
+    return (node.ended,
+            {k: _trie(c) for k, c in node.children.items()})
+
+
+def _assert_same(prof, ref):
+    snap = prof.snapshot_json()
+    for volatile in ("hz", "running", "since"):
+        del snap[volatile]
+    assert snap == ref.snapshot()
+    assert prof.collapsed() == ref.collapsed()
+    assert {r: _trie(n) for r, n in prof._roots.items()} == \
+        {r: _trie(n) for r, n in ref.roots.items()}
+
+
+class _Scripted:
+    """A named thread that runs `steps` in turn; each parks once or
+    more in `park`, where its Python frames stand still (the two locks'
+    methods are C calls: no frame of theirs is on the stack) until the
+    test `advance`s it to its next park, or to its end."""
+
+    def __init__(self, name, *steps):
+        self.steps, self.halted = steps, False
+        self.arrive = threading.Lock()
+        self.gate = threading.Lock()
+        self.arrive.acquire()
+        self.gate.acquire()
+        self.thread = threading.Thread(target=self._run, name=name,
+                                       daemon=True)
+
+    def park(self):
+        if not self.halted:
+            self.arrive.release()
+            self.gate.acquire()
+
+    def _run(self):
+        for step in self.steps:
+            step(self)
+        if not self.halted:
+            self.arrive.release()
+
+    def start(self):
+        self.thread.start()
+        assert self.arrive.acquire(timeout=10)
+        return self
+
+    def advance(self):
+        self.gate.release()
+        assert self.arrive.acquire(timeout=10)
+
+    def finish(self):
+        if self.thread.is_alive():
+            self.halted = True
+            self.gate.release()
+            self.thread.join(10)
+        assert not self.thread.is_alive()
+
+
+def _deep(s, n):
+    if n:
+        return _deep(s, n - 1)
+    s.park()
+
+
+def _depths(*ns):
+    def step(s):
+        for n in ns:
+            _deep(s, n)
+    return step
+
+
+def _next_job(s):
+    s.park()
+
+
+def _handle(s, n):
+    _deep(s, n)
+
+
+def _worker_loop(s):
+    for n in (2, 5, 1):
+        _next_job(s)
+        _handle(s, n)
+    _next_job(s)
+
+
+class _Yield:
+    def __await__(self):
+        yield
+
+
+def _gen_body(s):
+    while not s.halted:
+        s.arrive.release()      # parked in this very frame
+        s.gate.acquire()
+        yield
+
+
+async def _coro_body(s):
+    while not s.halted:
+        s.arrive.release()
+        s.gate.acquire()
+        await _Yield()
+
+
+def _via_a(g):
+    g.send(None)
+
+
+def _via_b(g):
+    g.send(None)
+
+
+def _resumed(body):
+    def step(s):
+        g = body(s)
+        for via in (_via_a, _via_b, _via_b, _via_a):
+            via(g)
+    return step
+
+
+def _scene_parked(run):
+    run.start(_Scripted("wire-3", _depths(6)))
+    run.start(_Scripted("wire-reactor-0", _depths(9)))
+    run.start(_Scripted("pio-fleet-health", _depths(0)))
+    for _ in range(4):
+        run.tick()
+
+
+def _scene_returned(run):
+    worker = run.start(_Scripted("wire-7", _worker_loop))
+    run.start(_Scripted("wire-8", _depths(3)))
+    for _ in range(6):
+        run.tick()
+        run.tick()
+        worker.advance()
+    run.tick()
+
+
+def _scene_resumed(body):
+    def scene(run):
+        s = run.start(_Scripted("pio-batch-drain", _resumed(body)))
+        for _ in range(3):
+            run.tick()
+            s.advance()
+            run.tick()
+    return scene
+
+
+def _scene_exit_and_reuse(run):
+    gone = run.start(_Scripted("wire-1", _depths(4)))
+    stays = run.start(_Scripted("wire-2", _depths(2)))
+    ended = run.start(_Scripted("wire-3", _depths(3)))
+    run.tick()
+    ended.advance()                     # returns, and the thread ends
+    ended.finish()
+    run.tick()
+    run.tick({42: gone, 43: stays}, {42: "wire-1", 43: "wire-2"})
+    gone.advance()
+    gone.finish()
+    # the ident comes back at once, on a thread of another role
+    heir = run.start(_Scripted("pio-batch-drain", _depths(4)))
+    run.tick({42: heir, 43: stays}, {42: "pio-batch-drain", 43: "wire-2"})
+    run.tick({42: heir, 43: stays}, {42: "pio-batch-drain", 43: "wire-2"})
+
+
+def _scene_budget(run):
+    walker = run.start(_Scripted("wire-5", _depths(*range(0, 60, 3))))
+    parked = run.start(_Scripted("wire-reactor-1", _depths(12)))
+    for _ in range(19):
+        run.tick()
+        walker.advance()
+        run.tick()
+    run.tick({7: parked, 8: walker}, {7: "wire-reactor-1", 8: "wire-5"})
+    # a parked thread renamed into a role the spent budget has no root for
+    run.tick({7: parked, 8: walker}, {7: "pio-refresher", 8: "wire-5"})
+    run.tick({7: parked, 8: walker}, {7: "pio-refresher", 8: "wire-5"})
+    assert run.prof._nodes == run.prof.max_nodes
+    assert len(run.prof._paths) == run.prof.max_nodes
+    assert run.prof._self_counts        # paths past the table, per frame
+
+
+def _scene_reset(run):
+    walker = run.start(_Scripted("wire-9", _worker_loop))
+    run.start(_Scripted("pio-fleet-health", _depths(5)))
+    run.tick()
+    walker.advance()
+    run.tick()
+    run.reset()
+    run.tick()
+    walker.advance()
+    run.tick()
+    run.reset()
+    run.tick()
+
+
+class _Run:
+    """Drives a scene: each tick hands the sampler's fold and the
+    reference the same frames of the scene's parked threads, and checks
+    that the trie, /profile.json and the collapsed stacks agree."""
+
+    def __init__(self, max_nodes):
+        self.prof = SamplingProfiler(hz=0, max_nodes=max_nodes)
+        self.ref = _EagerFold(self.prof.max_nodes)
+        self.scripts = []
+
+    def start(self, script):
+        self.scripts.append(script.start())
+        return script
+
+    def tick(self, by_ident=None, names=None):
+        if by_ident is None:
+            by_ident = {s.thread.ident: s for s in self.scripts
+                        if s.thread.is_alive()}
+            names = {i: s.thread.name for i, s in by_ident.items()}
+        now = sys._current_frames()
+        frames = {i: now[s.thread.ident] for i, s in by_ident.items()}
+        del now
+        self.prof._sample(frames, names, None)
+        self.ref.tick(frames, names)
+        _assert_same(self.prof, self.ref)
+
+    def reset(self):
+        self.prof.reset()
+        self.ref.reset()
+
+
+class TestFoldReuse:
+    @pytest.mark.parametrize("scene,max_nodes", [
+        (_scene_parked, 4096),
+        (_scene_returned, 4096),
+        (_scene_resumed(_gen_body), 4096),
+        (_scene_resumed(_coro_body), 4096),
+        (_scene_exit_and_reuse, 4096),
+        (_scene_budget, 16),
+        (_scene_reset, 4096),
+    ], ids=["parked", "returned-and-parked-again", "generator-resumed",
+            "coroutine-resumed", "exit-and-ident-reused",
+            "trie-at-its-budget", "reset-between-ticks"])
+    def test_fold_equals_a_walk_of_every_frame(self, scene, max_nodes):
+        run = _Run(max_nodes)
+        try:
+            scene(run)
+        finally:
+            for s in run.scripts:
+                s.finish()
+
+    @pytest.mark.parametrize("ends", [False, True],
+                             ids=["parks-elsewhere", "thread-ends"])
+    def test_a_returned_frame_is_not_held(self, ends):
+        class Payload:
+            pass
+
+        held = []
+
+        def holds(s):
+            payload = Payload()
+            held.append(weakref.ref(payload))
+            _deep(s, 2)
+
+        steps = (holds,) if ends else (holds, _depths(1))
+        s = _Scripted("wire-4", *steps).start()
+        try:
+            prof = SamplingProfiler(hz=0)
+            prof.sample_once()
+            s.advance()             # `holds` returns
+            if ends:
+                s.thread.join(10)
+            assert held[0]() is None
+            prof.sample_once()
+        finally:
+            s.finish()
+
+    def test_nothing_of_the_sampling_thread_is_held(self):
+        class Payload:
+            pass
+
+        prof = SamplingProfiler(hz=0)
+
+        def sample_beside_a_payload():
+            payload = Payload()
+            prof.sample_once()
+            return weakref.ref(payload)
+
+        assert sample_beside_a_payload()() is None
+
+    def test_held_frames_go_before_the_interpreter_finalizes(self):
+        """A frame the sampler saw at its last tick, returned since, has
+        let its locals go before the interpreter finalizes (a sampler
+        that held frames let a call's device arrays go during
+        finalization, and the process aborted at exit)."""
+        script = textwrap.dedent("""
+            import sys, threading, time
+            from predictionio_tpu.obs.profiler import SamplingProfiler
+
+            class Witness:
+                def __del__(self):
+                    print("finalizing" if sys.is_finalizing() else "before",
+                          flush=True)
+
+            def holds(go):
+                witness = Witness()
+                go.wait()
+
+            go = threading.Event()
+            t = threading.Thread(target=holds, args=(go,), name="wire-1",
+                                 daemon=True)
+            t.start()
+            prof = SamplingProfiler(hz=2)
+            prof.start()
+            time.sleep(0.7)      # one tick has seen `holds`' frame
+            go.set()
+            t.join(5)            # returned; the next tick is 0.3 s away
+        """)
+        out = subprocess.run(
+            [sys.executable, "-c", script], capture_output=True, text=True,
+            timeout=60, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.split() == ["before"]
+
+    def test_reuse_share_is_observed_once_a_tick(self):
+        prof_mod._reset_global_for_tests()   # no live sampler beside us
+        s = _Scripted("wire-6", _depths(8)).start()
+        try:
+            prof = SamplingProfiler(hz=0)
+            hist = prof._reused
+            count, total = hist.count, hist.sum
+            frames = {s.thread.ident: sys._current_frames()[s.thread.ident]}
+            names = {s.thread.ident: s.thread.name}
+            prof._sample(frames, names, None)     # nothing held yet
+            assert (hist.count, hist.sum) == (count + 1, total)
+            prof._sample(frames, names, None)     # parked: all reused
+            assert (hist.count, hist.sum) == (count + 2, total + 1.0)
+            for _ in range(3):
+                prof.sample_once()
+            assert hist.count == count + 5
+        finally:
+            s.finish()
 
 
 class TestGCPauseHook:
